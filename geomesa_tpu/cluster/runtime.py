@@ -114,25 +114,16 @@ class ClusterRuntime:
         plats = (os.environ.get("JAX_PLATFORMS")
                  or getattr(jax.config, "jax_platforms", None) or "")
         if str(plats).split(",")[0].strip().lower() == "cpu":
-            try:
-                jax.config.update("jax_cpu_collectives_implementation",
-                                  "gloo")
-            except Exception:
-                pass  # older/newer jax without the knob: initialize decides
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
         kwargs = {"coordinator_address": self.coordinator,
                   "num_processes": self.num_processes,
                   "process_id": self.process_id}
         n_local = config.CLUSTER_LOCAL_DEVICES.get()
         if n_local and n_local > 0:
             kwargs["local_device_ids"] = list(range(n_local))
-        try:
-            jax.distributed.initialize(
-                initialization_timeout=int(
-                    config.CLUSTER_INIT_TIMEOUT_S.get()),
-                **kwargs)
-        except TypeError:
-            # older jax without initialization_timeout
-            jax.distributed.initialize(**kwargs)
+        jax.distributed.initialize(
+            initialization_timeout=int(config.CLUSTER_INIT_TIMEOUT_S.get()),
+            **kwargs)
         self.initialized = True
         return self
 

@@ -52,7 +52,6 @@ gather blocks, and any structure-key drift between the lowered and
 interpreted residuals.
 
 Knobs: ``GEOMESA_TPU_FUSED_QUERY`` (master switch),
-``GEOMESA_TPU_PALLAS_REFINE`` (Pallas point-in-polygon inner loop),
 ``GEOMESA_TPU_FUSED_SHAPE_CACHE`` (recipe LRU bound),
 ``GEOMESA_TPU_KERNEL_CACHE`` (compiled program LRU bound).
 """
@@ -342,76 +341,6 @@ def _block_summaries(index, bsz: int):
     return summ
 
 
-# -- Pallas point-in-polygon refine prototype --------------------------------
-
-
-_PALLAS_OK: Optional[bool] = None
-
-
-def _pallas_pip(px, py, edges):
-    """Pallas tiling of the certainty-band point-in-polygon classifier:
-    point tiles stream through VMEM against the full resident edge table.
-    CPU-safe via interpret mode (non-TPU backends)."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    n = int(px.shape[0])
-    ne = int(edges.shape[0])
-    tile = 512 if n >= 512 else _pow2(n)
-    npad = -(-n // tile) * tile
-    if npad != n:
-        far = jnp.full((npad - n,), 1e9, jnp.float32)
-        px = jnp.concatenate([px, far])   # pad rows classify certain-out
-        py = jnp.concatenate([py, far])
-
-    def kernel(px_ref, py_ref, e_ref, cin_ref, cout_ref):
-        e = e_ref[...]
-        cin, cout = _pip_band(
-            px_ref[...][:, None], py_ref[...][:, None],
-            e[None, :, 0], e[None, :, 1], e[None, :, 2], e[None, :, 3])
-        cin_ref[...] = cin
-        cout_ref[...] = cout
-
-    cin, cout = pl.pallas_call(
-        kernel,
-        out_shape=[jax.ShapeDtypeStruct((npad,), jnp.bool_)] * 2,
-        grid=(npad // tile,),
-        in_specs=[pl.BlockSpec((tile,), lambda i: (i,)),
-                  pl.BlockSpec((tile,), lambda i: (i,)),
-                  pl.BlockSpec((ne, 4), lambda i: (0, 0))],
-        out_specs=[pl.BlockSpec((tile,), lambda i: (i,))] * 2,
-        interpret=jax.default_backend() != "tpu",
-    )(px, py, edges)
-    return cin[:n], cout[:n]
-
-
-def _pallas_available() -> bool:
-    """GEOMESA_TPU_PALLAS_REFINE gate + a one-time eager probe: any failure
-    (backend without pallas lowering) permanently falls back to the jnp
-    band kernel, so the knob can never break correctness."""
-    if not config.PALLAS_REFINE.get():
-        return False
-    global _PALLAS_OK
-    if _PALLAS_OK is None:
-        try:
-            import jax.numpy as jnp
-            ep = jnp.asarray(np.tile(ScanKernels._EDGE_PAD, (4, 1)))
-            z = jnp.zeros(4, jnp.float32)
-            _PALLAS_OK = bool(np.asarray(_pallas_pip(z, z, ep)[1]).all())
-        except Exception:
-            _PALLAS_OK = False
-    return _PALLAS_OK
-
-
-def _pip_flags(px, py, edges, use_pallas: bool):
-    if use_pallas:
-        return _pallas_pip(px, py, edges)
-    return _pip_band(px[:, None], py[:, None],
-                     edges[None, :, 0], edges[None, :, 1],
-                     edges[None, :, 2], edges[None, :, 3])
-
-
 # -- the fused program --------------------------------------------------------
 
 
@@ -443,7 +372,7 @@ class _Program:
 
 def _jit_program(mode: str, slots: tuple, six: Dict[str, int], emit,
                  T: int, n: int, bsz: int, cap: int, sel_cap: int,
-                 unc_cap: int, use_pallas: bool, has_bin: bool,
+                 unc_cap: int, has_bin: bool,
                  width: int, height: int, refine: str = "pip"):
     """Build + jit one fused program. Everything here is structure; values
     arrive through the packed vector at dispatch time."""
@@ -513,7 +442,10 @@ def _jit_program(mode: str, slots: tuple, six: Dict[str, int], emit,
                 cout = d >= dz[2] + _DIST_BAND
             else:
                 edges = get(packed, six["edges"])
-                cin, cout = _pip_flags(c["xf"], c["yf"], edges, use_pallas)
+                cin, cout = _pip_band(
+                    c["xf"][:, None], c["yf"][:, None],
+                    edges[None, :, 0], edges[None, :, 1],
+                    edges[None, :, 2], edges[None, :, 3])
             return m & cin, m & ~cin & ~cout
 
         if mode == "count":
@@ -679,18 +611,17 @@ def _build(index, sft, vocabs, mode: str, boxes: np.ndarray,
     sel_cap = min(_tier(capacity), _pow2(n)) \
         if mode in ("select", "select_refine") else 0
     unc_cap = _UNC_CAP if refine else 0
-    use_pallas = refine == "pip" and _pallas_available()
     has_bin = T > 0 and "bin" in cols
 
     # value-free program key: geometry/time/residual VALUES ride in the
     # packed vector; only structure lands here, so N distinct bboxes of one
     # shape share one compile (the recompile-churn pin)
     key = ("fq", mode, res_key, refine, layout.signature(), n, bsz, cap,
-           sel_cap, unc_cap, use_pallas, has_bin, width, height)
+           sel_cap, unc_cap, has_bin, width, height)
     slots = tuple(layout.slots)
     fn = _PROGRAMS.get(key, lambda: _jit_program(
         mode, slots, dict(six), emit, T, n, bsz, cap, sel_cap, unc_cap,
-        use_pallas, has_bin, width, height, refine))
+        has_bin, width, height, refine))
     summ = _block_summaries(index, bsz)
     return _Program(fn, cols, summ, layout.pack(values), mode, sel_cap,
                     unc_cap, n, res_key, key, layout)
@@ -1600,8 +1531,8 @@ def note_shape(planner, plan, f: ir.Filter, auths,
 def warm_programs(index) -> int:
     """Compile the common fused single-dispatch count shapes for an index
     ahead of traffic (1 box; 1 box + 1 window on temporal layers) and run
-    each once, paying the XLA compile + packed transfer-shape setup at
-    startup instead of on the first cold query. Returns programs warmed."""
+    each once, paying the XLA compile at startup instead of on the first
+    cold query. A compile failure raises. Returns programs warmed."""
     if not config.FUSED_QUERY.get():
         return 0
     cols = getattr(getattr(index, "device", None), "columns", None)

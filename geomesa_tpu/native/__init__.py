@@ -15,9 +15,13 @@ tests/test_native.py.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 import threading
+import warnings
+from typing import Optional
 
 import numpy as np
 
@@ -28,6 +32,7 @@ _SO = os.path.join(_DIR, "_encode.so")
 _lib = None
 _lock = threading.Lock()
 _load_failed = False
+_fallback_reason: Optional[str] = None   # why _load gave up (None: it didn't)
 
 
 def _nthreads() -> int:
@@ -37,38 +42,63 @@ def _nthreads() -> int:
         return 1
 
 
-def _src_digest() -> str:
-    import hashlib
+def _cpu_identity() -> str:
+    """What ``-march=native`` compiled for: the first processor's model and
+    feature flags (a binary copied from another host must not load as
+    fresh here)."""
+    ident = platform.machine()
+    try:
+        with open("/proc/cpuinfo") as f:
+            first = f.read().split("\n\n", 1)[0]
+        ident += "".join(
+            line for line in first.splitlines(True)
+            if line.split(":", 1)[0].strip() in ("model name", "flags",
+                                                  "Features"))
+    except OSError:
+        ident += platform.processor()
+    return hashlib.sha256(ident.encode()).hexdigest()[:16]
+
+
+def _stamp_prefix() -> str:
+    """Freshness stamp minus the flags: source digest + this host's CPU."""
     with open(_SRC, "rb") as f:
-        return hashlib.sha256(f.read()).hexdigest()[:16]
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return f"{digest} {_cpu_identity()} "
 
 
-def _build() -> bool:
+def _build() -> None:
     """Compile the shared object (per-process temp name + atomic rename, so
-    concurrent first-use from several processes can't install a torn file);
-    records the source digest next to it for freshness checks."""
+    concurrent first-use from several processes can't install a torn file)
+    and stamp it with source digest, CPU identity and flags. Raises
+    RuntimeError carrying every attempt's command and compiler stderr."""
     cxx = os.environ.get("CXX", "g++")
     tmp = f"{_SO}.{os.getpid()}.tmp"
+    errors = []
     for flags in (["-O3", "-march=native"], ["-O3"]):  # native may not exist
         cmd = [cxx, *flags, "-shared", "-fPIC", "-std=c++17", "-pthread",
                _SRC, "-o", tmp]
         try:
-            subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+            subprocess.run(cmd, check=True, capture_output=True, text=True,
+                           timeout=120)
             os.replace(tmp, _SO)
             with open(_SO + ".sha", "w") as f:
-                f.write(_src_digest())
-            return True
-        except Exception:
+                f.write(_stamp_prefix() + " ".join(flags))
+            return
+        except (OSError, subprocess.SubprocessError) as e:
+            stderr = (getattr(e, "stderr", None) or "").strip()
+            errors.append(f"{' '.join(cmd)}: {e}"
+                          + (f"\n{stderr}" if stderr else ""))
             try:
                 os.unlink(tmp)
             except OSError:
                 pass
-    return False
+    raise RuntimeError("\n".join(errors))
 
 
 def _load():
-    """The compiled library, or None when unavailable (numpy fallback)."""
-    global _lib, _load_failed
+    """The compiled library, or None when unavailable (numpy fallback —
+    slower; the reason is warned once and kept in ``fallback_reason()``)."""
+    global _lib, _load_failed, _fallback_reason
     if _lib is not None or _load_failed:
         return _lib
     with _lock:
@@ -79,21 +109,19 @@ def _load():
             _load_failed = True
             return None
         try:
-            digest = _src_digest()
             try:
                 with open(_SO + ".sha") as f:
-                    fresh = os.path.exists(_SO) and f.read().strip() == digest
+                    fresh = os.path.exists(_SO) \
+                        and f.read().startswith(_stamp_prefix())
             except OSError:
                 fresh = False
-            if not fresh and not _build():
-                _load_failed = True
-                return None
+            if not fresh:
+                _build()
             try:
                 lib = ctypes.CDLL(_SO)
             except OSError:
                 # stale/foreign binary (different arch or glibc): rebuild once
-                if not _build():
-                    raise
+                _build()
                 lib = ctypes.CDLL(_SO)
             i64, i32, i16, u32, f64, f32 = (
                 np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS"),
@@ -119,9 +147,22 @@ def _load():
                 ctypes.c_int64, ctypes.c_int32, i64, i64, u8, ctypes.c_int64]
             lib.gm_zranges.restype = ctypes.c_int64
             _lib = lib
-        except Exception:
+        except (OSError, RuntimeError, AttributeError) as e:
+            # no toolchain / unloadable binary / missing symbol: the numpy
+            # paths serve (bit-identical, slower) — said once, with the cause
             _load_failed = True
+            _fallback_reason = f"{type(e).__name__}: {e}"
+            warnings.warn(
+                "geomesa_tpu.native: C++ encoder unavailable, using the "
+                f"numpy paths ({_fallback_reason})", RuntimeWarning,
+                stacklevel=2)
     return _lib
+
+
+def fallback_reason() -> Optional[str]:
+    """Why the native library is not in use (None while it is, or before
+    first use, or when GEOMESA_TPU_NO_NATIVE turned it off)."""
+    return _fallback_reason
 
 
 def available() -> bool:

@@ -43,11 +43,9 @@ _MAX_DEVICE_K = 2048
 # per-planner KNN state: the radius that last satisfied the candidate
 # target (keyed by target, so k=10 and k=500 seed independently) and the
 # last padded block tier. Each extra radius round is a full host
-# plan+cover pass (the measured cfg4 cost at 100M — see the perf watch
-# report perf/reports/cfg4_knn_regression.json), and a tier flip between
-# adjacent powers of two is a fresh XLA compile (kernels.recompiles), so
-# both memos directly buy back blocking latency. Weak: a dropped planner
-# frees its state.
+# plan+cover pass, and a tier flip between adjacent powers of two is a
+# fresh XLA compile (kernels.recompiles), so both memos directly buy back
+# blocking latency. Weak: a dropped planner frees its state.
 _MEMO: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 
 

@@ -1150,7 +1150,9 @@ def serve_router(router: ReplicaRouter, host: str = "127.0.0.1",
     """Start the router's HTTP surface. ``background=True`` returns the
     server after starting a daemon thread (tests / embedded use)."""
     import json as _json
-    from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
+    from http.server import BaseHTTPRequestHandler
+
+    from geomesa_tpu.web.server import BacklogHTTPServer
 
     api = RouterApi(router, federator=federator)
 
@@ -1193,7 +1195,7 @@ def serve_router(router: ReplicaRouter, host: str = "127.0.0.1",
         def log_message(self, *a):
             pass
 
-    httpd = ThreadingHTTPServer((host, port), _RouterHandler)
+    httpd = BacklogHTTPServer((host, port), _RouterHandler)
     httpd.router_api = api
     if background:
         threading.Thread(target=httpd.serve_forever, daemon=True).start()

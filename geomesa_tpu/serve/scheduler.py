@@ -363,23 +363,15 @@ class QueryScheduler:
         self._n_single = 0
         self._running = True
         _metrics.set_gauge("scheduler.queue_depth", self._queue.qsize)
-        # pre-warm the fused-batch transfer shapes (boxes/windows/params at
-        # every pow2 flush tier) so the first coalesced dispatch doesn't eat
-        # the per-shape transfer cliff
-        from geomesa_tpu.index.scan import warm_transfer_shapes
-        tiers, b = [], 1
-        while b < self._flush_size:
-            b <<= 1
-            tiers.append(b)
-        # ... and the fused single-dispatch program tiers for every bound
-        # planner's indexes, so a cold single query through the scheduler
-        # doesn't pay the first-query XLA compile either (best-effort: the
-        # query path compiles lazily when warming can't reach the indexes)
-        fused_indexes = [
-            idx for p in getattr(binding, "_planners", {}).values()
-            for idx in getattr(p, "indexes", ())]
-        warm_transfer_shapes(batch_sizes=tiers or [1],
-                             fused_indexes=fused_indexes)
+        # compile the fused single-dispatch program tiers for every bound
+        # planner's indexes now, so a cold single query through the
+        # scheduler doesn't pay the first-query XLA compile — and a program
+        # the compiler refuses fails HERE, at start-up, instead of vanishing
+        # and resurfacing as a failed first query
+        from geomesa_tpu.index import compiled as _fused
+        for p in getattr(binding, "_planners", {}).values():
+            for idx in getattr(p, "indexes", ()):
+                _fused.warm_programs(idx)
         self._collector = threading.Thread(
             target=self._worker_main, args=("collector", self._collect_loop),
             name="geomesa-sched-collect", daemon=True)

@@ -156,6 +156,22 @@ def observe_table(stat: sk.Stat, table: FeatureTable,
             x, y = (bb[:, 0] + bb[:, 2]) / 2, (bb[:, 1] + bb[:, 3]) / 2
         stat.observe(x, y)
         return stat
+    if isinstance(stat, (sk.MinMaxStat, sk.FrequencyStat, sk.TopKStat)) \
+            and isinstance(sub.columns[stat.attr], StringColumn):
+        # dictionary column: observe each distinct value once, weighted by
+        # its code count — the same sketch state as a per-row pass without
+        # decoding (and sorting) one Python string per row
+        col = sub.columns[stat.attr]
+        cnt = np.bincount(col.codes, minlength=len(col.vocab))
+        present = np.flatnonzero(cnt)
+        values = np.asarray(col.vocab, dtype=object)[present]
+        order = np.argsort(values)   # vocab order is the builder's choice
+        values, cnt = values[order], cnt[present][order]
+        if isinstance(stat, sk.MinMaxStat):
+            stat.observe(values)     # min/max/HLL ignore multiplicity
+        else:
+            stat.observe(values, cnt)
+        return stat
     if isinstance(stat, sk.MinMaxStat):
         col = sub.columns[stat.attr]
         if isinstance(col, GeometryArray):

@@ -343,8 +343,13 @@ class TopKStat(Stat):
         self.attr = attr
         self.counts: Dict[object, int] = {}
 
-    def observe(self, values) -> None:
-        uniq, cnt = np.unique(np.asarray(values), return_counts=True)
+    def observe(self, values, counts=None) -> None:
+        """``counts`` given: ``values`` are distinct and sorted, each seen
+        ``counts[i]`` times (the dictionary-column form — no per-row pass)."""
+        if counts is None:
+            uniq, cnt = np.unique(np.asarray(values), return_counts=True)
+        else:
+            uniq, cnt = np.asarray(values), np.asarray(counts)
         order = np.argsort(-cnt)
         for i in order:
             v, c = _json_key(uniq[i]), int(cnt[i])
@@ -418,14 +423,16 @@ class FrequencyStat(Stat):
              % _U(self.width)).astype(np.int64)
             for i in range(self.DEPTH)])
 
-    def observe(self, values) -> None:
+    def observe(self, values, counts=None) -> None:
+        """``counts`` given: ``values`` are distinct, each seen ``counts[i]``
+        times (the dictionary-column form — no per-row pass)."""
         arr = np.asarray(values)
         if len(arr) == 0:
             return
         rows = self._rows(hash64(arr))
         for i in range(self.DEPTH):
-            np.add.at(self.table[i], rows[i], 1)
-        self.total += len(arr)
+            np.add.at(self.table[i], rows[i], 1 if counts is None else counts)
+        self.total += len(arr) if counts is None else int(np.sum(counts))
 
     def estimate(self, value) -> int:
         h = hash64(np.asarray([value]))

@@ -1251,6 +1251,8 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    from geomesa_tpu import config
+    config.enable_compile_cache()   # before any command touches a backend
     args.fn(args)
     return 0
 

@@ -65,7 +65,8 @@ Routes:
                                          pre-failover quiesce)
   POST /replication/promote?port=      → promote this replica to primary
                                          under a fresh fencing epoch
-  GET  /healthz                        → liveness + device count + durability,
+  GET  /healthz                        → liveness + backend, device kind and
+                                         count + durability,
                                          recovery/replay, replication and
                                          cluster-shard state
   GET  /cluster                        → partition plane: process count,
@@ -450,6 +451,8 @@ class GeoJsonApi:
                          "node": self._node_meta(),
                          "cluster": cluster,
                          "devices": len(jax.local_devices()),
+                         "backend": jax.default_backend(),
+                         "device_kind": jax.local_devices()[0].device_kind,
                          "types": len(self.store.get_type_names()),
                          "overload": overload,
                          "slo": slo,
@@ -717,12 +720,19 @@ class _Handler(BaseHTTPRequestHandler):
         pass
 
 
+class BacklogHTTPServer(ThreadingHTTPServer):
+    # socketserver's default listen backlog is 5: a burst of concurrent
+    # clients (the traffic the scheduler exists to batch) overflows it and
+    # the excess connects sit in SYN retransmits for 1, 2, 4 … seconds
+    request_queue_size = 128
+
+
 def serve(store, host: str = "127.0.0.1", port: int = 8765,
           background: bool = False):
     """Start the REST server. ``background=True`` returns the server after
     starting a daemon thread (tests / embedded use)."""
     handler = type("BoundHandler", (_Handler,), {"api": GeoJsonApi(store)})
-    httpd = ThreadingHTTPServer((host, port), handler)
+    httpd = BacklogHTTPServer((host, port), handler)
     if background:
         threading.Thread(target=httpd.serve_forever, daemon=True).start()
         return httpd

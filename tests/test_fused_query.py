@@ -41,7 +41,6 @@ def _small_blocks():
     yield
     config.PRUNE_BLOCK.unset()
     config.FUSED_QUERY.unset()
-    config.PALLAS_REFINE.unset()
 
 
 def _corpus(n=6000, seed=7):
@@ -156,22 +155,6 @@ def test_polygon_refine_parity(world):
     _check_parity(planner, table,
                   poly + " AND dtg DURING "
                   "2020-01-03T00:00:00Z/2020-01-25T00:00:00Z AND age > 20")
-
-
-def test_polygon_refine_pallas_variant(world):
-    planner, table = world
-    poly = ("INTERSECTS(geom, POLYGON((-10 20, 40 20, 40 60, -10 60, "
-            "15 40, -10 20)))")
-    base = planner.count(poly)
-    config.PALLAS_REFINE.set(True)
-    fused._PALLAS_OK = None   # re-probe under the knob
-    try:
-        assert planner.count(poly) == base
-        # CPU backends run Pallas in interpret mode — availability may be
-        # probed off on exotic backends, but correctness held either way
-    finally:
-        config.PALLAS_REFINE.unset()
-        fused._PALLAS_OK = None
 
 
 # -- recompile churn + dispatch accounting ------------------------------------

@@ -264,13 +264,6 @@ def test_scan_kernel_cache_bounded_and_correct(store):
     assert REGISTRY.snapshot()["gauges"].get("kernels.compiled", 0) >= 1
 
 
-def test_warm_transfer_shapes_accepts_batch_tiers():
-    from geomesa_tpu.index import scan as scan_mod
-    scan_mod.warm_transfer_shapes(batch_sizes=(3, 64, 100))
-    # rounds up to pow2 and records the warmed tiers
-    assert {4, 64, 128} <= scan_mod._WARMED_BATCH_SIZES
-
-
 # -- the web serving path -----------------------------------------------------
 
 

@@ -193,6 +193,19 @@ def test_observe_table(store):
     assert sum(seq.stats[2].counts.values()) == len(table)
 
 
+def test_observe_table_dictionary_column_equals_per_row(store):
+    """String columns observe each distinct value once, weighted by its
+    code count: the sketch state must equal the per-row observation."""
+    table = store.tables["pts"]
+    col = table.columns["name"]
+    raw = np.asarray(col.vocab, dtype=object)[col.codes]
+    for spec in ('MinMax("name")', 'Frequency("name",12)', 'TopK("name")'):
+        fast, slow = parse_stat(spec), parse_stat(spec)
+        observe_table(fast, table)
+        slow.observe(raw)
+        assert fast.to_dict() == slow.to_dict(), spec
+
+
 # -- GeoMesaStats API + estimation -------------------------------------------
 
 

@@ -138,6 +138,10 @@ def test_healthz(server):
     assert status == 200
     assert body["status"] == "ok" and body["devices"] >= 1
     assert body["types"] == 1
+    # what hardware answers: the CPU under the test harness
+    import jax
+    assert body["backend"] == jax.default_backend() == "cpu"
+    assert body["device_kind"] == jax.local_devices()[0].device_kind
 
 
 def test_bad_cql_is_400(server):
