@@ -74,7 +74,8 @@ from geomesa_tpu.index.scan import (EMPTY_BOX, EMPTY_WINDOW, PRIMARY_FNS,
                                     ModuleKernelCache, ScanKernels,
                                     Unsupported, _LazyBlockGather, _fetch,
                                     _grid_scatter, _pip_band, _time_mask,
-                                    pad_boxes, pad_windows, split_residual)
+                                    pad_boxes, pad_windows, program_name,
+                                    split_residual)
 from geomesa_tpu.index.spatial import _boxes_fp62, _strip_handled
 from geomesa_tpu.curves.binnedtime import time_to_binned_time
 from geomesa_tpu.metrics import REGISTRY
@@ -530,10 +531,11 @@ def _jit_program(mode: str, slots: tuple, six: Dict[str, int], emit,
 
     nb_blocks = -(-n // bsz)
     STATS["programs_built"] += 1
+    kid = f"fused_{mode}.point_boxes"
+    run.__name__ = program_name(kid)
     jitted = jax.jit(run)
     if _attrib.enabled():
-        jitted = _attrib.compile_probe(jitted, f"fused_{mode}.point_boxes",
-                                       cap)
+        jitted = _attrib.compile_probe(jitted, kid, cap)
     return jitted
 
 
@@ -986,9 +988,11 @@ def _jit_union_program(mode: str, slots: tuple, branches: tuple,
 
     nb_blocks = -(-n // bsz)
     STATS["programs_built"] += 1
+    kid = f"fused_union_{mode}"
+    run.__name__ = program_name(kid)
     jitted = jax.jit(run)
     if _attrib.enabled():
-        jitted = _attrib.compile_probe(jitted, f"fused_union_{mode}", cap)
+        jitted = _attrib.compile_probe(jitted, kid, cap)
     return jitted
 
 

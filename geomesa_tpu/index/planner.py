@@ -83,11 +83,12 @@ class QueryPlanner:
     def plan(self, f: Union[str, ir.Filter]) -> IndexScanPlan:
         if not _trace.enabled():
             return self._plan(f)
-        t0 = time.perf_counter()
+        t0 = time.perf_counter_ns()
         try:
             return self._plan(f)
         finally:
-            _trace.record("plan", "plan", time.perf_counter() - t0)
+            t1 = time.perf_counter_ns()
+            _trace.record("plan", "plan", (t1 - t0) / 1e9, t1)
 
     def _plan(self, f: Union[str, ir.Filter]) -> IndexScanPlan:
         if isinstance(f, str):
@@ -304,10 +305,13 @@ class QueryPlanner:
 
     # -- range pruning -------------------------------------------------------
 
-    def _pruned_blocks(self, plan: IndexScanPlan):
+    def _pruned_blocks(self, plan: IndexScanPlan, timed: bool = True):
         """Candidate gather-blocks for a plan (cached on the plan), or None
         when the full-table fused mask is the better scan. ≙ choosing ranged
-        scans over a full-table scan (QueryProperties.BlockFullTableScans)."""
+        scans over a full-table scan (QueryProperties.BlockFullTableScans).
+        ``timed=False``: the caller times the cover itself (the scheduler's
+        collector, which hands the seconds to the request's own trace), so
+        ``range_decompose`` is not observed a second time here."""
         from geomesa_tpu import config
         if not config.PRUNE_ENABLED.get():
             return None
@@ -320,11 +324,12 @@ class QueryPlanner:
             if (not plan.empty and plan.index is not None
                     and plan.candidate_slices is None
                     and hasattr(plan.index, "candidate_blocks")):
-                if _trace.enabled():
-                    t0 = time.perf_counter()
+                if timed and _trace.enabled():
+                    t0 = time.perf_counter_ns()
                     blocks = plan.index.candidate_blocks(plan)
+                    t1 = time.perf_counter_ns()
                     _trace.record("range_decompose", "range_decompose",
-                                  time.perf_counter() - t0)
+                                  (t1 - t0) / 1e9, t1)
                 else:
                     blocks = plan.index.candidate_blocks(plan)
             plan.blocks = blocks

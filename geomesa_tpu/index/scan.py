@@ -23,6 +23,7 @@ useFullFilter, Z3IndexKeySpace.scala:235-249):
 from __future__ import annotations
 
 import functools
+import re
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
@@ -771,8 +772,11 @@ class ScanKernels:
         else:
             raise ValueError(mode)
 
-        jitted = jax.jit(run)
         kid = f"{mode}.{primary_kind}"
+        # the XLA module (and every `jit_…` line of a profile) carries the
+        # kernel id, not `jit_run`
+        run.__name__ = program_name(kid)
+        jitted = jax.jit(run)
         if _prof.enabled():
             # recompile detection: a second distinct signature for this
             # kernel id (or a re-jit of an evicted one) is shape churn —
@@ -911,8 +915,7 @@ class ScanKernels:
         return lambda: fn(cols, b, w, rp)
 
     def _pad_blocks(self, blocks: np.ndarray) -> np.ndarray:
-        nb = max(8, 1 << max(0, (len(blocks) - 1)).bit_length())
-        out = np.full(nb, -1, dtype=np.int32)
+        out = np.full(blocks_tier(len(blocks)), -1, dtype=np.int32)
         out[: len(blocks)] = blocks
         return out
 
@@ -1145,6 +1148,18 @@ _I31MAX = (1 << 31) - 1
 # fp62 empty box: lo bound = +max, hi bound = 0 — matches nothing
 EMPTY_BOX = np.array([_I31MAX, _I31MAX, 0, 0, _I31MAX, _I31MAX, 0, 0], dtype=np.int32)
 EMPTY_WINDOW = np.array([1, 0, 0, 0], dtype=np.int32)    # bin_lo > bin_hi
+
+
+def program_name(kernel_id: str) -> str:
+    """A kernel id as a function name XLA accepts in a module name:
+    ``count_multi_blocks.point_boxes`` → ``count_multi_blocks_point_boxes``."""
+    return re.sub(r"\W", "_", kernel_id)
+
+
+def blocks_tier(n_blocks: int) -> int:
+    """Padded length of a candidate-block list: one compiled program per
+    tier, so this is the shape half of a pruned kernel's identity."""
+    return max(8, 1 << max(0, (n_blocks - 1)).bit_length())
 
 
 def pad_boxes(boxes: np.ndarray, min_size: int = 1) -> np.ndarray:

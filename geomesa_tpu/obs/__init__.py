@@ -72,11 +72,16 @@ def install() -> None:
     # pending fetch attributions, and the workload plane's pending event
     # queue all settle right before any snapshot-ish registry read, so
     # surfaces stay accurate without the per-query hot path paying for any
-    def _pre_drain():
-        from geomesa_tpu.obs import history as _history
-        from geomesa_tpu.obs import workload as _workload
+    def _pre_drain(reader: bool = True):
         _sampling.SAMPLER.drain()
         _attrib.flush()
+        if not reader:
+            # the registry folding its own backlog at a trace close: the
+            # roll-ups and the history tick stay a reader's to pay for (both
+            # are bounded on their own)
+            return
+        from geomesa_tpu.obs import history as _history
+        from geomesa_tpu.obs import workload as _workload
         _workload.WORKLOAD.drain()
         # history sampler LAST, so a tick retains the just-drained state;
         # self-throttled to the finest tier interval and reentrancy-guarded

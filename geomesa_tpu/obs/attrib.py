@@ -76,6 +76,9 @@ def record_transfer(kernel_id: str, tier: int, nbytes: int) -> None:
 
 
 def record_compile(kernel_id: str, tier: int, seconds: float) -> None:
+    # the probes call this from the thread that made a kernel's first call;
+    # the scheduler's cycle record takes the seconds from there
+    _local.first_call_s = seconds
     if not enabled():
         return
     _metrics.inc(_series(kernel_id, tier, "compiles"))
@@ -105,9 +108,20 @@ def compile_probe(fn, kernel_id: str, tier: int):
 
 class _Local(threading.local):
     label = None  # (kernel_id, tier) | None
+    first_call_s = None  # seconds of the last first call on this thread
 
 
 _local = _Local()
+
+
+def take_first_call() -> Optional[float]:
+    """Seconds of a kernel's first call (trace + compile, or a load from the
+    compile cache) made on this thread since the last take, else None — as
+    ``compile_probe`` / ``kernel_probe`` timed it, not timed again."""
+    s = _local.first_call_s
+    if s is not None:
+        _local.first_call_s = None
+    return s
 
 
 class kernel:

@@ -5,7 +5,9 @@ p99 regressed; only a per-request record with every dimension on one row
 tells you WHICH queries paid it. Every query/count/batch emits one
 structured wide event — trace id, query type, plan hash, plan/cover cache
 hit flags, batch size + batch id, admission class, deadline budget vs
-slack, device ms vs host ms, rows scanned/matched, shed/degrade/cancel/
+slack, ``device_ms`` vs ``host_ms`` (both host clock: ``device_ms`` is the
+host blocked until the answer was read back, never time on the device),
+rows scanned/matched, shed/degrade/cancel/
 breaker flags, error kind — into a bounded ring plus an optional JSONL
 sink with size rotation (the shared durability/rotation.py policy).
 
@@ -13,7 +15,9 @@ Two producers feed it:
 
   - the micro-batching scheduler emits the rich event per scheduled count
     (it knows cache hits, batch membership, admission class, degradation)
-    plus one ``batch`` event per fused device dispatch;
+    plus one ``batch`` event per fused device dispatch, which carries the
+    dispatch cycle's stages (``stages``: name → [start epoch ms, ms];
+    ``launch_ms``/``ready_ms``; see serve/scheduler.py ``_Dispatch``);
   - the trace-close hook derives an event from every other ROOT trace
     (direct counts, feature queries, explains), so the unscheduled paths
     are never dark.
@@ -277,7 +281,6 @@ def event_from_request(req, fut) -> dict:
     """The rich wide event for one scheduled request (serve/scheduler.py
     attaches this as a future done-callback — it fires on EVERY resolution
     path: result, degradation, cancellation, shed, crash sweep)."""
-    import time as _time
     err = None
     rows = None
     if fut.cancelled():
@@ -308,7 +311,8 @@ def event_from_request(req, fut) -> dict:
         "parent_span": req.parent_span,
         "plan_hash": plan_hash(req.type_name, req.f_key, req.auths_key),
         "duration_ms": round(
-            (_time.perf_counter() - req.t_submit) * 1000.0, 3),
+            (time.perf_counter_ns() - req.t_submit) / 1e6, 3),
+        # submit → its batch closed (none of its neighbours' planning)
         "queue_wait_ms": ms(req.queue_wait_s),
         "plan_cache_hit": req.plan_cache_hit,
         "cover_cache_hit": req.cover_cache_hit,
@@ -326,11 +330,19 @@ def event_from_request(req, fut) -> dict:
         "deadline_budget_ms": req.budget_ms,
         "deadline_slack_ms": None if req.deadline is None
         else round(req.deadline.remaining_ms(), 3),
+        # launch → resolved, on the host's clock
         "scan_ms": ms(req.scan_s),
-        # batched scan time IS the fused device round trip; singles carry
-        # their device split in the trace / kernel attribution instead
+        # NOT device time: for a batched request it is scan_ms, the host's
+        # wait from launch to its answer (launch, transfer, the program,
+        # read-back, the completer's turn). On a chip that idles 96 % of the
+        # time that is nearly all host. Device time is in a profiler trace;
+        # the dispatch's own split is the kind=batch event (``stages``).
+        # Singles carry theirs in the trace / kernel attribution instead
         "device_ms": ms(req.scan_s) if req.batched else None,
-        "host_ms": ms((req.plan_s or 0.0) + (req.queue_wait_s or 0.0)),
+        # submit → launch (queue_wait + batch_host); never launched: the
+        # queue wait alone
+        "host_ms": ms(req.queue_wait_s or 0.0) if req.t_launch is None
+        else round((req.t_launch - req.t_submit) / 1e6, 3),
         "rows_scanned": req.rows_scanned,
         "rows_matched": rows,
         "retries": req.retries,

@@ -52,10 +52,20 @@ and resolves with a flagged ApproximateCount. Worker loops are crash-safe:
 an unexpected worker death (or shutdown with work still queued) fails every
 outstanding future with a structured SchedulerCrashed/SchedulerShutdown
 error instead of leaving callers blocked forever.
+
+The dispatch cycle, timed from inside (``_Cycle`` / ``_Dispatch``): one turn
+of the collector is idle → window → plan loop (plan, cover, group) → per
+group union, prepare, launch; the completer adds pickup, delta, ready_wait,
+resolve. Every stage is a (start, end) pair on ``trace.py``'s clock. They go
+out on the ``kind=batch`` flight event (``stages``), the ``sched.stage.*``
+timers, ``slow_cycles`` of ``stats()`` and as ``sched.*`` annotations in a
+profiler trace; a request's own trace gets the partition submit → queue_wait
+→ batch_host → scan → wake from the same instants.
 """
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import queue
 import threading
@@ -86,8 +96,18 @@ from geomesa_tpu.serve.resilience.breaker import CircuitBreaker, retry_call
 from geomesa_tpu.serve.resilience.deadline import Deadline, DeadlineExceeded
 
 _pc = time.perf_counter
+_pcn = time.perf_counter_ns   # the clock of trace.py's spans
 _MISS = object()
 _STOP = object()
+_NO_ANNOTATION = contextlib.nullcontext()
+_SLOW_CYCLE_S = 1.0           # first dequeue → resolved; kept in slow_cycles
+_SLOW_CYCLES_KEPT = 8
+
+
+def _annotate(name: str, **kw):
+    """A ``sched.*`` span in a profiler trace, while tracing is on."""
+    return _trace.annotation(name, **kw) if _trace.enabled() \
+        else _NO_ANNOTATION
 
 
 def _query_cell(f: "ir.Filter") -> Optional[str]:
@@ -236,7 +256,8 @@ class Request:
 
     __slots__ = ("type_name", "f_ir", "f_key", "auths", "auths_key",
                  "planner", "delta", "generation", "epoch", "future",
-                 "t_submit", "plan", "queue_wait_s", "plan_s", "scan_s",
+                 "t_submit", "t_closed", "t_plan", "t_launch",
+                 "plan", "queue_wait_s", "scan_s",
                  "batched", "batch_size", "deadline", "priority",
                  "cancelled", "degraded",
                  # flight-recorder dimensions (obs/flight.py wide events)
@@ -264,10 +285,17 @@ class Request:
         self.generation = generation
         self.epoch = epoch
         self.future: Future = Future()
-        self.t_submit = _pc()
+        # instants on trace.py's clock (perf_counter_ns): submitted, its
+        # batch closed, its own (plan start, plan end, cover end) on a
+        # plan-cache miss, its dispatch launched (a single: handed to the
+        # completer). The stages below are their differences, in seconds:
+        # queue_wait = submit → closed, scan = launch → resolved
+        self.t_submit = _pcn()
+        self.t_closed: Optional[int] = None
+        self.t_plan: Optional[Tuple[int, int, int]] = None
+        self.t_launch: Optional[int] = None
         self.plan = None
         self.queue_wait_s: Optional[float] = None
-        self.plan_s: Optional[float] = None
         self.scan_s: Optional[float] = None
         self.batched = False
         self.batch_size = 1
@@ -296,6 +324,107 @@ class Request:
 
     def result(self, timeout: Optional[float] = None) -> int:
         return self.future.result(timeout=timeout)
+
+
+# -- the dispatch cycle, timed ------------------------------------------------
+
+
+def _observe_stages(stages: Dict[str, Tuple[int, int]]) -> None:
+    """(start, end) pairs into the ``sched.stage.<name>`` timers: a window's
+    delta of ``total_s`` is that stage's seconds."""
+    _metrics.observe_batch([("sched.stage." + name, (end - start) / 1e9)
+                            for name, (start, end) in stages.items()])
+
+
+class _Cycle:
+    """One turn of the collector thread, as instants on ``perf_counter_ns``:
+    ``idle`` (blocked in ``queue.get()`` with nothing queued) → ``window``
+    (first request → batch closed) → the per-request planning loop → one
+    ``_Dispatch`` per fused group. ``plan`` and ``cover`` are sums over the
+    loop's plan-cache misses; ``group`` is the rest of the loop (group keys,
+    deadline checks), so the three make the loop's wall exactly, and
+    ``loop_cpu_ns`` is the thread's CPU time across it: wall minus CPU is
+    time the thread wanted to run and did not (the GIL, the OS)."""
+
+    __slots__ = ("t_idle", "t_first", "t_closed", "t_loop", "t_loop_end",
+                 "plan_ns", "cover_ns", "plan_misses", "cover_misses",
+                 "loop_cpu_ns", "size")
+
+    def __init__(self, t_idle: int, t_first: int):
+        self.t_idle = t_idle
+        self.t_first = t_first
+        self.t_closed = self.t_loop = self.t_loop_end = t_first
+        self.plan_ns = self.cover_ns = self.loop_cpu_ns = 0
+        self.plan_misses = self.cover_misses = 0
+        self.size = 0
+
+    def stages(self) -> Dict[str, Tuple[int, int]]:
+        """(start, end) of the cycle's own stages. ``plan``, ``cover`` and
+        ``group`` interleave request by request inside the planning loop:
+        they are laid end to end from the loop's start in that order, so only
+        the loop's start and end are instants that happened; the lengths are
+        exact."""
+        plan_end = self.t_loop + self.plan_ns
+        cover_end = plan_end + self.cover_ns
+        return {"idle": (self.t_idle, self.t_first),
+                "window": (self.t_first, self.t_closed),
+                "plan": (self.t_loop, plan_end),
+                "cover": (plan_end, cover_end),
+                "group": (cover_end, self.t_loop_end)}
+
+
+class _Dispatch:
+    """One fused group's way through a cycle: ``union`` (the group's boxes
+    and block union) → ``prepare`` (padding, host→device puts) → ``launch``
+    (``disp()`` behind breaker and retries) on the collector; ``pickup`` (on
+    the ``_done`` queue) → ``delta`` → ``ready_wait`` (``np.asarray(out)``)
+    → ``resolve`` (result cache, ``set_result`` and its done-callbacks, up
+    to the last request's release) on the completer. Each stage ends where
+    the next begins."""
+
+    __slots__ = ("cycle", "batch_id", "size", "kernel", "tier", "union_tier",
+                 "rows_scanned", "first_call_s", "queue_depth", "threads",
+                 "t_union", "t_prepare", "t_launch", "t_put",
+                 "t_pickup", "t_wait", "t_ready", "t_resolved")
+
+    def __init__(self, cycle: _Cycle, batch_id: int, size: int):
+        self.cycle = cycle
+        self.batch_id = batch_id
+        self.size = size
+        self.kernel = None
+        self.tier = self.union_tier = self.rows_scanned = 0
+        # seconds of the program's first call when this launch made it
+        self.first_call_s: Optional[float] = None
+        self.queue_depth = self.threads = 0
+
+    def stages(self) -> Dict[str, Tuple[int, int]]:
+        return {"union": (self.t_union, self.t_prepare),
+                "prepare": (self.t_prepare, self.t_launch),
+                "launch": (self.t_launch, self.t_put),
+                "pickup": (self.t_put, self.t_pickup),
+                "delta": (self.t_pickup, self.t_wait),
+                "ready_wait": (self.t_wait, self.t_ready),
+                "resolve": (self.t_ready, self.t_resolved)}
+
+    def to_dict(self) -> dict:
+        """The dispatch and its cycle as the ``kind=batch`` flight event and
+        ``slow_cycles`` carry them: stages as ``[start (epoch ms), length
+        (ms)]``."""
+        c, ms = self.cycle, _trace.epoch_ms
+        return {
+            "batch_id": self.batch_id, "kernel": self.kernel,
+            "batch_size": self.size, "cycle_size": c.size,
+            "tier": self.tier, "union_tier": self.union_tier,
+            "rows_scanned": self.rows_scanned,
+            "stages": {k: [round(ms(a), 3), round((b - a) / 1e6, 3)]
+                       for k, (a, b) in {**c.stages(),
+                                         **self.stages()}.items()},
+            "launch_ms": round(ms(self.t_launch), 3),
+            "ready_ms": round(ms(self.t_ready), 3),
+            "plan_loop_cpu_ms": round(c.loop_cpu_ns / 1e6, 3),
+            "plan_misses": c.plan_misses, "cover_misses": c.cover_misses,
+            "first_call": self.first_call_s,
+            "queue_depth": self.queue_depth, "threads": self.threads}
 
 
 # -- the scheduler ------------------------------------------------------------
@@ -361,6 +490,10 @@ class QueryScheduler:
         self._n_batches = 0
         self._n_fused = 0
         self._n_single = 0
+        # completer-thread-only: the last cycles that took over
+        # _SLOW_CYCLE_S, whole (replaced, never mutated: readers take the
+        # reference)
+        self._slow_cycles: List[dict] = []
         self._running = True
         _metrics.set_gauge("scheduler.queue_depth", self._queue.qsize)
         # compile the fused single-dispatch program tiers for every bound
@@ -475,8 +608,15 @@ class QueryScheduler:
         / plan / scan leaves — a plan-cache hit shows NO plan span."""
         with _trace.trace("query.count", type=type_name, filter=str(f),
                           scheduled=True):
+            # under a caller's root (the REST span): the root has to say
+            # `scheduled` too, or its close derives a second flight event
+            _trace.mark_root(scheduled=True)
+            t0 = _pcn()
             req = self.submit(type_name, f, auths, deadline_ms=deadline_ms,
                               priority=priority, tenant=tenant)
+            if _trace.enabled():
+                t1 = _pcn()
+                _trace.record("submit", "submit", (t1 - t0) / 1e9, t1)
             return self._finish(req, timeout)
 
     def count_many(self, type_name: str, filters, auths: Optional[list] = None,
@@ -498,13 +638,7 @@ class QueryScheduler:
             return req.future.result(timeout=timeout)
         finally:
             if _trace.enabled():
-                if req.queue_wait_s is not None:
-                    _trace.record("queue_wait", "queue_wait",
-                                  req.queue_wait_s)
-                if req.plan_s is not None:
-                    _trace.record("plan", "plan", req.plan_s)
-                if req.scan_s is not None:
-                    _trace.record("scan", "scan", req.scan_s)
+                self._record_stages(req)
                 if req.cancelled:
                     # the trace-visible proof a timed-out query was dropped
                     # WITHOUT a device round trip: a cancel leaf and no scan
@@ -515,6 +649,41 @@ class QueryScheduler:
                     # trace-visible proof the hot answer came from memory:
                     # a cache leaf and NO queue_wait/plan/scan spans
                     _trace.record("result_cache", "cache_hit", 0.0)
+
+    @staticmethod
+    def _record_stages(req: Request) -> None:
+        """The request's stages into the caller's trace, from the instants
+        the collector and completer left on it. queue_wait, batch_host,
+        scan and wake follow one another, so with ``submit`` they partition
+        the latency. The request's own planning is part of batch_host's
+        interval and nests under it: ``plan``, and ``range_decompose`` where
+        the cover was computed and not found in the cover cache. Each feeds
+        its timer here and nowhere else (the collector calls the planner's
+        untimed entry points)."""
+        rec = _trace.record
+        closed, launch = req.t_closed, req.t_launch
+        if closed is not None:
+            rec("queue_wait", "queue_wait", req.queue_wait_s, closed)
+        if launch is not None:
+            host = rec("batch_host", "batch_host",
+                       (launch - closed) / 1e9, launch)
+            if req.t_plan is not None:
+                t0, t1, t2 = req.t_plan
+                if req.cover_cache_hit is False:
+                    rec("plan", "plan", (t1 - t0) / 1e9, t1, parent=host)
+                    rec("range_decompose", "range_decompose",
+                        (t2 - t1) / 1e9, t2, parent=host)
+                else:
+                    rec("plan", "plan", (t2 - t0) / 1e9, t2, parent=host)
+            if req.scan_s is not None:
+                resolved = launch + int(req.scan_s * 1e9)
+                rec("scan", "scan", req.scan_s, resolved,
+                    None if req.batch_id is None
+                    else {"batch_id": req.batch_id})
+                # resolved → this thread runs again: with many callers
+                # woken at once, their turn at the interpreter lock
+                now = _pcn()
+                rec("wake", "wake", (now - resolved) / 1e9, now)
 
     # -- resilience plumbing -------------------------------------------------
 
@@ -618,6 +787,7 @@ class QueryScheduler:
             "flush_reasons": dict(self._flush_reasons),
             "batch_size_hist": {str(k): v for k, v in
                                 sorted(self._batch_hist.items())},
+            "slow_cycles": self._slow_cycles,
             "plan_cache": self.plans.stats(),
             "cover_cache": self.covers.stats(),
             "result_cache": self.results.stats(),
@@ -650,7 +820,10 @@ class QueryScheduler:
 
     def _collect_loop(self) -> None:
         while True:
-            _, _, req = self._queue.get()
+            t_idle = _pcn()
+            with _annotate("sched.idle"):
+                _, _, req = self._queue.get()
+            cyc = _Cycle(t_idle, _pcn())
             _faults.serve_gate("sched.collect")
             if req is _STOP:
                 self._done.put(_STOP)
@@ -659,35 +832,38 @@ class QueryScheduler:
             t0 = _pc()
             reason = "window"
             stop = False
-            while len(batch) < self._flush_size:
-                remaining = self._window_us / 1e6 - (_pc() - t0)
-                if remaining <= 0:
-                    # window expired: drain whatever is ALREADY queued
-                    # (no extra wait) — a backlog that arrived during this
-                    # window must not fragment into the next one
+            with _annotate("sched.window"):
+                while len(batch) < self._flush_size:
+                    remaining = self._window_us / 1e6 - (_pc() - t0)
+                    if remaining <= 0:
+                        # window expired: drain whatever is ALREADY queued
+                        # (no extra wait) — a backlog that arrived during
+                        # this window must not fragment into the next one
+                        try:
+                            while len(batch) < self._flush_size:
+                                _, _, nxt = self._queue.get_nowait()
+                                if nxt is _STOP:
+                                    stop = True
+                                    break
+                                batch.append(nxt)
+                        except queue.Empty:
+                            pass
+                        break
                     try:
-                        while len(batch) < self._flush_size:
-                            _, _, nxt = self._queue.get_nowait()
-                            if nxt is _STOP:
-                                stop = True
-                                break
-                            batch.append(nxt)
+                        _, _, nxt = self._queue.get(timeout=remaining)
                     except queue.Empty:
-                        pass
-                    break
-                try:
-                    _, _, nxt = self._queue.get(timeout=remaining)
-                except queue.Empty:
-                    break
-                if nxt is _STOP:
-                    stop = True
-                    break
-                batch.append(nxt)
-            else:
-                reason = "size"
+                        break
+                    if nxt is _STOP:
+                        stop = True
+                        break
+                    batch.append(nxt)
+                else:
+                    reason = "size"
+            cyc.t_closed = _pcn()
+            cyc.size = len(batch)
             self._account(len(batch), reason)
             try:
-                self._dispatch(batch)
+                self._dispatch(batch, cyc)
             except Exception as e:  # never kill the loop: fail the batch
                 for r in batch:
                     self._fail(r, e)
@@ -701,7 +877,6 @@ class QueryScheduler:
         self._flush_reasons[reason] += 1
         self._batch_hist[n] = self._batch_hist.get(n, 0) + 1
         _metrics.observe_value("scheduler.batch_size", n)
-        _metrics.inc(f"scheduler.flush.{reason}")
         # adaptive window: see class docstring
         self._ema_batch = 0.8 * self._ema_batch + 0.2 * n
         if self._ema_batch <= 1.5:
@@ -709,10 +884,13 @@ class QueryScheduler:
         elif reason == "window" and self._ema_batch < self._flush_size / 2:
             self._window_us = min(self._max_window_us, self._window_us * 1.5)
 
-    def _plan_request(self, req: Request) -> None:
+    def _plan_request(self, req: Request, cyc: _Cycle) -> None:
         """Fill ``req.plan`` via the plan cache (auths-folded; cover cached
-        on the plan). A cache hit leaves ``req.plan_s`` None — the trace
-        shows no plan stage at all."""
+        on the plan). A cache hit leaves ``req.t_plan`` None — the trace
+        shows no plan stage at all. A miss is timed here, once: the
+        planner's untimed ``_plan`` / ``_pruned_blocks(timed=False)`` are
+        called, and the seconds reach the ``plan`` / ``range_decompose``
+        timers through the request's own trace (``_record_stages``)."""
         pkey = (req.epoch, req.type_name, req.generation, req.f_key,
                 req.auths_key)
         plan = self.plans.get(pkey)
@@ -721,11 +899,18 @@ class QueryScheduler:
             req.plan_cache_hit = True
             return
         req.plan_cache_hit = False
-        t0 = _pc()
+        t0 = _pcn()
         planner = req.planner
-        plan = planner._apply_auths(planner.plan(req.f_ir), req.auths)
+        plan = planner._apply_auths(planner._plan(req.f_ir), req.auths)
+        t1 = _pcn()
         self._fill_cover(req, plan, planner)
-        req.plan_s = _pc() - t0
+        t2 = _pcn()
+        req.t_plan = (t0, t1, t2)
+        cyc.plan_ns += t1 - t0
+        cyc.cover_ns += t2 - t1
+        cyc.plan_misses += 1
+        if req.cover_cache_hit is False:
+            cyc.cover_misses += 1
         req.plan = plan
         self.plans.put(pkey, plan)
 
@@ -748,19 +933,53 @@ class QueryScheduler:
             req.cover_cache_hit = True
             return
         req.cover_cache_hit = False
-        blocks = planner._pruned_blocks(plan)
+        blocks = planner._pruned_blocks(plan, timed=False)
         self.covers.put(ckey, blocks)
 
-    def _dispatch(self, batch: List[Request]) -> None:
+    def _dispatch(self, batch: List[Request], cyc: _Cycle) -> None:
         """Group a collected batch by fused-kernel compatibility and launch
         one async device dispatch per group; everything else falls back to
         per-query execution on the completer thread."""
+        groups: Dict[tuple, List[Request]] = {}
+        cpu0 = time.thread_time_ns()
+        cyc.t_loop = _pcn()
+        with _annotate("sched.plan_loop", n=len(batch)):
+            self._plan_loop(batch, cyc, groups)
+        cyc.t_loop_end = _pcn()
+        cyc.loop_cpu_ns = time.thread_time_ns() - cpu0
+        if _trace.enabled():
+            # one observation a cycle, before any of its groups is launched
+            # (a dispatch's own stages: the completer, in `_publish`)
+            _observe_stages(cyc.stages())
+            _metrics.inc("sched.plan_loop_cpu_us", cyc.loop_cpu_ns // 1000)
+        for gkey, grp in groups.items():
+            if len(grp) == 1 and grp[0].plan.blocks is not None \
+                    and len(grp[0].plan.blocks) == 0:
+                # provably-empty candidate set, nothing to dispatch
+                self._to_completer_single(grp[0])
+                continue
+            try:
+                self._dispatch_group(grp, gkey[-1], cyc)
+            except Exception as e:
+                for r in grp:
+                    self._fail(r, e)
+
+    def _to_completer_single(self, r: Request) -> None:
+        r.t_launch = _pcn()
+        self._done.put(("single", r))
+
+    def _plan_loop(self, batch: List[Request], cyc: _Cycle,
+                   groups: Dict[tuple, List[Request]]) -> None:
+        """The per-request part of a dispatch: deadline checks, plan and
+        cover through their caches, the fused-kernel group key. Python and
+        numpy only, nothing that should sleep."""
         from geomesa_tpu.index.scan import PRIMARY_FNS
 
-        groups: Dict[tuple, List[Request]] = {}
         degrade_floor = config.DEADLINE_DEGRADE_MS.get()
+        closed = cyc.t_closed
         for r in batch:
-            r.queue_wait_s = _pc() - r.t_submit
+            r.t_closed = closed
+            r.queue_wait_s = (closed - r.t_submit) / 1e9
             if r.deadline is not None:
                 rem = r.deadline.remaining_ms()
                 if rem < 0:
@@ -778,7 +997,7 @@ class QueryScheduler:
                         self._resolve(r, approx)
                         continue
             try:
-                self._plan_request(r)
+                self._plan_request(r, cyc)
             except Exception as e:  # parse/guard/plan errors fail one query
                 self._fail(r, e)
                 continue
@@ -800,57 +1019,55 @@ class QueryScheduler:
             else:
                 self._n_single += 1
                 _metrics.inc("scheduler.singles")
-                self._done.put(("single", r))
-        for gkey, grp in groups.items():
-            if len(grp) == 1 and grp[0].plan.blocks is not None \
-                    and len(grp[0].plan.blocks) == 0:
-                # provably-empty candidate set, nothing to dispatch
-                self._done.put(("single", grp[0]))
-                continue
-            try:
-                self._dispatch_group(grp, pruned=gkey[-1])
-            except Exception as e:
-                for r in grp:
-                    self._fail(r, e)
+                self._to_completer_single(r)
 
-    def _dispatch_group(self, grp: List[Request], pruned: bool) -> None:
+    def _dispatch_group(self, grp: List[Request], pruned: bool,
+                        cyc: _Cycle) -> None:
         """ONE async fused dispatch for a compatible group: per-query boxes
         stack into a (B, 8) array; pruned groups scan the union of their
         candidate blocks (the kernel re-applies the full exact mask, so the
         union cover stays a harmless superset)."""
         from geomesa_tpu.index import prune as _prune
+        from geomesa_tpu.index.scan import blocks_tier
 
         self._n_fused += len(grp)
         _metrics.inc("scheduler.fused", len(grp))
-        _metrics.observe_value("scheduler.fused_size", len(grp))
         lead = grp[0].plan
         kern = lead.index.kernels
-        boxes = np.concatenate([r.plan.boxes_loose for r in grp], axis=0)
         batch_id = next(self._batch_ids)
-        xfer = boxes.nbytes
-        if pruned:
-            nonempty = [r.plan.blocks for r in grp if len(r.plan.blocks)]
-            union = np.unique(np.concatenate(nonempty)).astype(np.int32) \
-                if nonempty else np.empty(0, dtype=np.int32)
-            rows_scanned = int(len(union)) * _prune.BLOCK_SIZE
-            xfer += union.nbytes
-            disp = kern.prepare_counts_multi_blocks(
-                lead.primary_kind, boxes, lead.windows, lead.residual_device,
-                union, _prune.BLOCK_SIZE)
-            kid = f"count_multi_blocks.{lead.primary_kind}"
-        else:
-            _cols = kern.cols
-            rows_scanned = int(next(iter(_cols.values())).shape[0]) \
-                if _cols else 0
-            disp = kern.prepare_counts_multi(
-                lead.primary_kind, boxes, lead.windows, lead.residual_device)
-            kid = f"count_multi.{lead.primary_kind}"
+        d = _Dispatch(cyc, batch_id, len(grp))
         # attribution tier = the padded batch size the dispatch shipped
-        tier = max(1, 1 << max(0, (len(grp) - 1)).bit_length())
-        _attrib.record_transfer(kid, tier, xfer)
+        d.tier = max(1, 1 << max(0, (len(grp) - 1)).bit_length())
+        d.t_union = _pcn()
+        with _annotate("sched.union", batch_id=batch_id):
+            boxes = np.concatenate([r.plan.boxes_loose for r in grp], axis=0)
+            xfer = boxes.nbytes
+            if pruned:
+                nonempty = [r.plan.blocks for r in grp if len(r.plan.blocks)]
+                union = np.unique(np.concatenate(nonempty)).astype(np.int32) \
+                    if nonempty else np.empty(0, dtype=np.int32)
+                d.rows_scanned = int(len(union)) * _prune.BLOCK_SIZE
+                d.union_tier = blocks_tier(len(union))
+                xfer += union.nbytes
+        d.t_prepare = _pcn()
+        with _annotate("sched.prepare", batch_id=batch_id):
+            if pruned:
+                disp = kern.prepare_counts_multi_blocks(
+                    lead.primary_kind, boxes, lead.windows,
+                    lead.residual_device, union, _prune.BLOCK_SIZE)
+                d.kernel = f"count_multi_blocks.{lead.primary_kind}"
+            else:
+                _cols = kern.cols
+                d.rows_scanned = int(next(iter(_cols.values())).shape[0]) \
+                    if _cols else 0
+                disp = kern.prepare_counts_multi(
+                    lead.primary_kind, boxes, lead.windows,
+                    lead.residual_device)
+                d.kernel = f"count_multi.{lead.primary_kind}"
+        _attrib.record_transfer(d.kernel, d.tier, xfer)
         for r in grp:
             r.batch_id = batch_id
-            r.rows_scanned = rows_scanned
+            r.rows_scanned = d.rows_scanned
         attempts = [0]
 
         def _launch():
@@ -858,15 +1075,20 @@ class QueryScheduler:
             _faults.serve_gate("sched.dispatch")
             return disp()  # async: enqueue only; the completer blocks for it
 
-        t0 = _pc()
+        d.t_launch = t_launch = _pcn()
         # the device boundary runs behind the breaker + capped-jitter
         # retries: transient dispatch failures retry (and count), a sick
         # device path opens the breaker and subsequent traffic fails fast
         # or degrades instead of piling on
-        out = retry_call(_launch, breaker=self.breaker)
+        with _annotate("sched.launch", batch_id=batch_id):
+            out = retry_call(_launch, breaker=self.breaker)
+        # the probe around a freshly built program timed its first call
+        d.first_call_s = _attrib.take_first_call()
         for r in grp:
             r.retries = attempts[0] - 1
-        self._done.put(("batch", out, grp, t0, (kid, tier, batch_id)))
+            r.t_launch = t_launch
+        d.t_put = _pcn()
+        self._done.put(("batch", out, grp, d))
 
     # -- completer thread ---------------------------------------------------
 
@@ -878,8 +1100,7 @@ class QueryScheduler:
             _faults.serve_gate("sched.complete")
             try:
                 if item[0] == "batch":
-                    self._complete_batch(item[1], item[2], item[3],
-                                         item[4] if len(item) > 4 else None)
+                    self._complete_batch(item[1], item[2], item[3])
                 else:
                     self._complete_single(item[1])
             except Exception as e:
@@ -887,49 +1108,82 @@ class QueryScheduler:
                 for r in reqs:
                     self._fail(r, e)
 
-    def _complete_batch(self, out, grp: List[Request], t0: float,
-                        attrib_key=None) -> None:
+    def _complete_batch(self, out, grp: List[Request], d: _Dispatch) -> None:
+        d.t_pickup = _pcn()
+        # as good as at launch (pickup follows it by ~0.3 ms), and read here
+        # because both take a lock the collector should not queue for
+        d.queue_depth = self._queue.qsize()
+        d.threads = threading.active_count()
+        batch_id = d.batch_id
         # host-side LSM-delta counts first: they overlap the in-flight
         # device round trip instead of adding to it
-        extras = [len(self.binding.delta_rows(r.delta, r.f_ir, r.auths))
-                  if r.delta is not None else 0 for r in grp]
-        _faults.serve_gate("sched.device_wait")
-        t_wait = _pc()
+        with _annotate("sched.delta", batch_id=batch_id):
+            extras = [len(self.binding.delta_rows(r.delta, r.f_ir, r.auths))
+                      if r.delta is not None else 0 for r in grp]
+            _faults.serve_gate("sched.device_wait")
+        d.t_wait = _pcn()
         try:
-            counts = np.asarray(out)  # blocks until the device batch is ready
+            with _annotate("sched.ready_wait", batch_id=batch_id):
+                # blocks until the device batch is ready
+                counts = np.asarray(out)
         except Exception:
             # a readback failure is a device-path failure too (the dispatch
             # already consumed its retries; the breaker learns either way)
             self.breaker.record_failure()
             raise
-        wait_s = _pc() - t_wait
-        scan_s = _pc() - t0
-        if attrib_key is not None:
-            kid, tier, batch_id = attrib_key
-            # per-kernel device attribution + the per-dispatch wide event
-            _attrib.record_dispatch(kid, tier, wait_s)
-            if config.OBS_ENABLED.get():
-                # a fused batch may mix admission classes/tenants: the
-                # event carries the distinct labels so the JSONL sink's
-                # batch rows are attributable like per-query rows
-                _flight.RECORDER.record({
-                    "kind": "batch", "batch_id": batch_id,
-                    "type": grp[0].type_name, "kernel": kid,
-                    "batch_size": len(grp),
-                    "priority": ",".join(sorted({r.priority
-                                                 for r in grp})),
-                    "tenant": ",".join(sorted({str(r.tenant or "default")
-                                               for r in grp})),
-                    "duration_ms": round(scan_s * 1000, 3),
-                    "device_ms": round(wait_s * 1000, 3),
-                    "rows_scanned": grp[0].rows_scanned})
-        for i, r in enumerate(grp):
-            r.batched = True
-            r.batch_size = len(grp)
-            r.scan_s = scan_s
-            n = int(counts[i]) + extras[i]
-            self._maybe_cache(r, n)
-            self._resolve(r, n)
+        d.t_ready = t_ready = _pcn()
+        # host time blocked on the read-back: an upper bound on what the
+        # device still had to do when the completer got here, never device
+        # time (a device trace gives that)
+        wait_s = (t_ready - d.t_wait) / 1e9
+        t_launch = d.t_launch
+        _attrib.record_dispatch(d.kernel, d.tier, wait_s)
+        last = len(grp) - 1
+        with _annotate("sched.resolve", batch_id=batch_id):
+            for i, r in enumerate(grp):
+                r.batched = True
+                r.batch_size = len(grp)
+                r.scan_s = (_pcn() - t_launch) / 1e9
+                n = int(counts[i]) + extras[i]
+                self._maybe_cache(r, n)
+                if i == last:
+                    # the dispatch's record goes out before its last
+                    # request is released: whoever holds every answer of a
+                    # dispatch finds its event and its timers. `resolve`
+                    # therefore ends one set_result short
+                    d.t_resolved = _pcn()
+                    self._publish(d, grp, wait_s)
+                self._resolve(r, n)
+
+    def _publish(self, d: _Dispatch, grp: List[Request],
+                 wait_s: float) -> None:
+        """A finished dispatch into the ``sched.stage.*`` timers, the
+        ``kind=batch`` flight event and, if its cycle took over
+        ``_SLOW_CYCLE_S`` from first dequeue to here, ``slow_cycles``."""
+        if _trace.enabled():
+            _observe_stages(d.stages())
+        slow = d.t_resolved - d.cycle.t_first > _SLOW_CYCLE_S * 1e9
+        obs = config.OBS_ENABLED.get()
+        if not (slow or obs):
+            return
+        cycle = d.to_dict()
+        if slow:
+            self._slow_cycles = (self._slow_cycles + [cycle])[
+                -_SLOW_CYCLES_KEPT:]
+        if obs:
+            # the per-dispatch wide event. A fused batch may mix admission
+            # classes/tenants: the event carries the distinct labels so the
+            # JSONL sink's batch rows are attributable like per-query rows.
+            # `duration_ms` is launch → read back; `device_ms` is the host
+            # blocked on the read-back, never device time
+            _flight.RECORDER.record({
+                "kind": "batch", **cycle,
+                "type": grp[0].type_name,
+                "priority": ",".join(sorted({r.priority for r in grp})),
+                "tenant": ",".join(sorted({str(r.tenant or "default")
+                                           for r in grp})),
+                "duration_ms": round((d.t_ready - d.t_launch) / 1e6, 3),
+                "device_ms": round(wait_s * 1000, 3)})
 
     def _complete_single(self, r: Request) -> None:
         """Fallback execution for plans the fused kernel can't serve (host
@@ -941,7 +1195,6 @@ class QueryScheduler:
         if r.deadline is not None and r.deadline.expired:
             self._cancel(r, "single")
             return
-        t0 = _pc()
         try:
             _faults.serve_gate("sched.single")
             with _rdl.use(r.deadline):
@@ -960,6 +1213,6 @@ class QueryScheduler:
         except Exception as e:
             self._fail(r, e)
             return
-        r.scan_s = _pc() - t0
+        r.scan_s = (_pcn() - r.t_launch) / 1e9
         self._maybe_cache(r, int(n))
         self._resolve(r, int(n))

@@ -13,10 +13,16 @@ Span kinds (the fixed vocabulary hot paths use):
 
   plan             filter parse + strategy selection
   range_decompose  key-range → candidate-block cover computation
-  queue_wait       time spent queued in the micro-batching scheduler before
-                   its batch dispatched (serve/scheduler.py)
+  submit           scheduled count, on the caller's thread: filter parse,
+                   Request, result-cache probe, admission (serve/scheduler.py)
+  queue_wait       scheduled count: submit → its micro-batch closed
+  batch_host       scheduled count: batch closed → device launch (the
+                   collector planning the whole batch; the request's own
+                   ``plan`` / ``range_decompose`` nest under it)
   scan             umbrella execution stage (staging + kernel + readback);
-                   its SELF time is constant staging / host glue
+                   its SELF time is constant staging / host glue. On a
+                   scheduled count: launch → resolved, ``batch_id`` in attrs
+  wake             scheduled count: resolved → the caller's thread runs again
   device_scan      kernel dispatch (host-side enqueue, async)
   device_wait      block_until_ready on the dispatched result
   refine           host f64 re-evaluation of device candidates
@@ -40,6 +46,13 @@ timer under its name, so the Prometheus surface gets per-stage percentiles
 for free — spans REPLACE the ad-hoc ``REGISTRY.time(...)`` calls on the hot
 paths. ``trace()`` nests: opened under an active trace it degrades to a
 plain span, so datastore-level and planner-level roots compose.
+
+Clock: every span holds its start on ``time.perf_counter_ns``; ``ANCHOR``
+pairs one reading of that clock with ``time.time_ns`` at import, so
+``epoch_ms(ns)`` puts any span — and the scheduler's dispatch-cycle stages,
+which run on threads with no active trace — on the wall clock the flight
+recorder stamps ``ts_ms`` with. ``to_dict`` gives ``start_ms`` relative to
+the root.
 
 Thread model: the current trace is thread-local (one query per thread, the
 ThreadingHTTPServer model); the ring buffer is process-global and locked.
@@ -66,7 +79,8 @@ from typing import Dict, Iterator, List, Optional
 
 from geomesa_tpu.metrics import REGISTRY as _REGISTRY
 
-SPAN_KINDS = ("plan", "range_decompose", "queue_wait", "scan", "device_scan",
+SPAN_KINDS = ("plan", "range_decompose", "submit", "queue_wait", "batch_host",
+              "scan", "wake", "device_scan",
               "device_wait", "refine", "aggregate", "serialize",
               "wal_append", "wal_fsync", "recovery",
               # query-lifecycle resilience (serve/resilience/): a request
@@ -83,7 +97,17 @@ SPAN_KINDS = ("plan", "range_decompose", "queue_wait", "scan", "device_scan",
               # show where a distributed query's wall time went
               "collective")
 
-_pc = time.perf_counter  # cached: spans sit on µs-scale hot paths
+_pcn = time.perf_counter_ns  # cached: spans sit on µs-scale hot paths
+
+# (perf_counter_ns, time_ns) read together once: the one bridge between the
+# span clock and the wall clock
+ANCHOR = (time.perf_counter_ns(), time.time_ns())
+
+
+def epoch_ms(ns: int) -> float:
+    """Wall-clock milliseconds of a ``perf_counter_ns`` reading."""
+    return (ANCHOR[1] + (ns - ANCHOR[0])) / 1e6
+
 
 class _Local(threading.local):
     # class-level defaults make `_local.trace` a plain read on threads that
@@ -266,14 +290,16 @@ class Span:
     None until the first child attaches (most spans are leaves; the lazy
     list keeps leaf allocation to one object on the hot path)."""
 
-    __slots__ = ("name", "kind", "attrs", "duration_ms", "children",
-                 "span_id")
+    __slots__ = ("name", "kind", "attrs", "start_ns", "duration_ms",
+                 "children", "span_id")
 
     def __init__(self, name: str, kind: Optional[str], attrs: Optional[dict]):
         self.name = name
         self.kind = kind if kind is not None else (
             name if name in SPAN_KINDS else "span")
         self.attrs = attrs
+        # perf_counter_ns at entry; None on a leaf recorded without its end
+        self.start_ns: Optional[int] = None
         self.duration_ms = 0.0
         self.children: Optional[List[Span]] = None
         # assigned on demand (inject_headers) when this span parents a
@@ -299,16 +325,22 @@ class Span:
             for c in self.children:
                 yield from c.walk()
 
-    def to_dict(self) -> dict:
+    def to_dict(self, origin_ns: Optional[int] = None) -> dict:
+        """``start_ms`` is relative to ``origin_ns`` — the root's start, which
+        the root passes down; called on its own a span is its own origin."""
+        if origin_ns is None:
+            origin_ns = self.start_ns
         d = {"name": self.name, "kind": self.kind,
              "duration_ms": round(self.duration_ms, 3),
              "self_ms": round(self.self_ms, 3)}
+        if self.start_ns is not None and origin_ns is not None:
+            d["start_ms"] = round((self.start_ns - origin_ns) / 1e6, 3)
         if self.span_id is not None:
             d["span_id"] = self.span_id
         if self.attrs:
             d["attrs"] = {k: str(v) for k, v in self.attrs.items()}
         if self.children:
-            d["children"] = [c.to_dict() for c in self.children]
+            d["children"] = [c.to_dict(origin_ns) for c in self.children]
         return d
 
 
@@ -456,6 +488,17 @@ def current_trace() -> Optional[QueryTrace]:
     return _local.trace
 
 
+def mark_root(**attrs) -> None:
+    """Set attributes on the active trace's ROOT from a stage nested in it:
+    what close hooks decide on (``scheduled``) is read from the root."""
+    tr = _local.trace
+    if tr is not None:
+        if tr.root.attrs is None:
+            tr.root.attrs = attrs
+        else:
+            tr.root.attrs.update(attrs)
+
+
 class span:
     """Context manager timing one stage. Attaches to the active trace (when
     one exists) and feeds the metrics registry under ``name`` either way —
@@ -482,22 +525,24 @@ class span:
             self._node = node
         else:
             self._node = None
-        self._t0 = _pc()
+        self._t0 = _pcn()
         return self
 
     def __exit__(self, *exc):
-        if self._t0 is None:
+        t0 = self._t0
+        if t0 is None:
             return False
-        dt = _pc() - self._t0
+        dt = _pcn() - t0
         node = self._node
         if node is not None:
             # under an active trace the registry feed is DEFERRED to trace
             # close (one batched lock acquisition for the whole span tree),
             # keeping per-span exit cost to pure bookkeeping
-            node.duration_ms = dt * 1000
+            node.start_ns = t0
+            node.duration_ms = dt / 1e6
             _local.stack.pop()
         else:
-            _REGISTRY.observe(self.name, dt)
+            _REGISTRY.observe(self.name, dt / 1e9)
         return False
 
 
@@ -505,27 +550,42 @@ def enabled() -> bool:
     return _state.enabled
 
 
-def _leaf(name: str, kind: str, duration_ms: float) -> Span:
+def _leaf(name: str, kind: str, duration_ms: float,
+          start_ns: Optional[int] = None,
+          attrs: Optional[dict] = None) -> Span:
     """Allocate a completed leaf span without the __init__ frame (hot path)."""
     s = Span.__new__(Span)
     s.name = name
     s.kind = kind
-    s.attrs = None
+    s.attrs = attrs
+    s.start_ns = start_ns
     s.duration_ms = duration_ms
     s.children = None
     s.span_id = None
     return s
 
 
-def record(name: str, kind: str, seconds: float) -> None:
-    """Record an already-timed LEAF stage (no children) without context
-    manager dispatch — the minimal-overhead hook for µs-scale hot paths.
-    Callers gate their own timing on ``enabled()``."""
-    tr = _local.trace
-    if tr is not None:
-        _local.stack[-1].add_child(_leaf(name, kind, seconds * 1000))
-    else:
-        _REGISTRY.observe(name, seconds)
+def record(name: str, kind: str, seconds: float,
+           end_ns: Optional[int] = None, attrs: Optional[dict] = None,
+           parent: Optional[Span] = None) -> Optional[Span]:
+    """Record an already-timed stage without context manager dispatch — the
+    minimal-overhead hook for µs-scale hot paths. Callers gate their own
+    timing on ``enabled()``. ``end_ns`` is the ``perf_counter_ns`` reading
+    the caller already took at the stage's end: the start is that minus the
+    duration, so a start costs no clock call here. The span hangs under
+    ``parent`` (a span an earlier ``record`` returned), else under the
+    innermost open span; with neither, the seconds go straight to the
+    registry and None is returned."""
+    if parent is None:
+        if _local.trace is None:
+            _REGISTRY.observe(name, seconds)
+            return None
+        parent = _local.stack[-1]
+    node = _leaf(name, kind, seconds * 1000,
+                 None if end_ns is None else end_ns - int(seconds * 1e9),
+                 attrs)
+    parent.add_child(node)
+    return node
 
 
 def device_fetch(block, dispatch, *args):
@@ -535,25 +595,41 @@ def device_fetch(block, dispatch, *args):
     is single-digit µs — see tests/test_perf_budget.py)."""
     if not _state.enabled:
         return block(dispatch(*args))
-    t0 = _pc()
+    t0 = _pcn()
     out = dispatch(*args)
-    t1 = _pc()
+    t1 = _pcn()
     out = block(out)
-    t2 = _pc()
+    t2 = _pcn()
     hook = _device_hook
     if hook is not None:
-        hook(t1 - t0, t2 - t1)
+        hook((t1 - t0) / 1e9, (t2 - t1) / 1e9)
     tr = _local.trace
     if tr is not None:
         parent = _local.stack[-1]
         parent.add_child(_leaf("device_scan", "device_scan",
-                               (t1 - t0) * 1000))
+                               (t1 - t0) / 1e6, t0))
         parent.add_child(_leaf("device_wait", "device_wait",
-                               (t2 - t1) * 1000))
+                               (t2 - t1) / 1e6, t1))
     else:
         _REGISTRY.observe_batch(
-            [("device_scan", t1 - t0), ("device_wait", t2 - t1)])
+            [("device_scan", (t1 - t0) / 1e9),
+             ("device_wait", (t2 - t1) / 1e9)])
     return out
+
+
+_annotation_cls = None
+
+
+def annotation(name: str, **kw):
+    """``jax.profiler.TraceAnnotation(name, **kw)``: a host span in the
+    profiler's own trace, on the device trace's clock. Imported on first use
+    (this module stays free of jax at import); outside a profiler session
+    entering one is a flag check."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls(name, **kw)
 
 
 class trace:
@@ -593,7 +669,7 @@ class trace:
         _local.trace = t
         _local.stack = [t.root]
         self._trace = t
-        self._t0 = _pc()
+        self._t0 = t.root.start_ns = _pcn()
         return t
 
     def __exit__(self, *exc):
@@ -601,21 +677,22 @@ class trace:
             return self._span.__exit__(*exc)
         if self._t0 is None:
             return False
-        dt = _pc() - self._t0
         t = self._trace
-        t.root.duration_ms = dt * 1000
+        t.root.duration_ms = (_pcn() - self._t0) / 1e6
         if exc and exc[0] is not None:
             t.error = exc[0].__name__
         _local.trace = None
         _local.stack = None
         RING.append(t)
-        # deferred feed: the whole span tree drains into the histograms at
-        # the next snapshot — trace close pays one list append. The trace id
-        # rides along so retained traces become bucket exemplars at drain.
-        _REGISTRY.feed_tree(t.root, trace_id=t.trace_id)
         for hook in _close_hooks:
             try:
                 hook(t)
             except Exception:
                 pass  # observability must never fail the query
+        # deferred feed: the whole span tree drains into the histograms at
+        # the next snapshot — trace close pays one list append. The trace id
+        # rides along so retained traces become bucket exemplars at drain
+        # (after the hooks: the tail sampler has to know the trace by then,
+        # should this feed be the one that drains).
+        _REGISTRY.feed_tree(t.root, trace_id=t.trace_id)
         return False

@@ -41,11 +41,22 @@ Routes:
                                          _bucket lines carry exemplar trace
                                          ids where a retained trace exists)
   GET  /traces?limit=N                 → recent query traces, newest first
+                                         (every span with ``start_ms`` from
+                                         its root; a scheduled count's
+                                         ``scan`` names its ``batch_id``)
   GET  /traces?retained=1              → the tail-sampled ring (errors, slow
                                          outliers, sampled rest)
   GET  /events?slow_ms=&error=1&kind=&type=&limit=
                                        → flight-recorder wide events (one
-                                         per query/count/batch), filtered
+                                         per query/count/batch), filtered.
+                                         ``device_ms`` is HOST time blocked
+                                         until the answer was read back,
+                                         never time on the device; a
+                                         ``kind=batch`` event holds the
+                                         dispatch cycle: ``stages`` (name →
+                                         [start epoch ms, ms]),
+                                         ``launch_ms``, ``ready_ms``,
+                                         ``plan_loop_cpu_ms``, ``first_call``
   GET  /slo                            → SLO burn-rate evaluation (5m/30m/
                                          1h/6h windows, page/ticket state)
   GET  /alerts                         → fleet-doctor detector firings
@@ -56,7 +67,9 @@ Routes:
                                          (index-build encode/upload/sort
                                          with row throughput)
   GET  /scheduler                      → scheduler state (queue depth, batch
-                                         histogram, cache hit rates)
+                                         histogram, cache hit rates, and
+                                         ``slow_cycles``: the last 8 dispatch
+                                         cycles that took over 1 s, whole)
   GET  /durability                     → WAL/snapshot status (policy, seq,
                                          unsynced bytes, last-snapshot age)
   GET  /replication                    → fleet role + fencing epoch +
@@ -664,6 +677,16 @@ class GeoJsonApi:
         return len(feats)
 
 
+def _route_family(path: str) -> str:
+    """``count`` for /types/{t}/count, else the first path word: the name
+    an ``http.request.*`` timer carries, so that polls of /metrics and
+    /events never dilute the count route's."""
+    parts = [p for p in path.split("/") if p]
+    if len(parts) == 3 and parts[0] == "types" and parts[2] == "count":
+        return "count"
+    return re.sub(r"\W", "_", parts[0]) if parts else "root"
+
+
 class _Handler(BaseHTTPRequestHandler):
     api: GeoJsonApi = None  # set by serve()
 
@@ -692,23 +715,40 @@ class _Handler(BaseHTTPRequestHandler):
         """Route + respond inside a last-resort guard: NOTHING a route
         raises may kill the handler thread and reset the client connection
         — an unexpected error becomes a structured 500 envelope (the
-        kind/status mapping itself lives in GeoJsonApi.handle)."""
-        try:
-            u = urlparse(self.path)
-            body = None
-            if method == "POST":
-                length = int(self.headers.get("Content-Length", 0))
-                body = self.rfile.read(length) if length else b""
-            status, payload = self.api.handle(method, u.path,
-                                              parse_qs(u.query), body,
-                                              headers=self.headers)
-        except Exception as e:  # handle() failed outside its own guards
-            status, payload = 500, {"error": str(e), "kind": "internal",
-                                    "type": type(e).__name__}
-        try:
-            self._respond(status, payload)
-        except (BrokenPipeError, ConnectionResetError):
-            pass  # client went away; the server thread must survive it
+        kind/status mapping itself lives in GeoJsonApi.handle).
+
+        Timed from the parsed request line to the flushed response as
+        ``http.request.<route family>`` (``count`` for /types/{t}/count,
+        else the first path word), with ``http.respond`` (JSON encoding and
+        the socket write) inside it. On the count route it is the root of
+        the request's trace, the scheduler's ``query.count`` nests under
+        it, and REST's own time is the root's self time; other routes keep
+        their own roots (``query.features``, ``explain``) and get the two
+        as flat timers."""
+        from geomesa_tpu import trace as _trace
+        u = urlparse(self.path)
+        family = _route_family(u.path)
+        timed = _trace.trace if family == "count" else _trace.span
+        # the root below is the upstream hop's child when the request
+        # carries trace context (handle() binds it again for its own roots)
+        with _trace.remote_parent(_trace.extract_headers(self.headers)), \
+                timed("http.request." + family):
+            try:
+                body = None
+                if method == "POST":
+                    length = int(self.headers.get("Content-Length", 0))
+                    body = self.rfile.read(length) if length else b""
+                status, payload = self.api.handle(method, u.path,
+                                                  parse_qs(u.query), body,
+                                                  headers=self.headers)
+            except Exception as e:  # handle() failed outside its own guards
+                status, payload = 500, {"error": str(e), "kind": "internal",
+                                        "type": type(e).__name__}
+            with _trace.span("http.respond"):
+                try:
+                    self._respond(status, payload)
+                except (BrokenPipeError, ConnectionResetError):
+                    pass  # client went away; the server thread must survive
 
     def do_GET(self):
         self._serve("GET")

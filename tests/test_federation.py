@@ -694,8 +694,9 @@ def _write_artifact(stitched):
 
 def test_two_process_propagation_one_stitched_trace(tmp_path):
     """A routed query against a REAL serving subprocess yields ONE
-    stitched trace: the remote process's query.count root is a child of
-    this process's proxy span, with the network hop explicit."""
+    stitched trace: the remote process's root (the REST span, with its
+    query.count inside) is a child of this process's proxy span, with the
+    network hop explicit."""
     pdir = str(tmp_path / "node")
     store = TpuDataStore.open(pdir, params={"wal.fsync": "off"})
     store.create_schema("t", SPEC)
@@ -731,7 +732,10 @@ def test_two_process_propagation_one_stitched_trace(tmp_path):
         assert hop["network_ms"] is not None and hop["network_ms"] >= 0
         remote_roots = [t for t in halves if t["node"] == "srv1"]
         assert remote_roots[0]["parent"]["trace"] == gid
-        assert remote_roots[0]["name"] == "query.count"
+        # the REST span is the remote root; the scheduler's count under it
+        assert remote_roots[0]["name"] == "http.request.count"
+        assert "query.count" in [
+            c["name"] for c in remote_roots[0]["root"]["children"]]
         # the remote half contains real serving spans (scan/plan/etc.)
         assert remote_roots[0]["stages_ms"], remote_roots[0]
         _write_artifact({"stitched": st, "halves": halves})

@@ -8,6 +8,7 @@ with hand-set durations, and nothing sleeps.
 """
 
 import json
+import time
 import urllib.parse
 import urllib.request
 
@@ -530,11 +531,25 @@ def _parse_exposition(text):
 def test_prometheus_exposition_conformance(server):
     base, ds = server
     q = urllib.parse.quote("BBOX(geom, -5, -5, 5, 5)")
+
+    def rest_counts():
+        return REGISTRY.snapshot()["timers"].get(
+            "http.request.count", {}).get("count", 0)
+
+    n0 = rest_counts()
     for _ in range(3):
         _get(f"{base}/types/obs_t/count?cql={q}")
+    # a count's REST root closes once its response is flushed, after the
+    # client has it: let the last one land before the two reads
+    deadline = time.time() + 5
+    while rest_counts() < n0 + 3 and time.time() < deadline:
+        time.sleep(0.005)
     with urllib.request.urlopen(f"{base}/metrics?format=prometheus") as r:
         text = r.read().decode()
     status, snap = _get(f"{base}/metrics")
+    # a scrape is a timed request too: it moves its own two timers
+    for own in ("http.request.metrics", "http.respond"):
+        snap["timers"].pop(own, None)
 
     types, samples = _parse_exposition(text)  # asserts no duplicate TYPEs
 

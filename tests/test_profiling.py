@@ -135,9 +135,11 @@ def test_kernel_handicap_stretches_matching_kernels(store):
         profiling.reset_kernel_handicap()
         kern2 = ScanKernels(store.planner("prof_t").indexes[0].kernels.cols)
         kern2.count("point_boxes", b, None, None)
-        t0 = time.perf_counter()
-        kern2.count("point_boxes", b, None, None)
-        plain = time.perf_counter() - t0
+        plain = float("inf")
+        for _ in range(3):   # the least of three: one descheduling is noise
+            t0 = time.perf_counter()
+            kern2.count("point_boxes", b, None, None)
+            plain = min(plain, time.perf_counter() - t0)
         # 50x handicap dominates scheduler noise even on a loaded host
         assert stretched > 5 * plain, (stretched, plain)
     finally:

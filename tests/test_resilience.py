@@ -423,6 +423,10 @@ def test_killed_collector_fails_outstanding_futures_promptly(store):
     s = QueryScheduler(StoreBinding(store), flush_size=64, window_us=50_000)
     try:
         faults.arm_serve_crash("sched.collect", at=1)
+        # the gate sleeps before it crashes: on a loaded host the collector
+        # would otherwise die between two of the submits below, and the
+        # later ones be refused instead of left outstanding
+        faults.arm_serve_delay("sched.collect", seconds=0.1, n=1)
         reqs = [s.submit("t", f"BBOX(geom, {-10 - i}, 5, 10, 25) AND "
                               f"{DURING}") for i in range(4)]
         t0 = time.perf_counter()
