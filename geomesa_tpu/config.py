@@ -144,11 +144,6 @@ SCHED_PLAN_CACHE = _register(
     "Plan-cache capacity (normalized filter + generation + auths -> plan). "
     "0 disables plan caching.")
 
-SCHED_COVER_CACHE = _register(
-    "GEOMESA_TPU_SCHED_COVER_CACHE", 256, int,
-    "Cover-cache capacity (boxes/windows -> candidate gather blocks). "
-    "0 disables cover caching.")
-
 WAL_FSYNC = _register(
     "GEOMESA_TPU_WAL_FSYNC", "batch", str,
     "Write-ahead-log fsync policy: off (OS page cache only — survives "

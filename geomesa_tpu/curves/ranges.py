@@ -74,30 +74,31 @@ def merge_range_arrays(lo: np.ndarray, hi: np.ndarray, cont: np.ndarray):
 
 
 def _zranges_arrays(
-    boxes: Sequence[Sequence[Tuple[int, int]]],
+    blo: np.ndarray,
+    bhi: np.ndarray,
     bits: int,
-    dims: int,
     max_ranges: int,
     max_levels: int,
 ):
     """Generic D-dimensional Morton cover → merged (lo, hi, contained)
     inclusive z-interval arrays covering the union of boxes.
 
-    boxes: per-box, per-dim inclusive int bounds [(lo, hi), ...] in
-    normalized int space. The native C++ pass (gm_zranges) runs when
+    blo / bhi: (n_boxes, D) inclusive int64 bounds in normalized int space,
+    D = 2 or 3. The native C++ pass (gm_zranges) runs when
     available (~50us — the cover sits on the cold-query planning path);
     the fallback is a level-synchronous vectorized numpy BFS. Budget rule
     mirrors sfcurve's maxRanges stop: when expanding the next level would
     exceed the budget, remaining overlapping cells flush as coarse
-    (uncontained) ranges.
+    (uncontained) ranges. The budget is for the whole cover, however many
+    boxes it unions (≙ geomesa.scan.ranges.target, per scan).
     """
-    if not boxes:
+    blo = np.asarray(blo, dtype=np.int64)
+    bhi = np.asarray(bhi, dtype=np.int64)
+    if len(blo) == 0:
         return _EMPTY_COVER
+    dims = blo.shape[1]
     interleave = {2: zorder.z2_encode, 3: zorder.z3_encode}[dims]
     max_levels = min(max_levels, bits)
-
-    blo = np.array([[d[0] for d in b] for b in boxes], dtype=np.int64)  # (B,D)
-    bhi = np.array([[d[1] for d in b] for b in boxes], dtype=np.int64)
 
     from geomesa_tpu import native
     res = native.zranges(blo, bhi, dims, bits, max_ranges, max_levels)
@@ -159,13 +160,11 @@ def to_ranges(arrays) -> List[IndexRange]:
             for l, h, c in zip(lo, hi, cont)]
 
 
-def _reshape_2d(boxes):
-    return [((xlo, xhi), (ylo, yhi)) for xlo, ylo, xhi, yhi in boxes]
-
-
-def _reshape_3d(boxes):
-    return [((xlo, xhi), (ylo, yhi), (tlo, thi))
-            for xlo, ylo, tlo, xhi, yhi, thi in boxes]
+def _corners(boxes, dims: int):
+    """(lo_0..lo_D-1, hi_0..hi_D-1) rows → the (n, D) lower and upper
+    corner arrays."""
+    b = np.asarray(boxes, dtype=np.int64).reshape(-1, 2 * dims)
+    return b[:, :dims], b[:, dims:]
 
 
 def zranges_2d(
@@ -192,10 +191,10 @@ def zranges_2d_arrays(boxes, bits: int = 31, max_ranges: int = 2000,
                       max_levels: int = 64):
     """Array-form 2-D cover: merged (lo, hi, contained) — the hot-path form
     consumed directly by prune.ranges_to_slices."""
-    return _zranges_arrays(_reshape_2d(boxes), bits, 2, max_ranges, max_levels)
+    return _zranges_arrays(*_corners(boxes, 2), bits, max_ranges, max_levels)
 
 
 def zranges_3d_arrays(boxes, bits: int = 21, max_ranges: int = 2000,
                       max_levels: int = 64):
     """Array-form 3-D cover: merged (lo, hi, contained)."""
-    return _zranges_arrays(_reshape_3d(boxes), bits, 3, max_ranges, max_levels)
+    return _zranges_arrays(*_corners(boxes, 3), bits, max_ranges, max_levels)

@@ -64,11 +64,10 @@ class Z2SFC:
                       max_levels: int = 64):
         """Array-form cover (lo, hi, contained) — the query-planning hot
         path (feeds prune.ranges_to_slices without per-range objects)."""
-        boxes = []
-        for xmin, ymin, xmax, ymax in xy:
-            xlo, ylo = self.normalize(xmin, ymin)
-            xhi, yhi = self.normalize(xmax, ymax)
-            boxes.append((int(xlo), int(ylo), int(xhi), int(yhi)))
+        xy = np.asarray(xy, dtype=np.float64).reshape(-1, 4)
+        xlo, ylo = self.normalize(xy[:, 0], xy[:, 1])
+        xhi, yhi = self.normalize(xy[:, 2], xy[:, 3])
+        boxes = np.stack([xlo, ylo, xhi, yhi], axis=1)
         return zranges_2d_arrays(boxes, self.precision, max_ranges or 2000,
                                  max_levels)
 
@@ -148,13 +147,15 @@ class Z3SFC:
                       max_levels: int = 64):
         """Array-form cover (lo, hi, contained) — the query-planning hot
         path (feeds prune.ranges_to_slices without per-range objects)."""
-        boxes = []
-        for xmin, ymin, xmax, ymax in xy:
-            xlo, ylo = self.lon.normalize(xmin), self.lat.normalize(ymin)
-            xhi, yhi = self.lon.normalize(xmax), self.lat.normalize(ymax)
-            for tmin, tmax in t:
-                tlo, thi = self.time.normalize(tmin), self.time.normalize(tmax)
-                boxes.append((int(xlo), int(ylo), int(tlo),
-                              int(xhi), int(yhi), int(thi)))
+        xy = np.asarray(xy, dtype=np.float64).reshape(-1, 4)
+        t = np.asarray(t, dtype=np.float64).reshape(-1, 2)
+        # every box against every window: (B * T, 6) corner rows
+        n_xy, n_t = len(xy), len(t)
+        xy, t = np.repeat(xy, n_t, axis=0), np.tile(t, (n_xy, 1))
+        lon, lat, time = self.lon, self.lat, self.time
+        boxes = np.stack(
+            [lon.normalize(xy[:, 0]), lat.normalize(xy[:, 1]),
+             time.normalize(t[:, 0]), lon.normalize(xy[:, 2]),
+             lat.normalize(xy[:, 3]), time.normalize(t[:, 1])], axis=1)
         return zranges_3d_arrays(boxes, self.precision, max_ranges or 2000,
                                  max_levels)

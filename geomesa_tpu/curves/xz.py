@@ -112,12 +112,9 @@ class XZSFC:
         queries: each (min_0..min_D-1, max_0..max_D-1) in user space.
         """
         max_ranges = max_ranges or (1 << 62)
-        windows = []
-        for q in queries:
-            mins = np.asarray(q[: self.dims], dtype=np.float64)
-            maxs = np.asarray(q[self.dims:], dtype=np.float64)
-            nmins, nmaxs = self._normalize(mins[None, :], maxs[None, :], lenient=False)
-            windows.append((nmins[0], nmaxs[0]))
+        q = np.asarray(queries, dtype=np.float64).reshape(-1, 2 * self.dims)
+        wmins, wmaxs = self._normalize(q[:, : self.dims], q[:, self.dims:],
+                                       lenient=False)   # (W, D) each
 
         out: List[IndexRange] = []
 
@@ -162,17 +159,10 @@ class XZSFC:
             cell_lo, level = queue.popleft()
             side = 0.5 ** level
             ext_hi = cell_lo + 2 * side  # enlarged element upper corner
-            cell_hi = cell_lo + side
-            contained = overlapped = False
-            for wmin, wmax in windows:
-                if np.all(wmin <= cell_lo) and np.all(wmax >= ext_hi):
-                    contained = True
-                    break
-                if np.all(wmax >= cell_lo) and np.all(wmin <= ext_hi):
-                    overlapped = True
-            if contained:
+            # one pass over all windows, however many boxes the cover unions
+            if ((wmins <= cell_lo) & (wmaxs >= ext_hi)).all(axis=1).any():
                 emit(cell_lo, level, True)
-            elif overlapped:
+            elif ((wmaxs >= cell_lo) & (wmins <= ext_hi)).all(axis=1).any():
                 emit(cell_lo, level, False)
                 if level < self.g and len(out) < max_ranges:
                     half = side / 2.0

@@ -137,7 +137,7 @@ class TpuDataStore:
         self._interceptors: Dict[str, list] = {}
         # per-type mutation generation (serve-path cache invalidation): every
         # ingest/flush/age-off/update/delete/schema-change bumps it, so a
-        # plan or cover cached against generation g is unreachable once the
+        # plan cached against generation g is unreachable once the
         # data it described has changed. Monotonic per NAME — it survives
         # remove_schema so a re-created type can't resurrect stale plans.
         self._generations: Dict[str, int] = {}
@@ -823,7 +823,7 @@ class TpuDataStore:
                    tenant: Optional[str] = None) -> List[int]:
         """Counts for many filters through the scheduler: compatible queries
         fuse into single batched device dispatches; repeated/parameterized
-        filters hit the plan/cover caches. Order-preserving. ``deadline_ms``
+        filters hit the plan cache. Order-preserving. ``deadline_ms``
         bounds every count in the set; ``priority`` classes the work for
         admission control ('interactive' | 'batch'); ``tenant`` labels it
         for workload analytics/metering (auths-derived when omitted)."""

@@ -242,7 +242,7 @@ class QueryPlanner:
                 "host_ms": round(max(0.0, t.duration_ms - device_ms), 3),
                 "stages_ms": {k: round(v, 3) for k, v in stages.items()},
                 # direct-path execution never serves from the scheduler's
-                # plan/cover caches; the store-level explain overlays the
+                # plan cache; the store-level explain overlays the
                 # live scheduler's provenance when one is running
                 "provenance": {"plan": "fresh",
                                "cover": "fresh" if blocks is not None
@@ -305,13 +305,12 @@ class QueryPlanner:
 
     # -- range pruning -------------------------------------------------------
 
-    def _pruned_blocks(self, plan: IndexScanPlan, timed: bool = True):
+    def _pruned_blocks(self, plan: IndexScanPlan):
         """Candidate gather-blocks for a plan (cached on the plan), or None
         when the full-table fused mask is the better scan. ≙ choosing ranged
         scans over a full-table scan (QueryProperties.BlockFullTableScans).
-        ``timed=False``: the caller times the cover itself (the scheduler's
-        collector, which hands the seconds to the request's own trace), so
-        ``range_decompose`` is not observed a second time here."""
+        The scheduler's fused groups do not come here: a group gets one cover
+        for all its members' boxes (``cover_blocks``)."""
         from geomesa_tpu import config
         if not config.PRUNE_ENABLED.get():
             return None
@@ -324,7 +323,7 @@ class QueryPlanner:
             if (not plan.empty and plan.index is not None
                     and plan.candidate_slices is None
                     and hasattr(plan.index, "candidate_blocks")):
-                if timed and _trace.enabled():
+                if _trace.enabled():
                     t0 = time.perf_counter_ns()
                     blocks = plan.index.candidate_blocks(plan)
                     t1 = time.perf_counter_ns()

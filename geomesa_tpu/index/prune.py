@@ -13,10 +13,16 @@ gathers candidate blocks and re-applies the full exact mask, so the cover
 only ever needs to be a superset (block granularity and cover slop are
 harmless).
 
-The planner prefers the pruned path when the candidate fraction is small
-(``PRUNE_MAX_FRACTION``); above that a full-table fused mask scan is faster
-than gathering (sequential HBM beats scattered gathers once most blocks are
-touched anyway).
+One cover per scan: the range budget is for the whole decomposition, however
+many boxes it unions, as upstream's ``geomesa.scan.ranges.target`` is per
+scan. A plan's boxes are one scan (``candidate_blocks``); so are all the
+boxes of a scheduler dispatch, which the collector covers together once
+(``BaseSpatialIndex.cover_blocks``), not request by request.
+
+The pruned path is preferred when the candidate fraction of the scan (for a
+dispatch, of the union of its boxes) is small (``PRUNE_MAX_FRACTION``);
+above that a full-table fused mask scan is faster than gathering (sequential
+HBM beats scattered gathers once most blocks are touched anyway).
 """
 
 from __future__ import annotations

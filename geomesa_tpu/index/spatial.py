@@ -913,41 +913,67 @@ class BaseSpatialIndex:
         """Sorted unique gather-block ids covering every possibly-matching
         row, or None when pruning doesn't apply or wouldn't pay (the device
         re-applies the full exact mask to gathered blocks, so this only ever
-        needs to be a superset). ≙ the reference's ≤2000-range scan plans
-        (Z3IndexKeySpace.getRanges:162-189); the decision threshold mirrors
-        full-table-scan avoidance (QueryProperties.BlockFullTableScans)."""
-        from geomesa_tpu.index import prune as _p
-
+        needs to be a superset). The plan-level entry of ``cover_blocks``:
+        one plan's boxes and intervals, at most 16 boxes."""
         if plan.empty or plan.boxes_loose is None:
             return None  # no spatial constraint → nothing to cover
         boxes = plan.explain.get("boxes")
         if not boxes or len(boxes) > 16:
             return None
+        blocks, stats = self.cover_blocks(list(boxes),
+                                          self.cover_intervals(plan))
+        plan.explain.update(stats)
+        return blocks
+
+    @staticmethod
+    def cover_intervals(plan: IndexScanPlan):
+        """The plan's time intervals as ``cover_blocks`` takes them.
+        plan.windows is None iff the temporal extraction was unconstrained —
+        the explain intervals then hold the open-ended sentinel, which must
+        read as "no temporal constraint", not as a 146-million-bin interval."""
+        return plan.explain.get("intervals") if plan.windows is not None \
+            else None
+
+    def cover_blocks(self, boxes, intervals) -> Tuple[Optional[np.ndarray],
+                                                     dict]:
+        """One range decomposition for the union of ``boxes`` (user-space
+        (xmin, ymin, xmax, ymax), from one plan or from every plan of a
+        scheduler group) under shared time ``intervals``: (sorted unique
+        int32 block ids or None, explain stats). None = scan the table: the
+        index has no cover, the table is tiny, or the union's rows pass
+        ``PRUNE_MAX_FRACTION`` of it, which is what a gather would read. An
+        empty array = provably nothing to scan. The range budget is
+        ``SCAN_RANGES_TARGET`` for the whole cover, however many boxes
+        (≙ the reference's ≤2000-range scan plans,
+        Z3IndexKeySpace.getRanges:162-189; the decision threshold mirrors
+        full-table-scan avoidance, QueryProperties.BlockFullTableScans)."""
+        from geomesa_tpu.index import prune as _p
+
         n = len(self.table)
         if n < 4 * _p.BLOCK_SIZE:
-            return None  # tiny tables: full mask is a single fused pass
-        # plan.windows is None iff the temporal extraction was unconstrained —
-        # the explain intervals then hold the open-ended sentinel, which must
-        # read as "no temporal constraint", not as a 146-million-bin interval
-        intervals = plan.explain.get("intervals") if plan.windows is not None else None
-        slices = self._row_slices(list(boxes), intervals)
-        if slices is None:
-            return None
+            return None, {}  # tiny tables: full mask is a single fused pass
+        cover = self._row_slices(boxes, intervals)
+        if cover is None:
+            return None, {}
+        slices, n_ranges = cover
+        stats = {"cover_boxes": len(boxes), "cover_ranges": n_ranges}
         total = int((slices[:, 1] - slices[:, 0]).sum()) if len(slices) else 0
         if total > _p.PRUNE_MAX_FRACTION * n:
-            return None
+            return None, stats
         blocks = _p.slices_to_blocks(slices, n)
         if blocks is not None and len(blocks) * _p.BLOCK_SIZE > _p.PRUNE_MAX_FRACTION * n:
-            return None
-        plan.explain.update(_p.candidate_stats(slices, blocks, n))
+            return None, stats
+        stats.update(_p.candidate_stats(slices, blocks, n))
         if blocks is None:
             # provably empty candidate set — still exact (superset of nothing)
             blocks = np.empty(0, dtype=np.int32)
-        return blocks
+        return blocks, stats
 
-    def _row_slices(self, boxes, intervals) -> Optional[np.ndarray]:
-        """Candidate [lo, hi) row slices in this index's sorted order (a
-        superset of matches), or None when unsupported."""
+    def _row_slices(self, boxes, intervals
+                    ) -> Optional[Tuple[np.ndarray, int]]:
+        """(candidate [lo, hi) row slices in this index's sorted order, a
+        superset of matches; key ranges the decomposition came back with),
+        or None when unsupported."""
         return None
 
     def _bin_segments(self):
@@ -971,10 +997,11 @@ class BaseSpatialIndex:
         return cached
 
     def _binned_row_slices(self, boxes, intervals, sorted_keys,
-                           cover_fn) -> Optional[np.ndarray]:
+                           cover_fn) -> Optional[Tuple[np.ndarray, int]]:
         """Shared epoch-major pruning: per-bin segments × per-window covers
         (covers dedup by in-bin window, so a multi-bin interval costs at most
-        three distinct covers: head, whole-period, tail)."""
+        three distinct covers: head, whole-period, tail). Each cover is of
+        all ``boxes`` together."""
         from geomesa_tpu.index import prune as _p
         from geomesa_tpu.curves.binnedtime import max_offset
 
@@ -998,7 +1025,11 @@ class BaseSpatialIndex:
             if w not in covers:
                 covers[w] = cover_fn(boxes, w)
             out.append(_p.ranges_to_slices(sorted_keys, covers[w], lo=lo, hi=hi))
-        return np.concatenate(out) if out else np.empty((0, 2), dtype=np.int64)
+        slices = np.concatenate(out) if out \
+            else np.empty((0, 2), dtype=np.int64)
+        # a cover is (lo, hi, contained) arrays or a list of IndexRange
+        return slices, sum(len(c[0]) if isinstance(c, tuple) else len(c)
+                           for c in covers.values())
 
     # explain ---------------------------------------------------------------
 
@@ -1134,7 +1165,7 @@ class Z2Index(BaseSpatialIndex):
     def _row_slices(self, boxes, intervals):
         from geomesa_tpu.index.prune import MAX_RANGES, ranges_to_slices
         rs = Z2SFC().ranges_arrays(boxes, max_ranges=MAX_RANGES)
-        return ranges_to_slices(self.sorted_z, rs)
+        return ranges_to_slices(self.sorted_z, rs), len(rs[0])
 
 
 class XZ3Index(BaseSpatialIndex):
@@ -1206,7 +1237,7 @@ class XZ2Index(BaseSpatialIndex):
         from geomesa_tpu.index.prune import MAX_RANGES, ranges_to_slices
         sfc = XZ2SFC.apply(self.sft.xz_precision)
         rs = sfc.ranges_bbox(boxes, max_ranges=MAX_RANGES)
-        return ranges_to_slices(self.sorted_xz, rs)
+        return ranges_to_slices(self.sorted_xz, rs), len(rs)
 
 
 class S2Index(BaseSpatialIndex):
@@ -1242,7 +1273,7 @@ class S2Index(BaseSpatialIndex):
         from geomesa_tpu.curves.s2 import S2SFC
         from geomesa_tpu.index.prune import MAX_RANGES, ranges_to_slices
         rs = S2SFC.apply().ranges(boxes, max_ranges=MAX_RANGES)
-        return ranges_to_slices(self.sorted_z, rs)
+        return ranges_to_slices(self.sorted_z, rs), len(rs)
 
 
 class S3Index(BaseSpatialIndex):
@@ -1283,16 +1314,11 @@ class S3Index(BaseSpatialIndex):
     def _row_slices(self, boxes, intervals):
         from geomesa_tpu.curves.s2 import S2SFC
         from geomesa_tpu.index.prune import MAX_RANGES
-        sfc = S2SFC.apply()
-        cover = {}
-
-        def cover_fn(bx, w):  # no time dim in the s2 key: one shared cover
-            if "c" not in cover:
-                cover["c"] = sfc.ranges(bx, max_ranges=MAX_RANGES)
-            return cover["c"]
-
-        return self._binned_row_slices(boxes, intervals, self.sorted_z,
-                                       cover_fn)
+        # no time dim in the s2 key: one cover shared by every window
+        rs = S2SFC.apply().ranges(boxes, max_ranges=MAX_RANGES)
+        cover = self._binned_row_slices(boxes, intervals, self.sorted_z,
+                                        lambda bx, w: rs)
+        return None if cover is None else (cover[0], len(rs))
 
 
 class FullScanIndex(BaseSpatialIndex):

@@ -3,8 +3,8 @@
 The Dapper/Canopy lesson (PAPERS.md): aggregate histograms tell you THAT a
 p99 regressed; only a per-request record with every dimension on one row
 tells you WHICH queries paid it. Every query/count/batch emits one
-structured wide event — trace id, query type, plan hash, plan/cover cache
-hit flags, batch size + batch id, admission class, deadline budget vs
+structured wide event — trace id, query type, plan hash, plan-cache hit
+flag, batch size + batch id, admission class, deadline budget vs
 slack, ``device_ms`` vs ``host_ms`` (both host clock: ``device_ms`` is the
 host blocked until the answer was read back, never time on the device),
 rows scanned/matched, shed/degrade/cancel/
@@ -315,7 +315,6 @@ def event_from_request(req, fut) -> dict:
         # submit → its batch closed (none of its neighbours' planning)
         "queue_wait_ms": ms(req.queue_wait_s),
         "plan_cache_hit": req.plan_cache_hit,
-        "cover_cache_hit": req.cover_cache_hit,
         # provenance: "result" = served from the hot-result cache with NO
         # device round trip (device_ms stays zero; workload device-time
         # accounting must not re-bill the original dispatch)

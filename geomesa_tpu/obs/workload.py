@@ -13,7 +13,7 @@ event stream:
              aggregating per (type, plan_hash, admission class, tenant):
              qps, latency p50/p99 on the SHARED metrics.py log-bucket
              geometry (so fleet merges stay lossless), rows scanned/
-             matched, device-ms, plan/cover cache-hit rates, shed/
+             matched, device-ms, plan cache-hit rate, shed/
              degrade/error rates.
 
   sketches   SpaceSaving top-k over plan hashes and tenants plus the
@@ -105,7 +105,7 @@ class _Group:
     nodes' groups merge by plain bucket-count sums."""
 
     __slots__ = ("n", "errors", "shed", "degraded", "cancelled",
-                 "plan_hits", "plan_known", "cover_hits", "cover_known",
+                 "plan_hits", "plan_known",
                  "rows_scanned", "rows_matched", "device_ms", "buckets")
 
     def __init__(self):
@@ -116,8 +116,6 @@ class _Group:
         self.cancelled = 0
         self.plan_hits = 0
         self.plan_known = 0
-        self.cover_hits = 0
-        self.cover_known = 0
         self.rows_scanned = 0
         self.rows_matched = 0
         self.device_ms = 0.0
@@ -137,10 +135,6 @@ class _Group:
         if ph is not None:
             self.plan_known += 1
             self.plan_hits += bool(ph)
-        ch = ev.get("cover_cache_hit")
-        if ch is not None:
-            self.cover_known += 1
-            self.cover_hits += bool(ch)
         self.rows_scanned += int(ev.get("rows_scanned") or 0)
         self.rows_matched += int(ev.get("rows_matched") or 0)
         self.device_ms += float(ev.get("device_ms") or 0.0)
@@ -157,8 +151,6 @@ class _Group:
         self.cancelled += other.cancelled
         self.plan_hits += other.plan_hits
         self.plan_known += other.plan_known
-        self.cover_hits += other.cover_hits
-        self.cover_known += other.cover_known
         self.rows_scanned += other.rows_scanned
         self.rows_matched += other.rows_matched
         self.device_ms += other.device_ms
@@ -169,8 +161,6 @@ class _Group:
         return {"n": self.n, "errors": self.errors, "shed": self.shed,
                 "degraded": self.degraded, "cancelled": self.cancelled,
                 "plan_hits": self.plan_hits, "plan_known": self.plan_known,
-                "cover_hits": self.cover_hits,
-                "cover_known": self.cover_known,
                 "rows_scanned": self.rows_scanned,
                 "rows_matched": self.rows_matched,
                 "device_ms": round(self.device_ms, 3),
@@ -181,7 +171,7 @@ class _Group:
     def from_state(cls, st: dict) -> "_Group":
         g = cls()
         for f in ("n", "errors", "shed", "degraded", "cancelled",
-                  "plan_hits", "plan_known", "cover_hits", "cover_known",
+                  "plan_hits", "plan_known",
                   "rows_scanned", "rows_matched"):
             setattr(g, f, int(st.get(f, 0)))
         g.device_ms = float(st.get("device_ms", 0.0))
@@ -210,9 +200,6 @@ class _Group:
             "plan_cache_hit_rate": round(
                 self.plan_hits / self.plan_known, 4)
             if self.plan_known else None,
-            "cover_cache_hit_rate": round(
-                self.cover_hits / self.cover_known, 4)
-            if self.cover_known else None,
             "rows_scanned": self.rows_scanned,
             "rows_matched": self.rows_matched,
             "device_ms": round(self.device_ms, 3),

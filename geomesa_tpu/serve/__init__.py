@@ -1,5 +1,5 @@
 """Serving-path runtime: the adaptive micro-batching query scheduler, its
-plan/cover caches (≙ the amortize-per-query-cost discipline of the
+plan cache (≙ the amortize-per-query-cost discipline of the
 reference's server-side scans, applied to concurrent request traffic), the
 query-lifecycle resilience layer (deadlines, admission control, circuit
 breaking, graceful degradation — serve/resilience/), and the fleet-facing

@@ -26,7 +26,7 @@ Cell affinity (GEOMESA_TPU_AFFINITY): each routed count is stamped with
 its coarse Morton cell (obs/sketches.cell_key — the same Z2 bit interleave
 the curves use) and, when the workload plane marks that cell hot, the
 rotation is re-ordered so the SAME healthy endpoint always leads for that
-cell — its result/plan/cover caches stay warm for the hot region instead
+cell — its result/plan caches stay warm for the hot region instead
 of the heat smearing round-robin across the fleet. Cold cells keep the
 plain rotation; ``freshness="strong"`` pins and demotion are never
 overridden (affinity only re-orders the healthy tier)."""

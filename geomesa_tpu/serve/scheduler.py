@@ -1,4 +1,4 @@
-"""Adaptive micro-batching query scheduler + plan/cover caching (serving path).
+"""Adaptive micro-batching query scheduler + plan caching (serving path).
 
 The whole GeoMesa design amortizes per-query cost by pushing work close to
 the data; the TPU build's batched scan kernel proves the same point for
@@ -11,8 +11,9 @@ bound, not device bound. This module closes that gap for concurrent traffic:
 
 Concurrent count requests are grouped by compatible kernel signature (same
 index kernels, primary kind, time windows, device residual) and fused into a
-single ``counts_multi[_blocks]`` dispatch over the union of their candidate
-blocks. An adaptive window flushes at B queries or T µs, whichever first;
+single ``counts_multi[_blocks]`` dispatch over ONE candidate-block cover,
+decomposed once for the union of the group's boxes. An adaptive window
+flushes at B queries or T µs, whichever first;
 the collector thread plans/dispatches batch N+1 while the completer thread
 waits on batch N's in-flight device round trip, so host planning overlaps
 the RTT instead of summing with it.
@@ -26,14 +27,12 @@ Caching in front of the batcher:
                (the trace tree shows no ``plan`` span). Keyed by auths so a
                privileged query's visibility-folded plan can never serve an
                unprivileged caller (tests/test_security.py).
-  cover cache  (epoch, type, generation, index, boxes, windows) → candidate gather
-               blocks. Parameterized queries that share a spatial/temporal
-               region but differ in residual or auths skip the host range
-               decomposition.
 
-Both invalidate through the datastore's per-type generation counter: every
+It invalidates through the datastore's per-type generation counter: every
 mutation (ingest append, LSM flush, age-off, update, delete, schema change)
 bumps the generation, so a stale cached plan is unreachable by construction.
+A plan that runs alone (a group of one, or off the fused path) keeps its
+candidate-block cover on the plan object the cache holds.
 
 Thread model: callers submit from any thread and block on a per-request
 future; one collector thread owns batching/planning/dispatch, one completer
@@ -54,8 +53,9 @@ outstanding future with a structured SchedulerCrashed/SchedulerShutdown
 error instead of leaving callers blocked forever.
 
 The dispatch cycle, timed from inside (``_Cycle`` / ``_Dispatch``): one turn
-of the collector is idle → window → plan loop (plan, cover, group) → per
-group union, prepare, launch; the completer adds pickup, delta, ready_wait,
+of the collector is idle → window → plan loop (plan and group key a
+request, then one cover a group) → per group union, prepare, launch; the
+completer adds pickup, delta, ready_wait,
 resolve. Every stage is a (start, end) pair on ``trace.py``'s clock. They go
 out on the ``kind=batch`` flight event (``stages``), the ``sched.stage.*``
 timers, ``slow_cycles`` of ``stats()`` and as ``sched.*`` annotations in a
@@ -165,8 +165,7 @@ class LruCache:
         self.misses = 0
 
     def get(self, key):
-        """Cached value or the module ``_MISS`` sentinel (values may
-        legitimately be None — a declined cover)."""
+        """Cached value or the module ``_MISS`` sentinel."""
         with self._lock:
             if self._cap > 0 and key in self._d:
                 self._d.move_to_end(key)
@@ -262,7 +261,7 @@ class Request:
                  "cancelled", "degraded",
                  # flight-recorder dimensions (obs/flight.py wide events)
                  "trace_id", "trace_gid", "parent_span", "budget_ms",
-                 "plan_cache_hit", "cover_cache_hit", "batch_id",
+                 "plan_cache_hit", "batch_id",
                  "rows_scanned", "shed", "breaker_open", "retries",
                  # workload-analytics dimensions (obs/workload.py)
                  "tenant", "cell", "funcs",
@@ -286,13 +285,13 @@ class Request:
         self.epoch = epoch
         self.future: Future = Future()
         # instants on trace.py's clock (perf_counter_ns): submitted, its
-        # batch closed, its own (plan start, plan end, cover end) on a
-        # plan-cache miss, its dispatch launched (a single: handed to the
+        # batch closed, its own (plan start, plan end) on a plan-cache
+        # miss, its dispatch launched (a single: handed to the
         # completer). The stages below are their differences, in seconds:
         # queue_wait = submit → closed, scan = launch → resolved
         self.t_submit = _pcn()
         self.t_closed: Optional[int] = None
-        self.t_plan: Optional[Tuple[int, int, int]] = None
+        self.t_plan: Optional[Tuple[int, int]] = None
         self.t_launch: Optional[int] = None
         self.plan = None
         self.queue_wait_s: Optional[float] = None
@@ -308,7 +307,6 @@ class Request:
         self.parent_span: Optional[int] = None
         self.budget_ms: Optional[float] = None
         self.plan_cache_hit: Optional[bool] = None
-        self.cover_cache_hit: Optional[bool] = None
         self.batch_id: Optional[int] = None
         self.rows_scanned: Optional[int] = None
         self.shed = False
@@ -339,12 +337,14 @@ def _observe_stages(stages: Dict[str, Tuple[int, int]]) -> None:
 class _Cycle:
     """One turn of the collector thread, as instants on ``perf_counter_ns``:
     ``idle`` (blocked in ``queue.get()`` with nothing queued) → ``window``
-    (first request → batch closed) → the per-request planning loop → one
-    ``_Dispatch`` per fused group. ``plan`` and ``cover`` are sums over the
-    loop's plan-cache misses; ``group`` is the rest of the loop (group keys,
-    deadline checks), so the three make the loop's wall exactly, and
-    ``loop_cpu_ns`` is the thread's CPU time across it: wall minus CPU is
-    time the thread wanted to run and did not (the GIL, the OS)."""
+    (first request → batch closed) → the planning loop → one ``_Dispatch``
+    per fused group. ``plan`` is the sum over the loop's plan-cache misses;
+    ``cover`` the sum over its groups' covers (range decomposition and
+    blocks, one for all the boxes of a group: ``cover_misses`` of them);
+    ``group`` is the rest of the loop (group keys, deadline checks), so the
+    three make the loop's wall exactly, and ``loop_cpu_ns`` is the thread's
+    CPU time across it: wall minus CPU is time the thread wanted to run and
+    did not (the GIL, the OS)."""
 
     __slots__ = ("t_idle", "t_first", "t_closed", "t_loop", "t_loop_end",
                  "plan_ns", "cover_ns", "plan_misses", "cover_misses",
@@ -359,11 +359,11 @@ class _Cycle:
         self.size = 0
 
     def stages(self) -> Dict[str, Tuple[int, int]]:
-        """(start, end) of the cycle's own stages. ``plan``, ``cover`` and
-        ``group`` interleave request by request inside the planning loop:
-        they are laid end to end from the loop's start in that order, so only
-        the loop's start and end are instants that happened; the lengths are
-        exact."""
+        """(start, end) of the cycle's own stages. ``plan`` and ``group``
+        interleave request by request inside the planning loop and the
+        groups' covers follow: the three are laid end to end from the loop's
+        start as plan, cover, group, so only the loop's start and end are
+        instants that happened; the lengths are exact."""
         plan_end = self.t_loop + self.plan_ns
         cover_end = plan_end + self.cover_ns
         return {"idle": (self.t_idle, self.t_first),
@@ -375,7 +375,8 @@ class _Cycle:
 
 class _Dispatch:
     """One fused group's way through a cycle: ``union`` (the group's boxes
-    and block union) → ``prepare`` (padding, host→device puts) → ``launch``
+    stacked, its cover's tier) → ``prepare`` (padding, host→device puts) →
+    ``launch``
     (``disp()`` behind breaker and retries) on the collector; ``pickup`` (on
     the ``_done`` queue) → ``delta`` → ``ready_wait`` (``np.asarray(out)``)
     → ``resolve`` (result cache, ``set_result`` and its done-callbacks, up
@@ -383,7 +384,8 @@ class _Dispatch:
     the next begins."""
 
     __slots__ = ("cycle", "batch_id", "size", "kernel", "tier", "union_tier",
-                 "rows_scanned", "first_call_s", "queue_depth", "threads",
+                 "rows_scanned", "cover_boxes", "cover_ranges",
+                 "first_call_s", "queue_depth", "threads",
                  "t_union", "t_prepare", "t_launch", "t_put",
                  "t_pickup", "t_wait", "t_ready", "t_resolved")
 
@@ -393,6 +395,9 @@ class _Dispatch:
         self.size = size
         self.kernel = None
         self.tier = self.union_tier = self.rows_scanned = 0
+        # boxes decomposed together for this group's cover and the key
+        # ranges it came back with (0, 0: no cover was computed)
+        self.cover_boxes = self.cover_ranges = 0
         # seconds of the program's first call when this launch made it
         self.first_call_s: Optional[float] = None
         self.queue_depth = self.threads = 0
@@ -423,6 +428,8 @@ class _Dispatch:
             "ready_ms": round(ms(self.t_ready), 3),
             "plan_loop_cpu_ms": round(c.loop_cpu_ns / 1e6, 3),
             "plan_misses": c.plan_misses, "cover_misses": c.cover_misses,
+            "cover_boxes": self.cover_boxes,
+            "cover_ranges": self.cover_ranges,
             "first_call": self.first_call_s,
             "queue_depth": self.queue_depth, "threads": self.threads}
 
@@ -449,7 +456,6 @@ class QueryScheduler:
                  window_us: Optional[float] = None,
                  min_window_us: Optional[float] = None,
                  plan_cache: Optional[int] = None,
-                 cover_cache: Optional[int] = None,
                  result_cache: Optional[int] = None):
         self.binding = binding
         self._flush_size = int(flush_size or config.SCHED_FLUSH_SIZE.get())
@@ -459,9 +465,7 @@ class QueryScheduler:
         self._window_us = self._max_window_us
         self._ema_batch = 1.0
         cap_p = config.SCHED_PLAN_CACHE.get() if plan_cache is None else plan_cache
-        cap_c = config.SCHED_COVER_CACHE.get() if cover_cache is None else cover_cache
         self.plans = LruCache(cap_p, "scheduler.plan_cache")
-        self.covers = LruCache(cap_c, "scheduler.cover_cache")
         # hot-result cache: same (epoch, type, generation, filter, auths)
         # keying as the plan cache, admission gated by the workload plane
         self.results = ResultCache(capacity=result_cache)
@@ -490,6 +494,8 @@ class QueryScheduler:
         self._n_batches = 0
         self._n_fused = 0
         self._n_single = 0
+        self._n_group_covers = 0
+        self._n_cover_boxes = 0
         # completer-thread-only: the last cycles that took over
         # _SLOW_CYCLE_S, whole (replaced, never mutated: readers take the
         # reference)
@@ -656,10 +662,10 @@ class QueryScheduler:
         the collector and completer left on it. queue_wait, batch_host,
         scan and wake follow one another, so with ``submit`` they partition
         the latency. The request's own planning is part of batch_host's
-        interval and nests under it: ``plan``, and ``range_decompose`` where
-        the cover was computed and not found in the cover cache. Each feeds
-        its timer here and nowhere else (the collector calls the planner's
-        untimed entry points)."""
+        interval and nests under it: ``plan``, fed to its timer here and
+        nowhere else (the collector calls the planner's untimed ``_plan``).
+        The cover is its group's, not the request's: the collector feeds
+        ``range_decompose`` once per group cover (``_cover_group``)."""
         rec = _trace.record
         closed, launch = req.t_closed, req.t_launch
         if closed is not None:
@@ -668,13 +674,8 @@ class QueryScheduler:
             host = rec("batch_host", "batch_host",
                        (launch - closed) / 1e9, launch)
             if req.t_plan is not None:
-                t0, t1, t2 = req.t_plan
-                if req.cover_cache_hit is False:
-                    rec("plan", "plan", (t1 - t0) / 1e9, t1, parent=host)
-                    rec("range_decompose", "range_decompose",
-                        (t2 - t1) / 1e9, t2, parent=host)
-                else:
-                    rec("plan", "plan", (t2 - t0) / 1e9, t2, parent=host)
+                t0, t1 = req.t_plan
+                rec("plan", "plan", (t1 - t0) / 1e9, t1, parent=host)
             if req.scan_s is not None:
                 resolved = launch + int(req.scan_s * 1e9)
                 rec("scan", "scan", req.scan_s, resolved,
@@ -788,8 +789,11 @@ class QueryScheduler:
             "batch_size_hist": {str(k): v for k, v in
                                 sorted(self._batch_hist.items())},
             "slow_cycles": self._slow_cycles,
+            "group_covers": self._n_group_covers,
+            "cover_boxes_mean": round(
+                self._n_cover_boxes / self._n_group_covers, 2)
+            if self._n_group_covers else 0.0,
             "plan_cache": self.plans.stats(),
-            "cover_cache": self.covers.stats(),
             "result_cache": self.results.stats(),
             "healthy": self.healthy(),
             "admission": self.admission.stats(),
@@ -885,12 +889,12 @@ class QueryScheduler:
             self._window_us = min(self._max_window_us, self._window_us * 1.5)
 
     def _plan_request(self, req: Request, cyc: _Cycle) -> None:
-        """Fill ``req.plan`` via the plan cache (auths-folded; cover cached
-        on the plan). A cache hit leaves ``req.t_plan`` None — the trace
-        shows no plan stage at all. A miss is timed here, once: the
-        planner's untimed ``_plan`` / ``_pruned_blocks(timed=False)`` are
-        called, and the seconds reach the ``plan`` / ``range_decompose``
-        timers through the request's own trace (``_record_stages``)."""
+        """Fill ``req.plan`` via the plan cache (auths-folded). Plans only:
+        the candidate-block cover is its group's (``_cover_group``). A cache
+        hit leaves ``req.t_plan`` None — the trace shows no plan stage at
+        all. A miss is timed here, once: the planner's untimed ``_plan`` is
+        called, and the seconds reach the ``plan`` timer through the
+        request's own trace (``_record_stages``)."""
         pkey = (req.epoch, req.type_name, req.generation, req.f_key,
                 req.auths_key)
         plan = self.plans.get(pkey)
@@ -903,38 +907,46 @@ class QueryScheduler:
         planner = req.planner
         plan = planner._apply_auths(planner._plan(req.f_ir), req.auths)
         t1 = _pcn()
-        self._fill_cover(req, plan, planner)
-        t2 = _pcn()
-        req.t_plan = (t0, t1, t2)
+        req.t_plan = (t0, t1)
         cyc.plan_ns += t1 - t0
-        cyc.cover_ns += t2 - t1
         cyc.plan_misses += 1
-        if req.cover_cache_hit is False:
-            cyc.cover_misses += 1
         req.plan = plan
         self.plans.put(pkey, plan)
 
-    def _fill_cover(self, req: Request, plan, planner) -> None:
-        """Resolve the plan's candidate-block cover through the cover cache
-        (keyed purely by the device constraint arrays, so filters differing
-        only in residual or auths share one range decomposition)."""
-        if getattr(plan, "blocks", None) is not False:
-            return  # union plans / already resolved
-        if plan.empty or plan.candidate_slices is not None \
-                or plan.index is None or plan.boxes_loose is None:
-            return  # cover never applies; leave lazy
-        ckey = (req.epoch, req.type_name, req.generation,
-                type(plan.index).__name__,
-                plan.boxes_loose.tobytes(),
-                None if plan.windows is None else plan.windows.tobytes())
-        cached = self.covers.get(ckey)
-        if cached is not _MISS:
-            plan.blocks = cached
-            req.cover_cache_hit = True
-            return
-        req.cover_cache_hit = False
-        blocks = planner._pruned_blocks(plan, timed=False)
-        self.covers.put(ckey, blocks)
+    def _cover_group(self, grp: List[Request], cyc: _Cycle) -> tuple:
+        """ONE candidate-block cover for a fused group: the range
+        decomposition of the union of its members' boxes under the time
+        windows its key guarantees equal (``cover_blocks``), so a dispatch
+        of 32 boxes costs one decomposition, not 32. Returns (blocks, the
+        cover's stats): sorted unique int32 block ids, or None to scan the
+        table, decided on the UNION's rows against ``PRUNE_MAX_FRACTION``,
+        which is what the gather kernel would read; no stats where nothing
+        was decomposed.
+        A superset by construction; the kernel re-applies every member's
+        exact mask. A group of one keeps its cover on the plan object the
+        plan cache holds, where ``planner._count`` keeps a single's."""
+        if not config.PRUNE_ENABLED.get():
+            return None, {}
+        lead = grp[0].plan
+        alone = len(grp) == 1
+        if alone and lead.blocks is not False:
+            return lead.blocks, {}   # a repeated lone query: already covered
+        t0 = _pcn()
+        boxes = list(dict.fromkeys(
+            b for r in grp for b in r.plan.explain["boxes"]))
+        index = lead.index
+        blocks, stats = index.cover_blocks(boxes, index.cover_intervals(lead))
+        if alone:
+            lead.blocks = blocks
+            lead.explain.update(stats)
+        t1 = _pcn()
+        cyc.cover_ns += t1 - t0
+        cyc.cover_misses += 1
+        self._n_group_covers += 1
+        self._n_cover_boxes += len(boxes)
+        if _trace.enabled():
+            _metrics.observe("range_decompose", (t1 - t0) / 1e9)
+        return blocks, stats
 
     def _dispatch(self, batch: List[Request], cyc: _Cycle) -> None:
         """Group a collected batch by fused-kernel compatibility and launch
@@ -945,6 +957,14 @@ class QueryScheduler:
         cyc.t_loop = _pcn()
         with _annotate("sched.plan_loop", n=len(batch)):
             self._plan_loop(batch, cyc, groups)
+            covers = []
+            for grp in groups.values():
+                try:
+                    covers.append(self._cover_group(grp, cyc))
+                except Exception as e:   # a cover's fault fails its group
+                    covers.append(None)
+                    for r in grp:
+                        self._fail(r, e)
         cyc.t_loop_end = _pcn()
         cyc.loop_cpu_ns = time.thread_time_ns() - cpu0
         if _trace.enabled():
@@ -952,14 +972,16 @@ class QueryScheduler:
             # (a dispatch's own stages: the completer, in `_publish`)
             _observe_stages(cyc.stages())
             _metrics.inc("sched.plan_loop_cpu_us", cyc.loop_cpu_ns // 1000)
-        for gkey, grp in groups.items():
-            if len(grp) == 1 and grp[0].plan.blocks is not None \
-                    and len(grp[0].plan.blocks) == 0:
+        for grp, cover in zip(groups.values(), covers):
+            if cover is None:
+                continue
+            if cover[0] is not None and len(cover[0]) == 0:
                 # provably-empty candidate set, nothing to dispatch
-                self._to_completer_single(grp[0])
+                for r in grp:
+                    self._to_completer_single(r)
                 continue
             try:
-                self._dispatch_group(grp, gkey[-1], cyc)
+                self._dispatch_group(grp, cover, cyc)
             except Exception as e:
                 for r in grp:
                     self._fail(r, e)
@@ -970,9 +992,9 @@ class QueryScheduler:
 
     def _plan_loop(self, batch: List[Request], cyc: _Cycle,
                    groups: Dict[tuple, List[Request]]) -> None:
-        """The per-request part of a dispatch: deadline checks, plan and
-        cover through their caches, the fused-kernel group key. Python and
-        numpy only, nothing that should sleep."""
+        """The per-request part of a dispatch: deadline checks, the plan
+        through its cache, the fused-kernel group key. Python and numpy
+        only, nothing that should sleep."""
         from geomesa_tpu.index.scan import PRIMARY_FNS
 
         degrade_floor = config.DEADLINE_DEGRADE_MS.get()
@@ -1005,7 +1027,6 @@ class QueryScheduler:
             if (plan.device_exact and plan.primary_kind in PRIMARY_FNS
                     and plan.boxes_loose is not None
                     and plan.boxes_loose.shape == (1, 8)):
-                pruned = plan.blocks is not None
                 rd = plan.residual_device
                 wkey = None if plan.windows is None \
                     else (plan.windows.shape[0], plan.windows.tobytes())
@@ -1014,19 +1035,20 @@ class QueryScheduler:
                      np.asarray(p).tobytes()) for p in rd[1])) \
                     if rd else None
                 gkey = (id(plan.index.kernels), plan.primary_kind,
-                        wkey, rkey, pruned)
+                        wkey, rkey)
                 groups.setdefault(gkey, []).append(r)
             else:
                 self._n_single += 1
                 _metrics.inc("scheduler.singles")
                 self._to_completer_single(r)
 
-    def _dispatch_group(self, grp: List[Request], pruned: bool,
+    def _dispatch_group(self, grp: List[Request], cover: tuple,
                         cyc: _Cycle) -> None:
         """ONE async fused dispatch for a compatible group: per-query boxes
-        stack into a (B, 8) array; pruned groups scan the union of their
-        candidate blocks (the kernel re-applies the full exact mask, so the
-        union cover stays a harmless superset)."""
+        stack into a (B, 8) array; a covered group scans the blocks of its
+        one cover (``_cover_group``; the kernel re-applies the full exact
+        mask, so the cover stays a harmless superset), any other the
+        table."""
         from geomesa_tpu.index import prune as _prune
         from geomesa_tpu.index.scan import blocks_tier
 
@@ -1036,6 +1058,10 @@ class QueryScheduler:
         kern = lead.index.kernels
         batch_id = next(self._batch_ids)
         d = _Dispatch(cyc, batch_id, len(grp))
+        union, stats = cover
+        d.cover_boxes = stats.get("cover_boxes", 0)
+        d.cover_ranges = stats.get("cover_ranges", 0)
+        pruned = union is not None
         # attribution tier = the padded batch size the dispatch shipped
         d.tier = max(1, 1 << max(0, (len(grp) - 1)).bit_length())
         d.t_union = _pcn()
@@ -1043,9 +1069,6 @@ class QueryScheduler:
             boxes = np.concatenate([r.plan.boxes_loose for r in grp], axis=0)
             xfer = boxes.nbytes
             if pruned:
-                nonempty = [r.plan.blocks for r in grp if len(r.plan.blocks)]
-                union = np.unique(np.concatenate(nonempty)).astype(np.int32) \
-                    if nonempty else np.empty(0, dtype=np.int32)
                 d.rows_scanned = int(len(union)) * _prune.BLOCK_SIZE
                 d.union_tier = blocks_tier(len(union))
                 xfer += union.nbytes

@@ -239,3 +239,61 @@ class TestXZ2SFC:
         for i, b in enumerate(boxes):
             single = sfc.index_bbox(*b)
             assert int(single[0]) == int(batch[i])
+
+
+class TestManyBoxCovers:
+    """A cover of many boxes normalises them as arrays and tests a cell
+    against all of them at once; the per-corner scalar form is the
+    reference."""
+
+    BOXES = [(-10.0, -5.0, 10.0, 5.0), (20.5, 20.25, 30.0, 31.0),
+             (-180.0, -90.0, -179.0, -89.0), (179.0, 89.0, 180.0, 90.0),
+             (12.345678, -45.6789, 12.345679, -45.6788)]
+
+    def test_z3_corners_match_scalar_normalize(self):
+        from geomesa_tpu.curves.ranges import zranges_3d_arrays
+        sfc = Z3SFC.apply(TimePeriod.WEEK)
+        windows = [(0, 1000), (5000, 604799)]
+        rows = []
+        for xmin, ymin, xmax, ymax in self.BOXES:
+            for t0, t1 in windows:
+                rows.append((int(sfc.lon.normalize(xmin)),
+                             int(sfc.lat.normalize(ymin)),
+                             int(sfc.time.normalize(t0)),
+                             int(sfc.lon.normalize(xmax)),
+                             int(sfc.lat.normalize(ymax)),
+                             int(sfc.time.normalize(t1))))
+        want = zranges_3d_arrays(rows, sfc.precision, 2000, 64)
+        got = sfc.ranges_arrays(self.BOXES, windows, max_ranges=2000)
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(a, b)
+
+    def test_z2_corners_match_scalar_normalize(self):
+        from geomesa_tpu.curves.ranges import zranges_2d_arrays
+        sfc = Z2SFC()
+        rows = []
+        for xmin, ymin, xmax, ymax in self.BOXES:
+            xlo, ylo = sfc.normalize(xmin, ymin)
+            xhi, yhi = sfc.normalize(xmax, ymax)
+            rows.append((int(xlo), int(ylo), int(xhi), int(yhi)))
+        want = zranges_2d_arrays(rows, sfc.precision, 2000, 64)
+        got = sfc.ranges_arrays(self.BOXES, max_ranges=2000)
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(a, b)
+        with pytest.raises(ValueError):
+            sfc.ranges_arrays([(-190.0, 0.0, 0.0, 1.0)])
+
+    def test_xz2_union_cover_is_the_union_of_the_covers(self):
+        """With no range budget in the way, the cover of many boxes holds
+        exactly the codes of the boxes' own covers (a cell is tested against
+        all windows in one pass)."""
+        sfc = XZ2SFC(g=6)
+
+        def codes(ranges):
+            return {c for r in ranges for c in range(r.lower, r.upper + 1)}
+
+        boxes = self.BOXES[:4] + [(-60.0, -30.0, 45.0, 50.0)]
+        want = set()
+        for box in boxes:
+            want |= codes(sfc.ranges_bbox([box]))
+        assert codes(sfc.ranges_bbox(boxes)) == want and want

@@ -12,7 +12,7 @@ import urllib.request
 import numpy as np
 import pytest
 
-from geomesa_tpu import trace
+from geomesa_tpu import config, trace
 from geomesa_tpu.datastore import TpuDataStore
 from geomesa_tpu.durability import faults
 from geomesa_tpu.features.table import FeatureTable
@@ -108,12 +108,17 @@ def test_every_batch_event_holds_all_stages(wave):
         for start, dur in e["stages"].values():
             assert dur >= 0 and start > 1e12   # epoch ms
         for key in ("launch_ms", "ready_ms", "plan_loop_cpu_ms",
-                    "plan_misses", "cover_misses", "union_tier", "tier",
+                    "plan_misses", "cover_misses", "cover_boxes",
+                    "cover_ranges", "union_tier", "tier",
                     "first_call", "queue_depth", "threads", "cycle_size"):
             assert key in e, key
         assert e["kernel"] == "count_multi_blocks.point_boxes"
         assert e["union_tier"] >= 8 and e["tier"] >= e["batch_size"]
         assert e["plan_misses"] <= e["cycle_size"]
+        # one decomposition for all the boxes of the dispatch, under one
+        # scan's range budget however many they are (two week bins here)
+        assert e["cover_boxes"] == e["batch_size"]
+        assert 0 < e["cover_ranges"] <= 2 * 2 * config.SCAN_RANGES_TARGET.get()
 
 
 @pytest.mark.parametrize("thread", ["collector", "completer"])
@@ -185,18 +190,25 @@ def test_plan_loop_cpu_counter_and_wall(wave):
     assert cpu_us / 1e6 <= loop_s * 1.05 + 0.02
 
 
-def test_plan_and_range_decompose_fed_once_per_miss(wave):
-    """The double feed is gone: on the scheduled path the `plan` timer
-    counts plan-cache misses, `range_decompose` cover-cache misses."""
+def test_range_decompose_counts_group_covers_and_plan_plan_misses(wave):
+    """On the scheduled path the `plan` timer counts plan-cache misses and
+    `range_decompose` the group covers: one a dispatch, not one a request."""
     before, after = wave["before"]["timers"], wave["after"]["timers"]
     s0, s1 = wave["stats0"], wave["stats1"]
     plan_misses = s1["plan_cache"]["misses"] - s0["plan_cache"]["misses"]
-    cover_misses = s1["cover_cache"]["misses"] - s0["cover_cache"]["misses"]
+    covers = s1["group_covers"] - s0["group_covers"]
     assert plan_misses == 128
     assert _delta(after, before, "plan", "count") == plan_misses
-    assert _delta(after, before, "range_decompose", "count") == cover_misses
-    assert sum(e["plan_misses"] for e in wave["events"]
-               if e is _first_of_cycle(wave, e)) == plan_misses
+    assert covers == len(wave["events"]) < 128
+    assert _delta(after, before, "range_decompose", "count") == covers
+    firsts = [e for e in wave["events"] if e is _first_of_cycle(wave, e)]
+    assert sum(e["plan_misses"] for e in firsts) == plan_misses
+    assert sum(e["cover_misses"] for e in firsts) == covers
+    assert s1["cover_boxes_mean"] > 1.0
+    # the cover's seconds are the cycle's `cover` stage
+    cover_s = _delta(after, before, "sched.stage.cover", "total_s")
+    decomposed_s = _delta(after, before, "range_decompose", "total_s")
+    assert abs(cover_s - decomposed_s) <= 0.02 * max(cover_s, 1e-3)
 
 
 def test_queue_wait_ends_when_the_batch_closed(wave):
@@ -216,7 +228,7 @@ def test_queue_wait_ends_when_the_batch_closed(wave):
         assert len({r.t_launch for r in grp}) == 1
         for r in grp:
             assert r.t_submit <= r.t_closed <= r.t_plan[0] <= r.t_plan[1] \
-                <= r.t_plan[2] <= r.t_launch
+                <= r.t_launch
             assert r.scan_s > 0
 
 

@@ -113,11 +113,13 @@ def test_zranges_parity_with_python_bfs():
                 b.append((lo, hi))
             boxes.append(b)
         mr = int(rng.choice([50, 500, 2000]))
-        nat = R._zranges_arrays(boxes, bits, dims, mr, 64)
+        blo = np.array([[d[0] for d in b] for b in boxes])
+        bhi = np.array([[d[1] for d in b] for b in boxes])
+        nat = R._zranges_arrays(blo, bhi, bits, mr, 64)
         config.NO_NATIVE.set(True)
         N._lib, N._load_failed = None, False
         try:
-            py = R._zranges_arrays(boxes, bits, dims, mr, 64)
+            py = R._zranges_arrays(blo, bhi, bits, mr, 64)
         finally:
             config.NO_NATIVE.unset()
             N._lib, N._load_failed = None, False

@@ -1,5 +1,5 @@
 """Micro-batching query scheduler (serve/scheduler.py): coalescing
-correctness, plan/cover caching, generation invalidation, trace integration,
+correctness, plan caching, group covers, generation invalidation, trace integration,
 kernel-cache bounding, and the web serving path."""
 
 import json
@@ -111,7 +111,7 @@ def test_count_future_async_api(store):
     assert req.future.done()
 
 
-# -- plan/cover caches --------------------------------------------------------
+# -- plan cache, covers -------------------------------------------------------
 
 
 def test_plan_cache_hit_skips_plan_stage_in_trace(store):
@@ -131,16 +131,60 @@ def test_plan_cache_hit_skips_plan_stage_in_trace(store):
     assert "scan" in second["stages_ms"]
 
 
-def test_cover_cache_shared_across_residuals(store):
-    """Same boxes/windows under different residuals share one host range
-    decomposition through the cover cache."""
+def test_lone_repeat_keeps_its_cover_on_the_cached_plan(store):
+    """A group of one covers through the plan object the plan cache holds:
+    the same query again, alone, decomposes nothing."""
     sched = store.scheduler()
-    box = "BBOX(geom, -8, -1, 12, 19) AND " + DURING
-    hits0 = sched.covers.hits
-    n_all = sched.count("t", box)
-    n_v = sched.count("t", f"{box} AND v < 50")
-    assert n_v <= n_all
-    assert sched.covers.hits > hits0
+    q = "BBOX(geom, -8, -1, 12, 19) AND " + DURING
+    first = sched.submit("t", q)
+    n1 = first.result(timeout=30)
+    covers = sched.stats()["group_covers"]
+    assert first.plan.blocks is not False
+    again = sched.submit("t", q)
+    assert again.result(timeout=30) == n1 == store.count("t", q)
+    if not again.result_cache_hit:
+        assert again.plan is first.plan and again.batch_id != first.batch_id
+    assert sched.stats()["group_covers"] == covers
+
+
+def test_a_group_is_covered_once_for_all_its_boxes(store):
+    sched = store.scheduler()
+    st0 = sched.stats()
+    qs = [f"BBOX(geom, {-40 + 2 * i}, {-30 + i}, {-37 + 2 * i}, {-27 + i}) "
+          f"AND {DURING} AND v < 90" for i in range(24)]
+    reqs = [sched.submit("t", q) for q in qs]
+    got = [r.result(timeout=30) for r in reqs]
+    assert got == [store.count("t", q) for q in qs]
+    st1 = sched.stats()
+    batches = {r.batch_id for r in reqs}
+    assert None not in batches
+    # one cover a dispatch, and none kept on a member's plan
+    assert st1["group_covers"] - st0["group_covers"] == len(batches) < 24
+    assert st1["cover_boxes_mean"] > 1.0
+    assert all(r.plan.blocks is False for r in reqs if r.batch_size > 1)
+    assert "cover_cache" not in st1
+
+
+def test_a_cover_fault_fails_its_group_only(store, monkeypatch):
+    sched = store.scheduler()
+    qs = [f"BBOX(geom, {-33 + i}, -12, {-31 + i}, -9) AND {DURING}"
+          for i in range(3)]
+    index = store.planner("t").plan(qs[0]).index
+
+    def boom(self, boxes, intervals):
+        raise RuntimeError("cover fault")
+
+    monkeypatch.setattr(type(index), "cover_blocks", boom)
+    reqs = [sched.submit("t", q) for q in qs]
+    single = sched.submit("t", "v < 50")
+    assert single.result(timeout=30) == store.count("t", "v < 50")
+    for r in reqs:
+        with pytest.raises(RuntimeError, match="cover fault"):
+            r.result(timeout=30)
+    monkeypatch.undo()
+    assert sched.healthy()
+    assert sched.count("t", qs[0] + " AND v < 99") == \
+        store.count("t", qs[0] + " AND v < 99")
 
 
 def test_generation_invalidates_on_ingest(store):
