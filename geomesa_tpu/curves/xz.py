@@ -82,22 +82,19 @@ class XZSFC:
         length = np.where((l1 < self.g) & np.all(fits, axis=1), l1 + 1, l1)
 
         # sequence code: walk the tree `length` levels toward the bbox's min
-        # corner (XZ2SFC.sequenceCode, :264-286), all features in lockstep
+        # corner (XZ2SFC.sequenceCode, :264-286), all features in lockstep.
+        # A cell halves at every level, so "min corner >= the cell's centre"
+        # at level i is binary digit i of the corner's normalized position
+        # (1.0 reads all ones); times 2^g and floor are exact in f64
+        top = (1 << self.g) - 1
+        digits = np.minimum(np.floor(np.ldexp(nmins, self.g)), top
+                            ).astype(np.int64)
         cs = np.zeros(n, dtype=np.int64)
-        lo = np.zeros((n, self.dims))
-        hi = np.ones((n, self.dims))
-        pos = nmins
         for i in range(self.g):
-            active = i < length
-            center = (lo + hi) / 2.0
-            upper = pos >= center  # per-dim quadrant bit
             quadrant = np.zeros(n, dtype=np.int64)
             for d in range(self.dims):
-                quadrant |= upper[:, d].astype(np.int64) << d
-            cs = np.where(active, cs + 1 + quadrant * self._seq_term(i), cs)
-            sel = active[:, None] & upper
-            lo = np.where(sel, center, lo)
-            hi = np.where(active[:, None] & ~upper, center, hi)
+                quadrant |= ((digits[:, d] >> (self.g - 1 - i)) & 1) << d
+            cs += np.where(i < length, 1 + quadrant * self._seq_term(i), 0)
         return cs
 
     # -- query decomposition ----------------------------------------------

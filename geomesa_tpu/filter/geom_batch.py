@@ -315,10 +315,10 @@ def _any_point_on_each_segment(pts: np.ndarray, segs: np.ndarray,
         sub = segs[i:i + step]
         x1, y1 = sub[:, 0][:, None], sub[:, 1][:, None]
         x2, y2 = sub[:, 2][:, None], sub[:, 3][:, None]
-        cross = (x2 - x1) * (py - y1) - (y2 - y1) * (px - x1)
-        scale = np.maximum(np.abs(x2 - x1), np.abs(y2 - y1)) + eps
-        collinear = np.abs(cross) <= eps * scale * np.maximum(
-            1.0, np.maximum(np.abs(px), np.abs(py)))
+        t1 = (x2 - x1) * (py - y1)
+        t2 = (y2 - y1) * (px - x1)
+        collinear = np.abs(t1 - t2) <= gn._CROSS_ROUNDING * (
+            np.abs(t1) + np.abs(t2))
         within = ((np.minimum(x1, x2) - eps <= px)
                   & (px <= np.maximum(x1, x2) + eps)
                   & (np.minimum(y1, y2) - eps <= py)

@@ -10,13 +10,20 @@ index-key order with the serialized values (SURVEY.md §3.2 step 4). Here the
   - ``bin``/``off`` int32 exact binned time (period bin + integer offset in
                    period units — ms/s/min, exactly representable)
   - bbox columns (extent geometries): f32 xmin/ymin/xmax/ymax
+  - the segment pool (extent layers): every feature's segments in row
+                   order, ``__seg__`` (4, segments) f32, rows
+                   sx1/sy1/sx2/sy2, and ``__way__`` (3, rows) int32, rows
+                   seg_off/seg_n/kind, so the ways of a gather block are one
+                   contiguous span and a tile of it one slice (the banded
+                   intersects refine, scan.intersects_band_blocks). Ragged:
+                   added after the build or merge of the per-row columns,
+                   never gathered or merged as one of them
   - attribute columns: numeric as int32/f32; strings as dictionary codes;
                    dates additionally as (bin, off) when they are the primary
                    temporal axis
 
 Only numeric-representable projections live on device; exact f64 coordinates
-and ragged geometry buffers stay host-side for refinement (the reference's
-full-filter path).
+stay host-side for refinement (the reference's full-filter path).
 """
 
 from __future__ import annotations
@@ -224,6 +231,83 @@ class DeviceTable:
                     lambda: jax.jit(lambda c, p: c[p]))
                 out[name] = g(jnp.asarray(codes), new_perm)
         return cls(n_new, out), new_perm
+
+
+# the segment pool's planes among a DeviceTable's columns
+SEG, WAY = "__seg__", "__way__"
+
+
+def segment_pool(garr: GeometryArray, perm: np.ndarray, pad: int):
+    """An extent layer's segment pool in ``perm`` (the index's row) order:
+    (device planes, the host's ``seg_off``), or None for an empty layer or
+    one past int32 offsets. A ring of k vertices gives k - 1 segments (a
+    Polygon's rings are stored closed), a lone vertex the degenerate segment
+    at it, and no segment bridges two rings. ``way`` says of every row where
+    its segments start, how many they are, and its kind: bit 0 for an areal
+    geometry (it may hold a query polygon that crosses none of its rings),
+    bit 1 for one of several parts (one vertex outside says nothing of the
+    other parts). ``seg_off`` has one entry more than rows (the pool's
+    length); the planes carry ``pad`` segments beyond it so that a tile read
+    from any offset stays inside.
+
+    In table order a row's segments are one run of the vertices that start
+    a segment, so in ``perm`` order the run's index goes up by one inside a
+    row and jumps where a row begins: a running sum gives it without a
+    ragged expansion. The device gathers the four planes from the f32
+    vertices."""
+    import jax
+
+    from geomesa_tpu.features.geometry import MULTIPOLYGON, POLYGON
+    from geomesa_tpu.obs import attrib as _attrib
+
+    if len(garr) == 0 or len(garr.coords) >= 1 << 31:
+        return None
+    ro = garr.ring_offsets
+    ring_len = np.diff(ro)
+    if np.any(ring_len == 0):
+        return None
+    open_ring = ring_len >= 2    # its last vertex starts no segment
+    starts = np.ones(len(garr.coords), dtype=bool)
+    starts[ro[1:][open_ring] - 1] = False
+    a_tab = np.flatnonzero(starts).astype(np.int32)
+    # rows → rings → vertices, and the segments before a row's first
+    r0 = garr.part_offsets[garr.geom_offsets[:-1]]
+    r1 = garr.part_offsets[garr.geom_offsets[1:]]
+    rings_before = np.zeros(len(ro), dtype=np.int64)
+    np.cumsum(open_ring, out=rings_before[1:])
+    seg_first = (ro[r0] - rings_before[r0])[perm]
+    seg_n = ((ro[r1] - ro[r0]) - (rings_before[r1] - rings_before[r0]))[perm]
+    seg_off = np.zeros(len(perm) + 1, dtype=np.int64)
+    np.cumsum(seg_n, out=seg_off[1:])
+    total = int(seg_off[-1])
+    if total + pad >= 1 << 31:
+        return None
+    step = np.ones(total + pad, dtype=np.int32)
+    step[total:] = 0
+    step[seg_off[1:-1]] = seg_first[1:] - (seg_first[:-1] + seg_n[:-1] - 1)
+    step[0] = seg_first[0]
+    a = a_tab[np.cumsum(step, out=step)]
+    # the other end: the next vertex, or the same one for a lone vertex
+    b = a + 1
+    if not open_ring.all():
+        lone = np.zeros(len(garr.coords), dtype=bool)
+        lone[ro[:-1][~open_ring]] = True
+        b -= lone[a]
+    b[total:] = a[total:]
+    kind = (np.isin(garr.type_codes, (POLYGON, MULTIPOLYGON)).astype(np.int32)
+            | (np.diff(garr.geom_offsets) > 1).astype(np.int32) << 1)[perm]
+    xy = [jnp.asarray(garr.coords[:, k].astype(np.float32)) for k in (0, 1)]
+    _attrib.record_transfer(
+        "device_table.segment_pool", 1,
+        sum(int(v.nbytes) for v in (*xy, a, b)) + 12 * len(perm))
+    gather = _merge_cache().get(
+        ("segment_pool", len(garr.coords), len(a)),
+        lambda: jax.jit(lambda x, y, a, b: jnp.stack(
+            [x[a], y[a], x[b], y[b]])))
+    seg_off = seg_off.astype(np.int32)
+    return {SEG: gather(*xy, jnp.asarray(a), jnp.asarray(b)),
+            WAY: jnp.asarray(np.stack([seg_off[:-1], seg_n.astype(np.int32),
+                                       kind]))}, seg_off
 
 
 _MERGE_CACHE = None

@@ -30,6 +30,7 @@ from geomesa_tpu.filter import ir
 from geomesa_tpu.filter.parser import parse_ecql
 from geomesa_tpu.index.api import IndexScanPlan, QueryResult, UnionScanPlan
 from geomesa_tpu.index import prune as _prune
+from geomesa_tpu.metrics import REGISTRY as _metrics
 from geomesa_tpu.serve.resilience import deadline as _rdl
 
 _SELECT_CAP = 1 << 16
@@ -425,42 +426,57 @@ class QueryPlanner:
             return plan.index.kernels.count(
                 plan.primary_kind, plan.boxes_loose, plan.windows,
                 plan.residual_device)
-        fused = _fused.try_count_refine(self, plan)
-        if fused is not None:
-            return fused
         fast = self._band_intersects_count(plan)
         if fast is not None:
             return fast
+        fused = _fused.try_count_refine(self, plan)
+        if fused is not None:
+            return fused
         return len(self.select_indices(
             f if isinstance(f, ir.Filter) else parse_ecql(f),
             plan=plan, auths=auths))
 
     def _band_intersects_count(self, plan) -> Optional[int]:
-        """Device certainty-band count for the common extent query shape —
-        a single polygon-INTERSECTS residual over a single-segment layer:
-        the kernel classifies candidates as certain-hit / certain-miss /
-        uncertain (f32 error bands), and only the uncertain sliver refines
-        on host in exact f64. None when the shape doesn't apply."""
+        """Device certainty-band count for the common extent query shape: a
+        single polygon-INTERSECTS residual over an extent layer whose index
+        keeps a segment pool. The kernel classifies the candidate blocks'
+        ways as certain-hit / certain-miss / uncertain (f32 error bands),
+        and only the uncertain sliver refines on host in exact f64. None
+        when the shape doesn't apply, or when the uncertain ways overflowed
+        the kernel's list (counted; the caller refines every candidate)."""
         res = plan.residual_host
         if not (isinstance(res, ir.Intersects) and plan.index is not None
                 and plan.candidate_slices is None
                 and plan.primary_kind == "bbox_overlap"):
             return None
         from geomesa_tpu.features import geometry as geo
-        code = res.geometry[0]
-        if code != geo.POLYGON:
+        if res.geometry[0] != geo.POLYGON:
             return None
-        if not getattr(plan.index, "ensure_segment_columns", lambda: False)():
+        seg_off = getattr(plan.index, "seg_off", None)
+        if seg_off is None:
             return None
-        blocks = self._pruned_blocks(plan)
-        if blocks is None or len(blocks) == 0:
-            return 0 if blocks is not None else None
+        blocks, bsz = self._pruned_blocks(plan), _prune.BLOCK_SIZE
+        if blocks is None:
+            # no cover, or one too wide to pay for a point scan: every block
+            # (a table under one block is one block of its own size)
+            bsz = min(bsz, len(self.table))
+            blocks = np.arange(-(-len(self.table) // bsz), dtype=np.int32)
+        if len(blocks) == 0:
+            return 0
         from geomesa_tpu.filter.geom_numpy import literal_segments
         edges = literal_segments(res.geometry).astype(np.float32)
-        certain, unc = plan.index.kernels.intersects_band_blocks(
-            plan.primary_kind, plan.boxes_loose, plan.windows,
-            plan.residual_device, edges, blocks, _prune.BLOCK_SIZE)
+        with _trace.span("refine.device", blocks=len(blocks),
+                         edges=len(edges)) as sp:
+            certain, unc, band = plan.index.kernels.intersects_band_blocks(
+                plan.primary_kind, plan.boxes_loose, plan.windows,
+                plan.residual_device, edges, blocks, bsz, seg_off)
+            sp.set(ways=band["candidate_ways"], segments=band["segments"],
+                   certain=certain, uncertain=band["uncertain_ways"])
+        _metrics.inc("refine.segments_tested", band["segments"])
+        _metrics.inc("refine.ways_candidate", band["candidate_ways"])
+        _metrics.inc("refine.ways_uncertain", band["uncertain_ways"])
         if unc is None:
+            _metrics.inc("refine.overflow_fallbacks")
             return None  # uncertainty overflow: full host refine instead
         if len(unc) == 0:
             return certain

@@ -32,6 +32,7 @@ import numpy as np
 
 from geomesa_tpu import trace as _trace
 from geomesa_tpu.filter import ir
+from geomesa_tpu.index.device import SEG, WAY
 from geomesa_tpu.obs import attrib as _attrib
 from geomesa_tpu.obs import profiling as _prof
 
@@ -156,6 +157,7 @@ _PRIMARY_COLS: Dict[str, tuple] = {
 _F32_EPS = np.float32(1.2e-7)     # 2^-23 with margin
 _IN_DELTA = np.float32(2.5e-5)    # |f64 coord - f32 coord| bound (lon/lat)
 _DY_BAND = np.float32(3e-5)       # vertex y-tie band for the crossing rule
+_BOX_GAP = 2 * _IN_DELTA          # envelopes this far apart are apart in f64
 
 
 def _orient_band(px, py, qx, qy, rx, ry):
@@ -173,18 +175,25 @@ def _orient_band(px, py, qx, qy, rx, ry):
     return det, tol
 
 
-def _pip_band(px, py, ex1, ey1, ex2, ey2, evalid=None):
-    """(certainly-inside, certainly-outside) of points vs polygon edges via
-    the half-open crossing rule; uncertain when any edge's crossing decision
-    sits inside its error band or a vertex y ties the ray. ``evalid``
-    masks padded edges out of both crossings and uncertainty (pair-kernel
-    padded tables)."""
+def _pip_edge_band(px, py, ex1, ey1, ex2, ey2):
+    """One polygon edge against the +x ray of a point, by the half-open
+    crossing rule: (certain crossing, uncertain). Uncertain when the crossing
+    decision sits inside its error band or a vertex y ties the ray; an edge
+    wholly to the left of the point can neither cross its ray nor make it
+    uncertain."""
     cond = (ey1 > py) != (ey2 > py)
     o, t = _orient_band(ex1, ey1, ex2, ey2, px, py)
-    upward = ey2 > ey1
-    cross = cond & jnp.where(upward, o > t, o < -t)
+    cross = cond & jnp.where(ey2 > ey1, o > t, o < -t)
     unc = (cond & (jnp.abs(o) <= t)) \
         | (jnp.abs(ey1 - py) <= _DY_BAND) | (jnp.abs(ey2 - py) <= _DY_BAND)
+    return cross, unc & (px <= jnp.maximum(ex1, ex2) + _BOX_GAP)
+
+
+def _pip_band(px, py, ex1, ey1, ex2, ey2, evalid=None):
+    """(certainly-inside, certainly-outside) of points vs polygon edges on
+    the last axis. ``evalid`` masks padded edges out of both crossings and
+    uncertainty (pair-kernel padded tables)."""
+    cross, unc = _pip_edge_band(px, py, ex1, ey1, ex2, ey2)
     if evalid is not None:
         cross = cross & evalid
         unc = unc & evalid
@@ -194,7 +203,12 @@ def _pip_band(px, py, ex1, ey1, ex2, ey2, evalid=None):
 
 
 def _segpair_band(ax, ay, bx, by, cx, cy, dx, dy):
-    """(certain-intersect, certain-miss) for segment (a,b) vs edge (c,d)."""
+    """(certain-intersect, certain-miss) for segment (a,b) vs edge (c,d).
+    A pair whose envelopes lie apart by more than the inputs' rounding is a
+    certain miss whatever its orientations say: a short segment's own line
+    is known too poorly (its direction to within delta / length) to place a
+    far edge on one side of it, so without this every segment near an
+    edge's *line*, however far from the edge, would stay uncertain."""
     o1, t1 = _orient_band(ax, ay, bx, by, cx, cy)
     o2, t2 = _orient_band(ax, ay, bx, by, dx, dy)
     o3, t3 = _orient_band(cx, cy, dx, dy, ax, ay)
@@ -203,7 +217,11 @@ def _segpair_band(ax, ay, bx, by, cx, cy, dx, dy):
     opp34 = ((o3 > t3) & (o4 < -t4)) | ((o3 < -t3) & (o4 > t4))
     same12 = ((o1 > t1) & (o2 > t2)) | ((o1 < -t1) & (o2 < -t2))
     same34 = ((o3 > t3) & (o4 > t4)) | ((o3 < -t3) & (o4 < -t4))
-    return opp12 & opp34, same12 | same34
+    apart = ((jnp.maximum(ax, bx) + _BOX_GAP < jnp.minimum(cx, dx))
+             | (jnp.minimum(ax, bx) - _BOX_GAP > jnp.maximum(cx, dx))
+             | (jnp.maximum(ay, by) + _BOX_GAP < jnp.minimum(cy, dy))
+             | (jnp.minimum(ay, by) - _BOX_GAP > jnp.maximum(cy, dy)))
+    return opp12 & opp34, same12 | same34 | apart
 
 
 _EARTH_R_M = 6371008.8
@@ -434,6 +452,73 @@ class _LazyBlockGather:
     def values(self):
         # row-count probes (Include/Exclude) only need a .shape[0]
         yield self._starts.repeat(self._bsz)
+
+
+# segments a tile of the pool holds: the unit a span is gathered and padded in
+POOL_TILE = 4096
+
+
+def _slices(planes, starts, width: int):
+    """(len(starts), rows, width): the columns [s, s + width) of a
+    (rows, n) array for every start, all rows of a slice in one read (a
+    slice a plane is what the device's time goes on, a few µs each)."""
+    from jax import lax, vmap
+    rows = planes.shape[0]
+    return vmap(lambda s: lax.dynamic_slice(
+        planes, (0, s), (rows, width)))(starts)
+
+
+def _classify_segments(ax, ay, bx, by, edges, n_edges):
+    """(3, tiles, POOL_TILE) int32 flags of pool segments (a, b) against the
+    first ``n_edges`` rows of ``edges`` (E, 4): [certain hit: the segment
+    surely crosses an edge or ``a`` is surely inside; pair open: some edge is
+    not surely missed; ``a`` surely outside]. One edge a turn, so nothing of
+    shape (segments, edges) is ever held."""
+    from jax import lax
+
+    def one(i, carry):
+        hit, open_, par, punc = carry
+        ex1, ey1, ex2, ey2 = (edges[i, k] for k in range(4))
+        hit_p, miss_p = _segpair_band(ax, ay, bx, by, ex1, ey1, ex2, ey2)
+        cross, unc = _pip_edge_band(ax, ay, ex1, ey1, ex2, ey2)
+        return hit | hit_p, open_ | ~miss_p, par ^ cross, punc | unc
+
+    no = jnp.zeros(ax.shape, dtype=bool)
+    hit, open_, inside, punc = lax.fori_loop(0, n_edges, one,
+                                             (no, no, no, no))
+    return jnp.stack([hit | (inside & ~punc), open_,
+                      ~inside & ~punc]).astype(jnp.int32)
+
+
+def _running_sum(x):
+    """Inclusive running sum over the last two axes taken as one: within a
+    row, plus the rows before it."""
+    inner = jnp.cumsum(x, axis=-1)
+    last = inner[..., -1]
+    return inner + (jnp.cumsum(last, axis=-1) - last)[..., None]
+
+
+def _first_set(mask, cap: int):
+    """Flat positions of the first ``cap`` set entries of a 2-D mask, the
+    mask's size where there are fewer: the j-th is where the running count
+    first reaches j + 1. In place of ``jnp.nonzero(size=cap)``, whose scatter
+    takes the TPU compiler half a minute at a million rows."""
+    count = _running_sum(mask.astype(jnp.int32)).reshape(-1)
+    return jnp.searchsorted(count, jnp.arange(1, cap + 1, dtype=jnp.int32),
+                            side="left")
+
+
+def _span_sums(flags, p0, p1):
+    """Sums of each flag row over the flat positions [p0, p1) of every way:
+    a running sum within a tile plus the tiles before it, read at the two
+    ends of the span. Spans of rows that are no candidates may lie anywhere:
+    their ends are clipped and their sums masked by the caller."""
+    k, tiles, width = flags.shape
+    upto = _running_sum(flags).reshape(k, tiles * width)
+    # sum of [0, p) = upto[p - 1]
+    at = lambda p: jnp.where(
+        p > 0, upto[:, jnp.clip(p - 1, 0, tiles * width - 1)], 0)
+    return at(p1) - at(p0)
 
 
 import weakref
@@ -688,38 +773,48 @@ class ScanKernels:
                     sel = rowids[jnp.clip(idxs, 0, rowids.shape[0] - 1)]
                     return -vals, sel.astype(jnp.int32)
             elif mode == "intersects_band_blocks":
-                # exact segment-vs-polygon intersects over candidate blocks,
-                # in f32 with certainty bands: returns [certain_hit_count,
-                # n_uncertain, uncertain_row_ids...]; the host refines only
-                # the uncertain sliver in exact f64 (geom_batch)
-                unc_cap = capacity[3]
+                # exact extent × polygon intersects over the candidate
+                # blocks' spans of the segment pool, in f32 with certainty
+                # bands: [certain hits, uncertain ways, candidate ways,
+                # uncertain row ids...]; the host refines only the uncertain
+                # sliver in exact f64 (geom_batch)
+                unc_cap, _, ntiles = capacity[3:]
 
-                def run(cols, boxes, windows, rparams, edges, block_ids):
+                def run(cols, boxes, windows, rparams, edges, n_edges,
+                        block_ids, tile_starts, flat_delta):
                     m, rowids, g = blocks_mask(cols, boxes, windows, rparams,
                                                block_ids)
-                    ax, ay = g["sx1"], g["sy1"]
-                    bx, by = g["sx2"], g["sy2"]
-                    ex1 = edges[None, :, 0]
-                    ey1 = edges[None, :, 1]
-                    ex2 = edges[None, :, 2]
-                    ey2 = edges[None, :, 3]
-                    hit_p, miss_p = _segpair_band(
-                        ax[:, None], ay[:, None], bx[:, None], by[:, None],
-                        ex1, ey1, ex2, ey2)
-                    in_a, out_a = _pip_band(ax[:, None], ay[:, None],
-                                            ex1, ey1, ex2, ey2)
-                    in_b, out_b = _pip_band(bx[:, None], by[:, None],
-                                            ex1, ey1, ex2, ey2)
-                    hit = m & (in_a | in_b | jnp.any(hit_p, axis=1))
-                    miss = out_a & out_b & jnp.all(miss_p, axis=1)
+                    seg = _slices(cols[SEG], tile_starts, POOL_TILE)
+                    flags = _classify_segments(
+                        *(seg[:, k] for k in range(4)), edges, n_edges)
+                    # by way: a way's segments are one span of the flat
+                    # tiles, at its pool offset plus its block's shift
+                    way = _slices(cols[WAY], g._starts, bsz)
+                    seg_n, kind = (way[:, k].reshape(-1) for k in (1, 2))
+                    p0 = way[:, 0].reshape(-1) + jnp.repeat(flat_delta, bsz)
+                    hits, pair_open, outside = _span_sums(
+                        flags, p0, p0 + seg_n)
+                    hit = m & (hits > 0)
+                    # no pair left open: the way's rings cross nothing, so
+                    # each part lies on one side, and a vertex surely
+                    # outside settles its part (of a way of several parts,
+                    # every vertex). An areal way may still hold the whole
+                    # polygon: one that could, by its envelope, stays open
+                    holds = ((kind & 1) > 0) \
+                        & (g["bxmin"] - _BOX_GAP <= edges[0, 0]) \
+                        & (g["bxmax"] + _BOX_GAP >= edges[0, 0]) \
+                        & (g["bymin"] - _BOX_GAP <= edges[0, 1]) \
+                        & (g["bymax"] + _BOX_GAP >= edges[0, 1])
+                    miss = (pair_open == 0) & ~holds & jnp.where(
+                        (kind & 2) > 0, outside == seg_n, outside > 0)
                     unc = m & ~hit & ~miss
                     total = m.shape[0]
-                    sel = jnp.nonzero(unc, size=unc_cap, fill_value=total)[0]
+                    sel = _first_set(unc.reshape(-1, bsz), unc_cap)
                     rows = jnp.where(sel < total,
                                      rowids[jnp.clip(sel, 0, total - 1)], n)
                     return jnp.concatenate([
-                        jnp.sum(hit)[None].astype(jnp.int32),
-                        jnp.sum(unc)[None].astype(jnp.int32),
+                        jnp.stack([jnp.sum(hit), jnp.sum(unc),
+                                   jnp.sum(m)]).astype(jnp.int32),
                         rows.astype(jnp.int32)])
             elif mode == "density_blocks":
                 # pruned heat-map: candidate blocks gather (contiguous HBM
@@ -1033,39 +1128,69 @@ class ScanKernels:
         db = jnp.asarray(b)
         return lambda: fn(cols, bx, w, rp, g, db)
 
-    # polygon-edge pad: far-away horizontal edges (ey1 == ey2 → no crossing;
-    # orientation signs large and same → certain-miss) so padded lanes never
-    # create hits or uncertainty
+    # polygon-edge pad of the fused refine (index/compiled.py): far-away
+    # horizontal edges (ey1 == ey2 → no crossing; orientation signs large
+    # and same → certain-miss) so padded lanes never create hits or
+    # uncertainty
     _EDGE_PAD = np.array([1e9, 1e9, 2e9, 1e9], dtype=np.float32)
 
     def intersects_band_blocks(self, primary_kind, boxes, windows, residual,
                                edges: np.ndarray, blocks: np.ndarray,
-                               block_size: int, unc_cap: int = 4096):
-        """(certain_hit_count, uncertain_row_positions) for exact
-        segment-feature × polygon intersects over candidate blocks. The
-        uncertain positions (rows within the f32 certainty band of a
-        boundary) need the host's exact f64 refine; returns None for the
+                               block_size: int, seg_off: np.ndarray,
+                               unc_cap: int = 16384):
+        """(certain_hit_count, uncertain_row_positions, facts) for exact
+        extent × polygon intersects over candidate blocks. A block's ways
+        are one span of the segment pool (``seg_off``: the host's copy of
+        the ways' offsets into it, one more than ways); the spans are read
+        in tiles of ``POOL_TILE`` segments, their number padded to a tier as
+        the blocks' is, and a way longer than a tile simply runs on into the
+        next. The uncertain positions (ways within the f32 certainty band of
+        the boundary) need the host's exact f64 refine; None for the
         positions when they overflowed ``unc_cap`` (caller falls back to the
-        full host refine)."""
-        b = self._pad_blocks(blocks)
-        ne = max(4, 1 << max(0, (len(edges) - 1)).bit_length())
-        ep = np.tile(self._EDGE_PAD, (ne, 1))
+        full host refine). Long block lists go out as several launches
+        (``band_launches``), one after another: each has its temporaries to
+        itself. ``facts``: ``candidate_ways`` (passed the envelope mask),
+        ``uncertain_ways``, and the pool ``segments`` the spans hold."""
+        # the edges ride in an array padded to a tier and the loop runs over
+        # the real ones: a tier is one program, so polygons of up to
+        # BAND_MIN_EDGES edges (a drawn area, a district) share one
+        ne = max(BAND_MIN_EDGES, 1 << max(0, (len(edges) - 1)).bit_length())
+        ep = np.zeros((ne, 4), dtype=np.float32)
         ep[: len(edges)] = edges
-        fn = self._get("intersects_band_blocks", primary_kind,
-                       windows is not None,
-                       residual[0] if residual else "none",
-                       residual[2] if residual else None,
-                       0 if boxes is None else boxes.shape[0],
-                       0 if windows is None else windows.shape[0],
-                       (b.shape[0], block_size, 0, unc_cap, ne))
         rp = [jnp.asarray(p) for p in residual[1]] if residual else []
-        out = np.asarray(_fetch(fn, self.cols, _dev(boxes), _dev(windows),
-                                rp, jnp.asarray(ep), jnp.asarray(b)))
-        certain = int(out[0])
-        n_unc = int(out[1])
-        if n_unc > unc_cap:
-            return certain, None
-        return certain, out[2: 2 + n_unc].astype(np.int64)
+        dbx, dw, dep = _dev(boxes), _dev(windows), jnp.asarray(ep)
+        n_edges = np.int32(len(edges))
+        n = len(seg_off) - 1
+        facts = {"segments": 0}
+        outs = []
+        for chunk, starts, delta, n_seg, tiers in band_launches(
+                blocks, block_size, seg_off, n):
+            b = np.full(tiers[0], -1, dtype=np.int32)
+            b[: len(chunk)] = chunk
+            tiles = np.zeros(tiers[1], dtype=np.int32)
+            tiles[: len(starts)] = starts
+            shift = np.zeros(len(b), dtype=np.int32)
+            shift[: len(delta)] = delta
+            fn = self._get("intersects_band_blocks", primary_kind,
+                           windows is not None,
+                           residual[0] if residual else "none",
+                           residual[2] if residual else None,
+                           0 if boxes is None else boxes.shape[0],
+                           0 if windows is None else windows.shape[0],
+                           (len(b), block_size, 0, unc_cap, ne, len(tiles)))
+            with _attrib.kernel(f"intersects_band_blocks.{primary_kind}",
+                                len(b)):
+                outs.append(np.asarray(_fetch(
+                    fn, self.cols, dbx, dw, rp, dep, n_edges,
+                    jnp.asarray(b), jnp.asarray(tiles), jnp.asarray(shift))))
+            facts["segments"] += n_seg
+        certain = sum(int(o[0]) for o in outs)
+        facts["candidate_ways"] = sum(int(o[2]) for o in outs)
+        facts["uncertain_ways"] = sum(int(o[1]) for o in outs)
+        if any(int(o[1]) > unc_cap for o in outs):
+            return certain, None, facts
+        return certain, np.concatenate(
+            [o[3: 3 + int(o[1])] for o in outs]).astype(np.int64), facts
 
     def topk_nearest_blocks(self, primary_kind, boxes, windows, residual,
                             qx: float, qy: float, m: int,
@@ -1160,6 +1285,66 @@ def blocks_tier(n_blocks: int) -> int:
     """Padded length of a candidate-block list: one compiled program per
     tier, so this is the shape half of a pruned kernel's identity."""
     return max(8, 1 << max(0, (n_blocks - 1)).bit_length())
+
+
+# the smallest tier a polygon's edges are padded to
+BAND_MIN_EDGES = 16
+
+# candidate blocks one launch of the banded refine takes: bounds its
+# temporaries (some 50 B a segment) and the tiers it can meet
+BAND_MAX_BLOCKS = 256
+
+
+def band_launches(blocks: np.ndarray, block_size: int, seg_off: np.ndarray,
+                  n: int):
+    """Sorted unique candidate ``blocks`` cut into the banded refine's
+    launches: (blocks, tile starts, shifts, segments, (block tier, tile
+    tier)) each. The two tiers name one compiled program, and a pair that
+    traffic rarely forms is first compiled in the middle of the load (1.3 s
+    behind which every single waits: PR 27's builder, on the chip). So past
+    smallest block tier the tile tier follows from the block tier: ``per``
+    tiles a block, the layer's own mean rounded up to a power of two. Spans
+    that need more take a wider block tier, and past the widest launch are
+    cut in two; only a lone block of very long ways keeps a tile tier of its
+    own."""
+    per = 1 << max(0, int(np.ceil(np.log2(max(
+        1.0, int(seg_off[-1]) * block_size / max(1, n) / POOL_TILE)))))
+    todo = [blocks[i: i + BAND_MAX_BLOCKS]
+            for i in range(0, len(blocks), BAND_MAX_BLOCKS)]
+    while todo:
+        chunk = todo.pop(0)
+        starts, delta, n_seg = pool_tiles(chunk, block_size, seg_off, n)
+        bt, need = blocks_tier(len(chunk)), blocks_tier(len(starts))
+        while bt * per < need and bt < BAND_MAX_BLOCKS:
+            bt *= 2
+        if bt * per < need and len(chunk) > 1:
+            todo[:0] = [chunk[: len(chunk) // 2], chunk[len(chunk) // 2:]]
+            continue
+        tt = need if bt == blocks_tier(1) else max(need, bt * per)
+        yield chunk, starts, delta, n_seg, (bt, tt)
+
+
+def pool_tiles(blocks: np.ndarray, block_size: int, seg_off: np.ndarray,
+               n: int):
+    """The segment-pool tiles that hold the spans of sorted unique candidate
+    ``blocks``: (tile starts into the pool, each block's shift from pool
+    offset to flat tile position, segments the spans hold). Neighbouring
+    blocks are one run of the pool and share tiles; a run's last tile runs on
+    into segments of ways that are no candidates, which no span reads."""
+    lo = seg_off[np.minimum(blocks.astype(np.int64) * block_size, n)]
+    hi = seg_off[np.minimum((blocks.astype(np.int64) + 1) * block_size, n)]
+    first = np.ones(len(blocks), dtype=bool)
+    first[1:] = blocks[1:] != blocks[:-1] + 1
+    run = np.cumsum(first) - 1
+    run_lo = lo[first].astype(np.int64)
+    run_hi = hi[np.append(np.flatnonzero(first)[1:] - 1, len(blocks) - 1)]
+    tiles = -(-(run_hi - run_lo) // POOL_TILE)
+    before = np.cumsum(tiles) - tiles
+    starts = np.repeat(run_lo - before * POOL_TILE, tiles) \
+        + np.arange(int(tiles.sum())) * POOL_TILE
+    delta = (before * POOL_TILE - run_lo)[run]
+    return (starts.astype(np.int32), delta.astype(np.int32),
+            int((run_hi - run_lo).sum()))
 
 
 def pad_boxes(boxes: np.ndarray, min_size: int = 1) -> np.ndarray:

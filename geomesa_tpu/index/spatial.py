@@ -404,6 +404,7 @@ class BaseSpatialIndex:
                                      type_name=sft.name):
                     self.device = DeviceTable.build(
                         table, self._perm_cache, self.period)
+        self._build_segment_pool()
         self.kernels = ScanKernels(self.device.columns)
         self.vocabs = {
             name: col.vocab for name, col in table.columns.items()
@@ -803,6 +804,7 @@ class BaseSpatialIndex:
             if new_dev_perm is not None:
                 self._dev_perm = new_dev_perm
 
+            self._build_segment_pool()
             self.kernels = ScanKernels(self.device.columns)
             self.vocabs = merged_vocabs
             self.build_stages = {
@@ -883,29 +885,25 @@ class BaseSpatialIndex:
 
     # certified segment predicates ------------------------------------------
 
-    def ensure_segment_columns(self) -> bool:
-        """Upload per-feature segment endpoints (sx1/sy1/sx2/sy2 f32) when
-        every feature is a single-segment LineString — enabling the device
-        certainty-band intersects refine (scan.intersects_band_blocks).
-        Lazy + cached; False when the layer shape doesn't qualify."""
-        cached = getattr(self, "_seg_cols_ok", None)
-        if cached is not None:
-            return cached
-        ok = False
-        garr = self.table.geometry()
-        if not garr.is_points and len(garr):
-            from geomesa_tpu.features import geometry as geo
-            counts = np.diff(garr.ring_offsets)
-            if (np.all(garr.type_codes == geo.LINESTRING)
-                    and len(counts) == len(garr) and np.all(counts == 2)):
-                import jax.numpy as jnp
-                segs = garr.coords.reshape(len(garr), 4)[self.perm]
-                for i, name in enumerate(("sx1", "sy1", "sx2", "sy2")):
-                    self.device.columns[name] = jnp.asarray(
-                        segs[:, i].astype(np.float32))
-                ok = True
-        self._seg_cols_ok = ok
-        return ok
+    def _build_segment_pool(self) -> None:
+        """Every feature's segments onto the device in this index's row
+        order (columns ``__seg__`` and ``__way__``), for the banded refine
+        (scan.intersects_band_blocks); ``seg_off`` stays on the host too,
+        where a query's blocks become spans of the pool. Extent indexes
+        only. Built whole at every build and merge: an append to an extent
+        type uploads the pool again."""
+        self.seg_off = None
+        if self.points or self.geom is None:
+            return
+        from geomesa_tpu.index.device import segment_pool
+        from geomesa_tpu.index.scan import POOL_TILE
+        from geomesa_tpu.obs.profiling import PROGRESS as _progress
+        with _progress.phase("segment_pool", rows=len(self.table),
+                             type_name=self.sft.name):
+            built = segment_pool(self.table.geometry(), self.perm, POOL_TILE)
+            if built is not None:
+                planes, self.seg_off = built
+                self.device.columns.update(planes)
 
     # range pruning ---------------------------------------------------------
 

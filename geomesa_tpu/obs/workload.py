@@ -330,13 +330,18 @@ class WorkloadAnalytics:
     # -- consumer side (deferred) ---------------------------------------------
 
     def drain(self) -> int:
-        """Fold every pending event into windows/sketches/meters.
-        Reentrancy-safe and cheap when idle (one truthiness check)."""
+        """Fold the events pending at entry into windows/sketches/meters.
+        Reentrancy-safe and cheap when idle (one truthiness check). What
+        producers append meanwhile waits for the next reader: a drain that
+        ran until the queue was empty never returned while eight clients
+        kept it filling, and the page that had asked for it (``/healthz``,
+        ``/metrics``) timed out after 120 s (PR 28, `select-c8` rehearsed on
+        the CPU, where a select is answered faster than it is folded)."""
         if not self._pending:
             return 0
         out = 0
         with self._lock:
-            while True:
+            for _ in range(len(self._pending)):
                 try:
                     item = self._pending.popleft()
                 except IndexError:

@@ -256,7 +256,7 @@ class Request:
     __slots__ = ("type_name", "f_ir", "f_key", "auths", "auths_key",
                  "planner", "delta", "generation", "epoch", "future",
                  "t_submit", "t_closed", "t_plan", "t_launch",
-                 "plan", "queue_wait_s", "scan_s",
+                 "plan", "queue_wait_s", "scan_s", "staged",
                  "batched", "batch_size", "deadline", "priority",
                  "cancelled", "degraded",
                  # flight-recorder dimensions (obs/flight.py wide events)
@@ -296,6 +296,8 @@ class Request:
         self.plan = None
         self.queue_wait_s: Optional[float] = None
         self.scan_s: Optional[float] = None
+        # spans the completer timed inside a single's ``scan``
+        self.staged = None
         self.batched = False
         self.batch_size = 1
         self.deadline = deadline
@@ -678,9 +680,11 @@ class QueryScheduler:
                 rec("plan", "plan", (t1 - t0) / 1e9, t1, parent=host)
             if req.scan_s is not None:
                 resolved = launch + int(req.scan_s * 1e9)
-                rec("scan", "scan", req.scan_s, resolved,
-                    None if req.batch_id is None
-                    else {"batch_id": req.batch_id})
+                scan = rec("scan", "scan", req.scan_s, resolved,
+                           None if req.batch_id is None
+                           else {"batch_id": req.batch_id})
+                for node in req.staged or ():
+                    scan.add_child(node)
                 # resolved → this thread runs again: with many callers
                 # woken at once, their turn at the interpreter lock
                 now = _pcn()
@@ -1214,13 +1218,16 @@ class QueryScheduler:
         slices, empty plans). Runs planner._count with the cached plan — the
         plan/auths work is still amortized even off the fused path. The
         request's deadline rides along as the ambient deadline, so the
-        planner's range-decompose/refine checkpoints fire for it too."""
+        planner's range-decompose/refine checkpoints fire for it too. What
+        the planner times here (``range_decompose``, ``refine.device``,
+        ``refine``, the device leaves) is kept on the request and hangs
+        under its ``scan`` leaf in the caller's trace."""
         if r.deadline is not None and r.deadline.expired:
             self._cancel(r, "single")
             return
         try:
             _faults.serve_gate("sched.single")
-            with _rdl.use(r.deadline):
+            with _rdl.use(r.deadline), _trace.detached() as staged:
                 if r.plan.empty:
                     n = 0
                 else:  # _count handles empty covers, unions, fids, residuals
@@ -1228,6 +1235,8 @@ class QueryScheduler:
                 if r.delta is not None:
                     n += len(self.binding.delta_rows(r.delta, r.f_ir,
                                                      r.auths))
+            if staged is not None:
+                r.staged = staged.children
         except DeadlineExceeded as e:
             r.cancelled = True
             _metrics.inc("scheduler.deadline_cancelled")

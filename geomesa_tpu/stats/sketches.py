@@ -618,10 +618,16 @@ class Z3HistogramStat(Stat):
         b = np.asarray(bins, dtype=np.int64)
         o = np.clip((np.asarray(offs, np.float64) / max_off * self.BUCKETS).astype(np.int64),
                     0, self.BUCKETS - 1)
-        for ub in np.unique(b):
+        # one pass for every bin together: a table spread over years of
+        # weekly bins would otherwise be masked once a bin
+        ubs, which = np.unique(b, return_inverse=True)
+        counts = np.bincount(which * self.BUCKETS + o,
+                             minlength=len(ubs) * self.BUCKETS
+                             ).reshape(len(ubs), self.BUCKETS)
+        for ub, row in zip(ubs.tolist(), counts):
             if ub not in self.bins:
-                self.bins[int(ub)] = np.zeros(self.BUCKETS, dtype=np.int64)
-            self.bins[int(ub)] += np.bincount(o[b == ub], minlength=self.BUCKETS)
+                self.bins[ub] = np.zeros(self.BUCKETS, dtype=np.int64)
+            self.bins[ub] += row
 
     def mass_in_windows(self, windows: Sequence[Tuple[int, int, int, int]],
                         max_off: int) -> float:

@@ -528,6 +528,12 @@ class span:
         self._t0 = _pcn()
         return self
 
+    def set(self, **attrs) -> None:
+        """Attributes known only once the stage has run."""
+        node = self._node if self._t0 is not None else None
+        if node is not None:
+            node.attrs = {**(node.attrs or {}), **attrs}
+
     def __exit__(self, *exc):
         t0 = self._t0
         if t0 is None:
@@ -548,6 +554,31 @@ class span:
 
 def enabled() -> bool:
     return _state.enabled
+
+
+class detached:
+    """Stages a worker thread times on behalf of a request whose trace is
+    open on its caller's thread: inside, ``span`` / ``record`` /
+    ``device_fetch`` hang under a root of this thread's own that lands
+    nowhere (no ring, no close hook, no registry feed). Yields that root, or
+    None with tracing off; the owner moves its ``children`` under a span of
+    the caller's trace, whose close feeds them to the registry once."""
+
+    __slots__ = ("_on",)
+
+    def __enter__(self) -> Optional[Span]:
+        self._on = _state.enabled and _local.trace is None
+        if not self._on:
+            return None
+        t = _local.trace = QueryTrace("detached", None)
+        _local.stack = [t.root]
+        return t.root
+
+    def __exit__(self, *exc):
+        if self._on:
+            _local.trace = None
+            _local.stack = None
+        return False
 
 
 def _leaf(name: str, kind: str, duration_ms: float,

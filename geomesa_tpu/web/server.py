@@ -556,6 +556,10 @@ class GeoJsonApi:
                     hints["crs"] = query["crs"][0]
                 res = self.store.query(t, cql, hints=hints or None,
                                        auths=auths)
+                # what the response will carry: its size is the route's
+                # cost (one GeoJSON feature a row, built in Python)
+                from geomesa_tpu.metrics import REGISTRY
+                REGISTRY.inc("http.features.rows", len(res.table))
                 if "select" in query:
                     # geometry-catalog projections: st_* terms evaluate
                     # through the vmapped kernels (GEOM_KERNELS knob),
