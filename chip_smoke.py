@@ -363,7 +363,8 @@ def _requests(smoke: Smoke, ds, corpus: dict, ref: Reference,
     breaker = h["overload"].get("breaker", {}).get("state")
     smoke.check(degraded == 0, f"scheduler.degraded == 0 (is {degraded})")
     smoke.check(breaker == "closed", f"breaker closed (is {breaker})")
-    fq = {k: v - h0["fused_query"][k] for k, v in h["fused_query"].items()}
+    fq = {k: v - h0["fused_query"].get(k, 0)
+          for k, v in h["fused_query"].items()}
     smoke.check(fq["fallbacks"] == 0 and fq["queries"] > 0,
                 f"fused programs served the point shapes: {json.dumps(fq)}")
     compiles = {k[len("kernel."):-len(".compile")]: round(v["total_s"], 2)

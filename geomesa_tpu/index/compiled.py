@@ -20,10 +20,19 @@ Three layers:
 2. **In-kernel cover selection**: per-block f32 coordinate (and time-bin)
    summaries live on device; the program gates blocks against the query's
    f32 envelope (slack-expanded superset — the exact fp62 mask re-applies to
-   every gathered row), gathers up to CAP candidate blocks, and falls back to
-   the full-table mask *inside the same program* (``lax.cond``) when the
-   candidate set overflows. The program is total: no host-visible overflow
-   round trip for counts.
+   every gathered row) and counts the blocks that stay alive. ONE
+   ``lax.switch`` (``_over_alive``, shared by every mode and by the union
+   program) then picks, on the device, the first rung of a ladder of block
+   capacities (``_ladder``: powers of two from ``_RUNG_FLOOR`` up to CAP,
+   CAP = the table's blocks × ``PRUNE_MAX_FRACTION``) that holds the alive
+   blocks, and gathers, masks and compacts that many blocks: the work
+   follows the alive blocks, at most twice them, not CAP. Past CAP the last
+   branch masks the full table *inside the same program*. The program is
+   total (no host-visible overflow round trip for counts), still one
+   dispatch and, the ladder being a function of CAP alone, still one compile
+   a shape. ``n_alive`` comes back beside the result: ``_Program.fetch``
+   adds it to the counter ``fused.blocks_alive``, the serving branch's
+   blocks to ``fused.blocks_gathered`` and the rung to ``STATS``.
 
 3. **Shape-keyed caching + recipe fast path**: programs key by the same
    normalized structure signature discipline as the plan cache (geometry is
@@ -97,6 +106,8 @@ STATS: Dict[str, int] = {
     "shape_misses": 0,     # shapes seen before a recipe existed
     "bind_failures": 0,    # recipe present but the new values didn't bind
     "overflow_retries": 0, # select capacity regrows
+    # + "rung.<blocks>" / "rung.full": dispatches read back by the branch
+    # that served them (``_Program.fetch``), keyed as they first occur
 }
 
 REGISTRY.set_gauge("fused.programs", lambda: len(_PROGRAMS._jitted))
@@ -113,6 +124,13 @@ _GATE_SLACK = np.float32(1e-3)
 _SELECT_TIERS = (1 << 10, 1 << 13, 1 << 16, 1 << 19, 1 << 22)
 
 _UNC_CAP = 4096  # refine-mode uncertain-row capacity (host fallback past it)
+
+# the ladder of block capacities a program's pruned branches gather at
+# (``_ladder``): powers of two (step 1) from this floor up to the table's
+# ``cap``. Set from what the chip reads at 10M rows (PERF.md §6, PR 29: device
+# ms a select by rung, compile seconds by ladder length).
+_RUNG_FLOOR = 32
+_RUNG_STEP = 1
 
 # radial-distance certainty band (degrees) for the "dist" refine kind: must
 # exceed the f32 error of hypot over the f32 coordinate planes — coordinate
@@ -349,10 +367,10 @@ class _Program:
     """A compiled fused program bound to one query's packed constants."""
 
     __slots__ = ("fn", "cols", "summ", "packed", "mode", "sel_cap",
-                 "unc_cap", "n", "res_key", "key", "layout")
+                 "unc_cap", "n", "res_key", "key", "layout", "rungs", "nb")
 
     def __init__(self, fn, cols, summ, packed, mode, sel_cap, unc_cap, n,
-                 res_key, key, layout=None):
+                 res_key, key, rungs, nb, layout=None):
         self.fn = fn
         self.cols = cols
         self.summ = summ
@@ -363,12 +381,173 @@ class _Program:
         self.n = n
         self.res_key = res_key
         self.key = key
+        self.rungs = rungs     # _ladder(cap): the pruned branches' blocks
+        self.nb = nb           # the table's blocks: what ``full`` reads
         self.layout = layout   # set by _build; the template-rebind fast path
 
     def dispatch(self):
         """The single dispatch: packed constants ride into the jit call, the
-        returned device value syncs only when the caller reads it."""
+        returned device values, (result, n_alive), sync only when the caller
+        reads them."""
         return self.fn(self.cols, self.summ, self.packed)
+
+    def fetch(self):
+        """Dispatch, wait, and read the result back with ``n_alive`` beside
+        it (one read-back); the blocks the gate kept alive and the blocks the
+        branch that served them gathered go to the counters
+        ``fused.blocks_alive`` / ``fused.blocks_gathered`` and the rung to
+        ``STATS``. Returns the result on the host."""
+        import jax
+        out, n_alive = jax.device_get(_fetch(self.dispatch))
+        n_alive = int(n_alive)
+        which = sum(n_alive > r for r in self.rungs)   # the switch's index
+        gathered = (self.rungs + (self.nb,))[which]
+        rung = "rung.full" if which == len(self.rungs) else f"rung.{gathered}"
+        STATS[rung] = STATS.get(rung, 0) + 1
+        REGISTRY.inc("fused.blocks_alive", n_alive)
+        REGISTRY.inc("fused.blocks_gathered", gathered)
+        return out
+
+
+def _ladder(cap: int) -> Tuple[int, ...]:
+    """Block capacities of a program's pruned branches: every
+    ``_RUNG_STEP``-th power of two from ``_RUNG_FLOOR`` up to ``cap``, and
+    ``cap`` itself. A function of ``cap`` alone, which the program key
+    already holds: the ladder adds no compile."""
+    rungs = []
+    r = min(_RUNG_FLOOR, cap)
+    while r < cap:
+        rungs.append(r)
+        r <<= _RUNG_STEP
+    return tuple(rungs) + (cap,)
+
+
+def _gate_alive(summ, gate, windows):
+    """(nb,) bool: the blocks whose envelope (and week bins, where
+    ``windows`` is given) meet any of the query's gate envelopes."""
+    import jax.numpy as jnp
+
+    alive = jnp.any(
+        (summ["bxmax"][:, None] >= gate[None, :, 0])
+        & (summ["bxmin"][:, None] <= gate[None, :, 2])
+        & (summ["bymax"][:, None] >= gate[None, :, 1])
+        & (summ["bymin"][:, None] <= gate[None, :, 3]), axis=1)
+    if windows is not None:
+        blo, bhi = windows[:, 0], windows[:, 2]
+        alive = alive & jnp.any(
+            (blo <= bhi)[None, :]
+            & (summ["binmin"][:, None] <= bhi[None, :])
+            & (summ["binmax"][:, None] >= blo[None, :]), axis=1)
+    return alive
+
+
+def _first_set(mask, size: int):
+    """``jnp.nonzero(mask.reshape(-1), size=size, fill_value=mask.size)[0]``
+    for a (blocks, rows a block) mask: the same prefix sum and scatter-add,
+    laid out so that the TPU compiler takes seconds over a ladder of them.
+    The prefix sum runs along the rows of a block and then over the blocks'
+    totals, and the mask is materialised first: a scan over a rung's rows in
+    one line, or one whose every step recomputes the mask from the gathered
+    planes, cost the compiler 15-70 s a rung (PERF.md §6, PR 29)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    mask = lax.optimization_barrier(mask)
+    within = jnp.cumsum(mask.astype(jnp.int32), axis=1)
+    totals = within[:, -1]
+    upto = (within + (jnp.cumsum(totals) - totals)[:, None]).reshape(-1)
+    # the k-th set row's position is the number of rows with under k set
+    # rows up to and including them
+    first = jnp.cumsum(jnp.bincount(upto, length=size))
+    return jnp.where(jnp.arange(size) < upto[-1], first, mask.size)
+
+
+def _over_alive(alive, cols, n: int, bsz: int, cap: int, mask_of, tail):
+    """The program's one conditional: ``tail`` over the alive blocks,
+    gathered at the first rung of ``_ladder(cap)`` that holds them, or over
+    the whole table when more than ``cap`` are alive. The rung is chosen on
+    the device from ``n_alive`` (integer compares, no host round trip), so
+    the gather and the compaction run over at most ``_RUNG_STEP`` doublings
+    of the alive blocks, never over ``cap`` for a box that keeps 80 alive.
+
+    ``tail(c, m, compact)`` is the mode's aggregate over a column view ``c``
+    and its exact mask ``m``; ``compact(mask, size)`` gives the TABLE rows
+    of the mask's first ``size`` set rows, padded with ``n``. Returns
+    (the tail's result, n_alive)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    n_alive = jnp.sum(alive).astype(jnp.int32)
+    alive_ids = jnp.nonzero(alive, size=cap, fill_value=-1)[0].astype(
+        jnp.int32)
+
+    def pruned(rung: int):
+        total = rung * bsz
+
+        def branch(_):
+            # scan.py expand_blocks discipline: clamped starts re-read a
+            # suffix of the previous block; the membership test masks the
+            # re-reads and -1 pads without double counts
+            bids = alive_ids[:rung]
+            starts = bids * bsz
+            astart = jnp.clip(starts, 0, max(0, n - bsz))
+            rows = astart[:, None] + jnp.arange(bsz, dtype=jnp.int32)[None, :]
+            membership = ((bids >= 0)[:, None]
+                          & (rows >= starts[:, None])
+                          & (rows < starts[:, None] + bsz)).reshape(-1)
+            rowids = rows.reshape(-1)
+            g = _LazyBlockGather(cols, astart, bsz, total)
+
+            def compact(mask, size):
+                s = _first_set(mask.reshape(rung, bsz), size)
+                return jnp.where(
+                    s < total, rowids[jnp.clip(s, 0, total - 1)],
+                    n).astype(jnp.int32)
+
+            return tail(g, mask_of(g, membership), compact)
+
+        return branch
+
+    def full(_):
+        def compact(mask, size):
+            return jnp.nonzero(mask, size=size, fill_value=n)[0].astype(
+                jnp.int32)
+
+        return tail(cols, mask_of(cols), compact)
+
+    rungs = _ladder(cap)
+    which = sum((n_alive > r).astype(jnp.int32) for r in rungs)
+    return lax.switch(which, [pruned(r) for r in rungs] + [full], 0), n_alive
+
+
+def _mode_tail(mode: str, sel_cap: int, unc_cap: int = 0, refine_of=None,
+               grid=None, width: int = 0, height: int = 0):
+    """What a mode makes of a column view and its mask (``_over_alive``'s
+    ``tail``): the same for a gathered rung and for the whole table."""
+    import jax.numpy as jnp
+
+    def count(m):
+        return jnp.sum(m).astype(jnp.int32)
+
+    if mode == "count":
+        return lambda c, m, compact: count(m)
+    if mode == "select":
+        return lambda c, m, compact: jnp.concatenate(
+            [count(m)[None], compact(m, sel_cap)])
+    if mode in ("count_refine", "select_refine"):
+        def refined(c, m, compact):
+            hit, unc = refine_of(c, m)
+            parts = [count(hit)[None], count(unc)[None]]
+            if mode == "select_refine":
+                parts.append(compact(hit, sel_cap))
+            parts.append(compact(unc, unc_cap))
+            return jnp.concatenate(parts)
+        return refined
+    if mode == "density":
+        return lambda c, m, compact: (
+            _grid_scatter(c["xf"], c["yf"], m, None, grid, width, height),
+            count(m))
+    raise ValueError(mode)
 
 
 def _jit_program(mode: str, slots: tuple, six: Dict[str, int], emit,
@@ -379,29 +558,16 @@ def _jit_program(mode: str, slots: tuple, six: Dict[str, int], emit,
     arrive through the packed vector at dispatch time."""
     import jax
     import jax.numpy as jnp
-    from jax import lax
 
     get = _make_get(slots)
-    total = cap * bsz
 
     def run(cols, summ, packed):
         boxes = get(packed, six["boxes"])
-        gate = get(packed, six["gate"])
         windows = get(packed, six["windows"]) if T else None
 
         # -- in-kernel cover: which blocks can possibly match -------------
-        alive = jnp.any(
-            (summ["bxmax"][:, None] >= gate[None, :, 0])
-            & (summ["bxmin"][:, None] <= gate[None, :, 2])
-            & (summ["bymax"][:, None] >= gate[None, :, 1])
-            & (summ["bymin"][:, None] <= gate[None, :, 3]), axis=1)
-        if T and has_bin:
-            blo, bhi = windows[:, 0], windows[:, 2]
-            alive = alive & jnp.any(
-                (blo <= bhi)[None, :]
-                & (summ["binmin"][:, None] <= bhi[None, :])
-                & (summ["binmax"][:, None] >= blo[None, :]), axis=1)
-        n_alive = jnp.sum(alive)
+        alive = _gate_alive(summ, get(packed, six["gate"]),
+                            windows if has_bin else None)
 
         def mask_of(c, membership=None):
             m = PRIMARY_FNS["point_boxes"](c, boxes)
@@ -419,22 +585,6 @@ def _jit_program(mode: str, slots: tuple, six: Dict[str, int], emit,
                 m = m & membership
             return m
 
-        def gathered():
-            # scan.py expand_blocks discipline: clamped starts re-read a
-            # suffix of the previous block; the membership test masks the
-            # re-reads and -1 pads without double counts
-            bids = jnp.nonzero(
-                alive, size=cap, fill_value=-1)[0].astype(jnp.int32)
-            bids = jnp.where(bids < nb_blocks, bids, -1)
-            starts = bids * bsz
-            astart = jnp.clip(starts, 0, max(0, n - bsz))
-            rows = astart[:, None] + jnp.arange(bsz, dtype=jnp.int32)[None, :]
-            membership = ((bids >= 0)[:, None]
-                          & (rows >= starts[:, None])
-                          & (rows < starts[:, None] + bsz)).reshape(-1)
-            g = _LazyBlockGather(cols, astart, bsz, total)
-            return mask_of(g, membership), rows.reshape(-1), g
-
         def refine_of(c, m):
             if refine == "dist":
                 dz = get(packed, six["dist"])
@@ -449,87 +599,10 @@ def _jit_program(mode: str, slots: tuple, six: Dict[str, int], emit,
                     edges[None, :, 2], edges[None, :, 3])
             return m & cin, m & ~cin & ~cout
 
-        if mode == "count":
-            def pruned(_):
-                m, _, _ = gathered()
-                return jnp.sum(m).astype(jnp.int32)
+        grid = get(packed, six["grid"]) if mode == "density" else None
+        return _over_alive(alive, cols, n, bsz, cap, mask_of, _mode_tail(
+            mode, sel_cap, unc_cap, refine_of, grid, width, height))
 
-            def full(_):
-                return jnp.sum(mask_of(cols)).astype(jnp.int32)
-
-            return lax.cond(n_alive <= cap, pruned, full, 0)
-
-        if mode == "select":
-            def pruned(_):
-                m, rowids, _ = gathered()
-                sel = jnp.nonzero(m, size=sel_cap, fill_value=total)[0]
-                rows = jnp.where(
-                    sel < total, rowids[jnp.clip(sel, 0, total - 1)], n)
-                return jnp.concatenate([
-                    jnp.sum(m)[None].astype(jnp.int32),
-                    rows.astype(jnp.int32)])
-
-            def full(_):
-                m = mask_of(cols)
-                sel = jnp.nonzero(m, size=sel_cap, fill_value=n)[0]
-                return jnp.concatenate([
-                    jnp.sum(m)[None].astype(jnp.int32),
-                    sel.astype(jnp.int32)])
-
-            return lax.cond(n_alive <= cap, pruned, full, 0)
-
-        if mode in ("count_refine", "select_refine"):
-            def pruned(_):
-                m, rowids, g = gathered()
-                hit, unc = refine_of(g, m)
-                parts = [jnp.sum(hit)[None].astype(jnp.int32),
-                         jnp.sum(unc)[None].astype(jnp.int32)]
-                if mode == "select_refine":
-                    s = jnp.nonzero(hit, size=sel_cap, fill_value=total)[0]
-                    parts.append(jnp.where(
-                        s < total, rowids[jnp.clip(s, 0, total - 1)],
-                        n).astype(jnp.int32))
-                u = jnp.nonzero(unc, size=unc_cap, fill_value=total)[0]
-                parts.append(jnp.where(
-                    u < total, rowids[jnp.clip(u, 0, total - 1)],
-                    n).astype(jnp.int32))
-                return jnp.concatenate(parts)
-
-            def full(_):
-                m = mask_of(cols)
-                hit, unc = refine_of(cols, m)
-                parts = [jnp.sum(hit)[None].astype(jnp.int32),
-                         jnp.sum(unc)[None].astype(jnp.int32)]
-                if mode == "select_refine":
-                    parts.append(jnp.nonzero(
-                        hit, size=sel_cap,
-                        fill_value=n)[0].astype(jnp.int32))
-                parts.append(jnp.nonzero(
-                    unc, size=unc_cap, fill_value=n)[0].astype(jnp.int32))
-                return jnp.concatenate(parts)
-
-            return lax.cond(n_alive <= cap, pruned, full, 0)
-
-        if mode == "density":
-            grid = get(packed, six["grid"])
-
-            def pruned(_):
-                m, _, g = gathered()
-                return (_grid_scatter(g["xf"], g["yf"], m, None, grid,
-                                      width, height),
-                        jnp.sum(m).astype(jnp.int32))
-
-            def full(_):
-                m = mask_of(cols)
-                return (_grid_scatter(cols["xf"], cols["yf"], m, None, grid,
-                                      width, height),
-                        jnp.sum(m).astype(jnp.int32))
-
-            return lax.cond(n_alive <= cap, pruned, full, 0)
-
-        raise ValueError(mode)
-
-    nb_blocks = -(-n // bsz)
     STATS["programs_built"] += 1
     kid = f"fused_{mode}.point_boxes"
     run.__name__ = program_name(kid)
@@ -626,7 +699,7 @@ def _build(index, sft, vocabs, mode: str, boxes: np.ndarray,
         has_bin, width, height, refine))
     summ = _block_summaries(index, bsz)
     return _Program(fn, cols, summ, layout.pack(values), mode, sel_cap,
-                    unc_cap, n, res_key, key, layout)
+                    unc_cap, n, res_key, key, _ladder(cap), nb, layout)
 
 
 # -- plan qualification -------------------------------------------------------
@@ -763,7 +836,7 @@ def try_count(planner, plan) -> Optional[int]:
     STATS["queries"] += 1
     REGISTRY.inc("fused.queries")
     with _attrib.kernel("fused_count.point_boxes"):
-        return int(_fetch(prog.dispatch))
+        return int(prog.fetch())
 
 
 def try_select(planner, plan, capacity: Optional[int]) \
@@ -782,7 +855,7 @@ def try_select(planner, plan, capacity: Optional[int]) \
         STATS["queries"] += 1
         REGISTRY.inc("fused.queries")
         with _attrib.kernel("fused_select.point_boxes", prog.sel_cap):
-            out = np.asarray(_fetch(prog.dispatch))
+            out = prog.fetch()
         cnt = int(out[0])
         if cnt <= prog.sel_cap:
             return out[1: 1 + cnt].astype(np.int64)
@@ -803,7 +876,7 @@ def try_count_refine(planner, plan) -> Optional[int]:
     STATS["queries"] += 1
     REGISTRY.inc("fused.queries")
     with _attrib.kernel("fused_count_refine.point_boxes"):
-        out = np.asarray(_fetch(prog.dispatch))
+        out = prog.fetch()
     certain, n_unc = int(out[0]), int(out[1])
     if n_unc > prog.unc_cap:
         return None  # uncertainty overflow: staged/host refine instead
@@ -836,7 +909,7 @@ def try_select_refine(planner, plan, capacity: Optional[int]) \
         STATS["queries"] += 1
         REGISTRY.inc("fused.queries")
         with _attrib.kernel("fused_select_refine.point_boxes", prog.sel_cap):
-            out = np.asarray(_fetch(prog.dispatch))
+            out = prog.fetch()
         n_in, n_unc = int(out[0]), int(out[1])
         if n_unc > prog.unc_cap:
             return None
@@ -873,8 +946,8 @@ def try_density(planner, plan, grid_bbox, width: int, height: int):
     STATS["queries"] += 1
     REGISTRY.inc("fused.queries")
     with _attrib.kernel("fused_density.point_boxes"):
-        grid, cnt = _fetch(prog.dispatch)
-    return np.asarray(grid), int(cnt)
+        grid, cnt = prog.fetch()
+    return grid, int(cnt)
 
 
 # -- union (Or-of-covers) lowering --------------------------------------------
@@ -890,31 +963,19 @@ def _jit_union_program(mode: str, slots: tuple, branches: tuple,
     (slot-index dict, residual emit | None, window count) from
     ``_build_union``; the block gate keeps a block alive when ANY branch's
     envelope set touches it."""
+    import functools
+
     import jax
     import jax.numpy as jnp
-    from jax import lax
 
     get = _make_get(slots)
-    total = cap * bsz
 
     def run(cols, summ, packed):
-        alive = jnp.zeros(summ["bxmin"].shape[0], dtype=bool)
-        for six, _, T in branches:
-            gate = get(packed, six["gate"])
-            a = jnp.any(
-                (summ["bxmax"][:, None] >= gate[None, :, 0])
-                & (summ["bxmin"][:, None] <= gate[None, :, 2])
-                & (summ["bymax"][:, None] >= gate[None, :, 1])
-                & (summ["bymin"][:, None] <= gate[None, :, 3]), axis=1)
-            if T and has_bin:
-                windows = get(packed, six["windows"])
-                blo, bhi = windows[:, 0], windows[:, 2]
-                a = a & jnp.any(
-                    (blo <= bhi)[None, :]
-                    & (summ["binmin"][:, None] <= bhi[None, :])
-                    & (summ["binmax"][:, None] >= blo[None, :]), axis=1)
-            alive = alive | a
-        n_alive = jnp.sum(alive)
+        alive = functools.reduce(jnp.logical_or, [
+            _gate_alive(summ, get(packed, six["gate"]),
+                        get(packed, six["windows"]) if T and has_bin
+                        else None)
+            for six, _, T in branches])
 
         def mask_of(c, membership=None):
             m = None
@@ -935,58 +996,10 @@ def _jit_union_program(mode: str, slots: tuple, branches: tuple,
                 m = m & membership
             return m
 
-        def gathered():
-            bids = jnp.nonzero(
-                alive, size=cap, fill_value=-1)[0].astype(jnp.int32)
-            bids = jnp.where(bids < nb_blocks, bids, -1)
-            starts = bids * bsz
-            astart = jnp.clip(starts, 0, max(0, n - bsz))
-            rows = astart[:, None] + jnp.arange(bsz, dtype=jnp.int32)[None, :]
-            membership = ((bids >= 0)[:, None]
-                          & (rows >= starts[:, None])
-                          & (rows < starts[:, None] + bsz)).reshape(-1)
-            g = _LazyBlockGather(cols, astart, bsz, total)
-            return mask_of(g, membership), rows.reshape(-1), g
+        grid = get(packed, six_g["grid"]) if mode == "density" else None
+        return _over_alive(alive, cols, n, bsz, cap, mask_of, _mode_tail(
+            mode, sel_cap, grid=grid, width=width, height=height))
 
-        if mode == "select":
-            def pruned(_):
-                m, rowids, _ = gathered()
-                sel = jnp.nonzero(m, size=sel_cap, fill_value=total)[0]
-                rows = jnp.where(
-                    sel < total, rowids[jnp.clip(sel, 0, total - 1)], n)
-                return jnp.concatenate([
-                    jnp.sum(m)[None].astype(jnp.int32),
-                    rows.astype(jnp.int32)])
-
-            def full(_):
-                m = mask_of(cols)
-                sel = jnp.nonzero(m, size=sel_cap, fill_value=n)[0]
-                return jnp.concatenate([
-                    jnp.sum(m)[None].astype(jnp.int32),
-                    sel.astype(jnp.int32)])
-
-            return lax.cond(n_alive <= cap, pruned, full, 0)
-
-        if mode == "density":
-            grid = get(packed, six_g["grid"])
-
-            def pruned(_):
-                m, _, g = gathered()
-                return (_grid_scatter(g["xf"], g["yf"], m, None, grid,
-                                      width, height),
-                        jnp.sum(m).astype(jnp.int32))
-
-            def full(_):
-                m = mask_of(cols)
-                return (_grid_scatter(cols["xf"], cols["yf"], m, None, grid,
-                                      width, height),
-                        jnp.sum(m).astype(jnp.int32))
-
-            return lax.cond(n_alive <= cap, pruned, full, 0)
-
-        raise ValueError(mode)
-
-    nb_blocks = -(-n // bsz)
     STATS["programs_built"] += 1
     kid = f"fused_union_{mode}"
     run.__name__ = program_name(kid)
@@ -1082,7 +1095,7 @@ def _build_union(planner, plan, mode: str, auths,
         width, height))
     summ = _block_summaries(idx, bsz)
     return _Program(fn, cols, summ, layout.pack(values), mode, sel_cap,
-                    0, n, "|".join(res_keys), key, layout)
+                    0, n, "|".join(res_keys), key, _ladder(cap), nb, layout)
 
 
 def try_union_select(planner, plan, auths,
@@ -1101,7 +1114,7 @@ def try_union_select(planner, plan, auths,
         STATS["queries"] += 1
         REGISTRY.inc("fused.queries")
         with _attrib.kernel("fused_union_select", prog.sel_cap):
-            out = np.asarray(_fetch(prog.dispatch))
+            out = prog.fetch()
         cnt = int(out[0])
         if cnt <= prog.sel_cap:
             pos = out[1: 1 + cnt].astype(np.int64)
@@ -1124,8 +1137,8 @@ def try_union_density(planner, plan, auths, grid_bbox, width: int,
     STATS["queries"] += 1
     REGISTRY.inc("fused.queries")
     with _attrib.kernel("fused_union_density"):
-        grid, cnt = _fetch(prog.dispatch)
-    return np.asarray(grid), int(cnt)
+        grid, cnt = prog.fetch()
+    return grid, int(cnt)
 
 
 # -- shape-keyed recipe fast path (skip planning entirely) --------------------
@@ -1341,7 +1354,7 @@ def _rebind(recipe, boxes, gate, windows, dev_ir) -> Optional[_Program]:
             packed[off:off + size] = a
     return _Program(prog.fn, cols, prog.summ, packed, prog.mode,
                     prog.sel_cap, prog.unc_cap, prog.n, prog.res_key,
-                    prog.key)
+                    prog.key, prog.rungs, prog.nb)
 
 
 class Recipe:
@@ -1436,7 +1449,7 @@ class FusedPrepared:
         if self._prog is None:
             return None
         with _trace.span("device_scan", kind="device_scan"):
-            return self._prog.dispatch()
+            return self._prog.dispatch()[0]
 
     def count(self) -> int:
         from geomesa_tpu.index.guards import Deadline
@@ -1446,7 +1459,7 @@ class FusedPrepared:
         with _trace.trace("count", **attrs):    # pay it when traces record
             dl = Deadline(self.planner.timeout_ms)
             t0 = time.perf_counter()
-            n = 0 if self._prog is None else int(_fetch(self._prog.dispatch))
+            n = 0 if self._prog is None else int(self._prog.fetch())
             dl.check("scan")
             self.planner._write_audit(self.plan, self.filter, 0.0,
                                       (time.perf_counter() - t0) * 1000, n)
@@ -1560,7 +1573,7 @@ def warm_programs(index) -> int:
                       None, 0, 0, None)
         if prog is None:
             continue
-        _fetch(prog.dispatch)   # empty gate: executes, compiles both branches
+        _fetch(prog.dispatch)   # empty gate: executes, compiles every branch
         warmed += 1
     return warmed
 

@@ -660,7 +660,7 @@ class PreparedQuery:
                 # single-dispatch fused program: cover + scan + residual +
                 # count in one device round; constants ride with the call
                 self._fused = prog
-                self._count_disp = prog.dispatch
+                self._count_disp = lambda: prog.dispatch()[0]
                 return
             blocks = planner._pruned_blocks(plan)
             if blocks is not None and len(blocks) > 0:
@@ -701,6 +701,8 @@ class PreparedQuery:
             t0 = time.perf_counter()
             if self.plan.empty:
                 n = 0
+            elif self._fused is not None:
+                n = int(self._fused.fetch())   # tallies its blocks
             elif self._count_disp is not None:
                 n = int(_fetch(self._count_disp))
             else:

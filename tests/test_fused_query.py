@@ -332,3 +332,119 @@ def test_density_mode_matches_host_histogram(world):
     assert cnt == int(host.sum())
     assert grid.shape == (16, 32)
     assert int(grid.sum()) == cnt   # every match lands in exactly one cell
+
+
+# -- the ladder of block capacities -------------------------------------------
+
+_LADDER_BLOCK = 64
+_LADDER_WEEK = "dtg DURING 2020-01-09T00:00:00Z/2020-01-15T00:00:00Z"
+# boundary → (fewest and most blocks the gate may keep alive, blocks the
+# serving branch gathers); the table has 1,024 blocks of 64 rows, cap 256,
+# rungs 32 / 64 / 128 / 256
+_LADDER_CASES = {"under_floor": (1, 31, 32), "on_rung": (64, 64, 64),
+                 "over_rung": (65, 65, 128), "at_cap": (256, 256, 256),
+                 "over_cap": (257, 1024, 1024)}
+_LADDER_MODES = ("count", "select", "count_refine", "select_refine",
+                 "density", "union_select")
+
+
+@pytest.fixture(scope="module")
+def ladder_world():
+    """65,536 points of ONE week (one time bin, so the gate is the boxes'),
+    and for every boundary a box whose gate keeps that many blocks alive:
+    found by a seeded search over the block envelopes in numpy, not
+    by the program under test."""
+    _unshadow_block_size()
+    config.PRUNE_BLOCK.set(_LADDER_BLOCK)
+    try:
+        n = 1024 * _LADDER_BLOCK
+        rng = np.random.default_rng(11)
+        base = np.datetime64("2020-01-09T00:00:00", "ms").astype(np.int64)
+        sft = SimpleFeatureType.from_spec(
+            "fl", "age:Int,dtg:Date,*geom:Point;geomesa.z3.interval=week")
+        table = FeatureTable.build(sft, {
+            "age": rng.integers(0, 100, n).astype(np.int32),
+            "dtg": base + rng.integers(0, 6 * 86400000, n),
+            "geom": (rng.uniform(-170, 170, n), rng.uniform(-80, 80, n))})
+        planner = QueryPlanner(sft, table, [Z3Index(sft, table)])
+        summ = {k: np.asarray(v) for k, v in fused._block_summaries(
+            planner.indexes[0], _LADDER_BLOCK).items()}
+    finally:
+        config.PRUNE_BLOCK.unset()
+
+    def alive(boxes):
+        g = np.asarray(boxes, dtype=np.float32).reshape(-1, 4)
+        return ((summ["bxmax"][None, :] >= g[:, 0:1])
+                & (summ["bxmin"][None, :] <= g[:, 2:3])
+                & (summ["bymax"][None, :] >= g[:, 1:2])
+                & (summ["bymin"][None, :] <= g[:, 3:4]))
+
+    k = 40000
+    c = np.stack([rng.uniform(-150, 150, k), rng.uniform(-70, 70, k)], 1)
+    half = np.stack([rng.uniform(0.01, 80, k), rng.uniform(0.01, 50, k)],
+                    1) * rng.random((k, 2))
+    cand = np.round(np.concatenate([c - half, c + half], 1), 3)
+    counts = np.concatenate([alive(cand[i:i + 4000]).sum(1)
+                             for i in range(0, k, 4000)])
+    boxes = {name: tuple(cand[np.flatnonzero(
+        (counts >= lo) & (counts <= hi))[0]].tolist())
+        for name, (lo, hi, _) in _LADDER_CASES.items()}
+    return planner, table, alive, boxes
+
+
+@pytest.mark.parametrize("case", sorted(_LADDER_CASES))
+@pytest.mark.parametrize("mode", _LADDER_MODES)
+def test_ladder_rung_by_alive_blocks(ladder_world, mode, case):
+    """Every mode at every boundary of the ladder: the answer is the host
+    evaluator's, ``fused.blocks_alive`` grew by the gate's own count and
+    ``fused.blocks_gathered`` by the first rung that holds it (the table's
+    blocks past ``cap``)."""
+    from geomesa_tpu.metrics import REGISTRY
+    planner, table, alive, boxes = ladder_world
+    lo, hi, gathered = _LADDER_CASES[case]
+    x0, y0, x1, y1 = box = boxes[case]
+    if mode in ("count_refine", "select_refine"):
+        # a concave pentagon with the box's envelope: same gate, host refine
+        q = (f"INTERSECTS(geom, POLYGON(({x0} {y0}, {x1} {y0}, {x1} {y1}, "
+             f"{x0} {y1}, {(x0 + x1) / 2} {(y0 + y1) / 2}, {x0} {y0}))) "
+             f"AND {_LADDER_WEEK}")
+    elif mode == "union_select":
+        # the second branch lies inside the first: every block it keeps
+        # alive the first keeps too, and the OR dedups its rows
+        q = (f"BBOX(geom,{x0},{y0},{x1},{y1}) OR BBOX(geom,{x0},{y0},"
+             f"{(x0 + x1) / 2},{(y0 + y1) / 2})")
+    else:
+        q = f"BBOX(geom,{x0},{y0},{x1},{y1}) AND {_LADDER_WEEK} AND age > 20"
+    host = evaluate(parse_ecql(q), table)
+
+    def counters():
+        snap = REGISTRY.snapshot()["counters"]
+        return (snap.get("fused.blocks_alive", 0),
+                snap.get("fused.blocks_gathered", 0),
+                fused.STATS.get(f"rung.{gathered}", 0),
+                fused.STATS.get("rung.full", 0))
+
+    config.PRUNE_BLOCK.set(_LADDER_BLOCK)
+    try:
+        plan = planner.plan(parse_ecql(q))
+        gates = [bp.explain["boxes"] for _, bp in plan.branches] \
+            if mode == "union_select" else [plan.explain["boxes"]]
+        target = int(np.any([alive(g).any(0) for g in gates], 0).sum())
+        assert lo <= target <= hi
+        before = counters()
+        if mode in ("count", "count_refine"):
+            assert planner.count(q) == int(host.sum())
+        elif mode == "density":
+            grid, cnt = fused.try_density(planner, plan, box, 32, 16)
+            assert cnt == int(host.sum()) and int(grid.sum()) == cnt
+        else:
+            assert np.array_equal(planner.select_indices(q),
+                                  np.flatnonzero(host))
+        after = counters()
+    finally:
+        config.PRUNE_BLOCK.set(512)
+    assert host.sum() > 0
+    assert after[0] - before[0] == target
+    assert after[1] - before[1] == gathered
+    assert (after[3] - before[3], after[2] - before[2]) == (
+        (1, 0) if case == "over_cap" else (0, 1))
