@@ -7,7 +7,7 @@ One process (it spawns nothing, so it is the only holder of the chip) that
 
 1. refuses to run unless ``jax.default_backend() == "tpu"``;
 2. builds a GDELT-shaped point corpus from ``--seed`` (README quick-start
-   schema, clustered centres as ``bench.py``), 100,000,000 rows by default;
+   schema, 64 clustered centres), 100,000,000 rows by default;
 3. loads it through ``DataStoreFinder → create_schema → ds.load`` and serves
    it with ``web.serve(ds, background=True)`` — what ``geomesa-tpu serve`` runs;
 4. sends the REST requests a client would (count, 64 concurrent counts,
@@ -52,8 +52,8 @@ EARTH_R_M = 6371008.8
 
 
 def make_corpus(rows: int, seed: int) -> dict:
-    """Raw host columns: 64 clustered centres over 30 days (bench.py's
-    corpus) plus the two quick-start attributes. ``name`` is made in bulk as
+    """Raw host columns: 64 clustered centres over 30 days plus the two
+    quick-start attributes. ``name`` is made in bulk as
     dictionary codes + vocab, the form FeatureTable stores it in."""
     rng = np.random.default_rng(seed)
     centers = rng.uniform([-120, -40], [140, 60], size=(64, 2))

@@ -24,8 +24,7 @@ Modules:
             unsorted rows into per-process contiguous key ranges, so
             distributed index builds land sorted-by-construction.
   dryrun    spawned N-process CPU-backend dryrun + single-process
-            oracle comparison (the CI acceptance surface and bench
-            cfg12 engine).
+            oracle comparison (the CI acceptance surface).
   cells     shard cells (cluster v2): each Morton key-range shard as a
             replicated primary+follower group with its own fencing
             epoch — the ownership map the shard-aware router routes

@@ -1,7 +1,7 @@
 """N-process CPU-backend distributed dryrun + single-process oracle.
 
 The acceptance surface for the cluster runtime (and the engine behind
-bench cfg12 and the CI ``cluster`` job): spawn N real worker processes
+the CI ``cluster`` job): spawn N real worker processes
 (``JAX_PLATFORMS=cpu``, gloo collectives), have each
 
   1. deal itself a round-robin slice of a deterministic shared-seed
@@ -719,7 +719,7 @@ def _check(oracle: dict, ranks: List[Optional[dict]], n: int,
         checks["fleet_registered"] = all(_fleet_ok(r) for r in live)
     if drill:
         # every rank ran the drill and rank 0's ledger was active
-        # (scoring against the pinned bars lives in bench cfg13)
+        # (scoring against the pinned bars lives in tests/test_shardwatch.py)
         checks["drill_reported"] = all(
             (r.get("drill") or {}).get("mode") == drill for r in live)
         r0 = next((r for r in live if r["process_id"] == 0), None)

@@ -114,10 +114,6 @@ DENSITY_PACK = _register(
     "f32 grid). Unknown values fall back to auto. ≙ the reference's sparse "
     "kryo density grids (DensityScan.scala:95).")
 
-BENCH_N = _register(
-    "GEOMESA_TPU_BENCH_N", 100_000_000, int,
-    "bench.py corpus size.")
-
 SCHED_ENABLED = _register(
     "GEOMESA_TPU_SCHEDULER", True, _parse_bool,
     "Master switch for the micro-batching query scheduler on the serving "
@@ -401,7 +397,7 @@ SLO_AVAIL_TARGET = _register(
     "Target success-fraction for the default availability SLO (sheds, "
     "deadline cancellations and worker deaths spend its budget).")
 
-# -- device profiling + perf regression watch (obs/profiling, obs/perfwatch) --
+# -- device profiling (obs/profiling) -----------------------------------------
 
 PROFILING_ENABLED = _register(
     "GEOMESA_TPU_PROFILING", True, _parse_bool,
@@ -411,30 +407,13 @@ PROFILING_ENABLED = _register(
     "phase progress. All costs land at compile/build time — the "
     "steady-state dispatch path pays one wrapper call.")
 
-PERFWATCH_K = _register(
-    "GEOMESA_TPU_PERFWATCH_K", 4.0, float,
-    "Noise threshold for bench regression gating: a metric flags only "
-    "past baseline median + k*MAD (in its bad direction). CI perf-smoke "
-    "runs with the looser k=3 plus the relative floor.")
-
-PERFWATCH_MIN_REL = _register(
-    "GEOMESA_TPU_PERFWATCH_MIN_REL", 0.10, float,
-    "Relative noise floor for regression gating: deltas under this "
-    "fraction of the baseline median never flag, even when k*MAD is "
-    "smaller (few-sample baselines can have MAD ~0).")
-
-BENCH_MINI_N = _register(
-    "GEOMESA_TPU_BENCH_MINI_N", 200_000, int,
-    "Corpus size for bench.py --mini (the CI-runnable deterministic "
-    "mini-bench the perf-smoke regression gate measures).")
-
 # -- fleet-wide observability (obs/federation.py + trace propagation) ---------
 
 NODE_ID = _register(
     "GEOMESA_TPU_NODE_ID", "", str,
     "Stable node identity for fleet observability (the `node` label on "
     "federated metrics, the node dimension on traces/flight events, the "
-    "/healthz + BENCH_summary attribution). Empty = derived "
+    "/healthz attribution). Empty = derived "
     "hostname-pid-suffix, unique per process incarnation.")
 
 FED_PROPAGATE = _register(
@@ -715,9 +694,9 @@ SOAK_CATCHUP_BUDGET_S = _register(
 SOAK_STRETCH = _register(
     "GEOMESA_TPU_SOAK_STRETCH", 1.0, float,
     "Multiplier on the injected chaos magnitudes (lag-spike delay per "
-    "frame and frame count). The perfwatch gate self-test runs the "
-    "soak with a stretch > 1 and requires the cfg11 check to flag the "
-    "regressed catch-up/burn metrics — proving the fleet gate trips.")
+    "frame and frame count). A stretch > 1 makes the lag-spike "
+    "genuinely worse, so the scoreboard's catch-up and burn-rate axes "
+    "move with it.")
 
 
 # -- multi-process cluster runtime (ISSUE 15) ---------------------------------
@@ -1017,8 +996,8 @@ JOURNAL_KEEP = _register(
 
 def enable_compile_cache() -> str:
     """Give JAX's persistent compilation cache a stable home and return the
-    directory in force. Entry points (``chip_smoke.py``, ``bench.py``, the
-    CLI) call this before any backend initialises.
+    directory in force. Entry points (``chip_smoke.py``, ``benchmark/run.py``,
+    the CLI) call this before any backend initialises.
 
     ``JAX_COMPILATION_CACHE_DIR`` wins: JAX reads the variable itself, so
     nothing is set here and an operator can place the cache from outside.

@@ -223,7 +223,7 @@ def join_battery(planner, polygons: Sequence,
                  runtime: Optional[ClusterRuntime] = None,
                  fids: Optional[np.ndarray] = None,
                  max_pairs: Optional[int] = None) -> dict:
-    """Both join ops over one polygon set — the dryrun/bench unit.
+    """Both join ops over one polygon set — the dryrun unit.
     ``stable`` is identical on every rank (the orchestrator asserts it
     against the single-process oracle verbatim); ``meta`` carries the
     rank-local timings/sizes, excluded from equality."""

@@ -2,8 +2,8 @@
 
 ≙ the reference's server-side push-down taken to its limit: instead of the
 host orchestrating plan → range-decompose → scan → refine as separate device
-rounds (each paying the dispatch floor ``bench.py`` tracks as
-``dispatch_floor_ms_per_query``), a qualifying plan shape compiles into a
+rounds (each paying one host↔device round trip, counted by
+``scan._RoundLedger``), a qualifying plan shape compiles into a
 single jitted program that does cover/block selection, the primary scan, the
 lowered residual predicate, and the aggregate in ONE dispatch with ONE
 host→device round trip.

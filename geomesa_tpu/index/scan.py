@@ -39,10 +39,9 @@ from geomesa_tpu.obs import profiling as _prof
 
 class _RoundLedger:
     """Process-wide host↔device round counter: every kernel dispatch and
-    every constant upload is one potential host↔device round trip (each pays
-    the ``dispatch_floor_ms_per_query`` the bench tracks). ``rounds_since`` a
-    snapshot is how the cfg14 bench and the fused-query tests pin
-    ``dispatches_per_cold_query`` — the fused path must read exactly 1."""
+    every constant upload is one potential host↔device round trip.
+    ``rounds_since`` a snapshot is how the fused-query tests pin the
+    dispatches a cold query makes — the fused path must read exactly 1."""
 
     __slots__ = ("dispatches", "uploads")
 

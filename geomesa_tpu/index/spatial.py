@@ -592,7 +592,7 @@ class BaseSpatialIndex:
             jax.block_until_ready(self._dev_perm)
         t2 = _time.perf_counter()
         # per-stage build timings (≙ the profile the reference exposes via
-        # MethodProfiling around its writers); bench surfaces these so a
+        # MethodProfiling around its writers); chip_smoke.py prints these so a
         # slow build is attributable: upload is host→device bandwidth, sort
         # is device + compile (persistent-cached after the first run)
         mb = sum(k.nbytes for k in keys) / 1e6 \

@@ -2,8 +2,7 @@
 with exemplars, per-kernel device cost attribution, SLO burn rates —
 plus the performance observatory (ISSUE 6): device-level kernel
 profiling with recompile detection and build-phase progress
-(obs/profiling.py) and the noise-aware bench regression gate
-(obs/perfwatch.py).
+(obs/profiling.py).
 
 Layered ON TOP of trace.py/metrics.py (which stay import-light and
 hook-based): ``install()`` wires

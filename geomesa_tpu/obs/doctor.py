@@ -16,8 +16,7 @@ configured, the fleet-merged state) on the same injectable clock as
                     stalled/dead-follower signal
   recompile_churn   ``kernels.recompiles`` advancing faster than the
                     per-minute bar inside the window; the suspect kernel
-                    is named from the recompile flight events, with the
-                    perfwatch baseline compile counts as context
+                    is named from the recompile flight events
   shed_storm        ``admission.shed`` rate over the bar; the dominant
                     shed priority class is the suspect
   breaker_flapping  open/close transition EDGES on one breaker inside
@@ -290,24 +289,11 @@ class DoctorEngine:
                 suspect = {"kernel": top[0], "recent_recompiles": top[1]}
         except Exception:
             pass
-        baseline = None
-        try:
-            import os
-            from geomesa_tpu.obs import perfwatch as _pw
-            path = os.path.join(os.path.dirname(os.path.dirname(
-                os.path.dirname(os.path.abspath(__file__)))),
-                "perf", "baselines.json")
-            kb = _pw.load_baselines(path).get("kernels") or {}
-            baseline = sum(int((m or {}).get("compiles", 0))
-                           for m in kb.values()) or None
-        except Exception:
-            pass
         return [{
             "rule": "recompile_churn", "severity": "ticket",
             "cause": "kernels:recompiles",
             "detail": {"rate_per_min": round(rate, 2), "delta": delta,
-                       "bar_per_min": bar, "total": int(v),
-                       "baseline_compiles": baseline},
+                       "bar_per_min": bar, "total": int(v)},
             "suspect": suspect,
             "match": {"kind": "kernel.recompile"},
         }]

@@ -45,8 +45,8 @@ path (everything lands at compile/build time):
       ``reindex`` flight events (build_started/aborted/installed/failed).
 
 A deterministic fault hook (``arm_kernel_handicap``) stretches matching
-kernels' device time by a factor — the regression gate's self-test
-(bench.py --check must flag an injected 2x slowdown and name the kernel).
+kernels' device time by a factor — the fault the doctor's soak
+(obs/soak.py) and the trend drill (obs/trenddrill.py) inject.
 """
 
 from __future__ import annotations
@@ -112,7 +112,7 @@ def note_signature(seen: Dict[str, set], kernel_id: str, key,
         pass  # observability must never fail the compile
 
 
-# -- deterministic kernel handicap (the regression gate's fault hook) ---------
+# -- deterministic kernel handicap (the soaks' fault hook) --------------------
 
 _handicap: Optional[tuple] = None  # (substring, factor)
 
@@ -120,8 +120,8 @@ _handicap: Optional[tuple] = None  # (substring, factor)
 def arm_kernel_handicap(match: str, factor: float) -> None:
     """Stretch every dispatch of kernels whose id contains ``match`` by
     ``factor`` (sleep (factor-1) x the measured call time after it). The
-    deterministic injection bench.py --check's self-test uses to prove an
-    in-kernel slowdown is flagged AND attributed to the right kernel.
+    deterministic injection obs/soak.py and obs/trenddrill.py use to prove
+    an in-kernel slowdown is flagged AND attributed to the right kernel.
     Applies to kernels compiled after arming."""
     global _handicap
     _handicap = (match, float(factor)) if factor and factor > 1.0 else None

@@ -66,7 +66,7 @@ from geomesa_tpu.obs.soakfleet import (_NoWorkload, _Traffic, _free_port,
 
 SCOREBOARD_DEFAULT = "SOAKCELLS_scoreboard.json"
 
-# most recent scoreboard (GET /cluster/soak and bench cfg16 read this)
+# most recent scoreboard (GET /cluster/soak reads this)
 LAST: Optional[dict] = None
 
 
@@ -818,9 +818,8 @@ def run_cell_soak(base_dir: Optional[str] = None, faulted: bool = True,
 
 
 def scoreboard_metrics(board: dict) -> dict:
-    """Flatten the scoreboard into the cfg16 gate metrics folded into
-    perf/baselines.json (exact-match axes pinned in
-    perfwatch._OVERRIDES, statistical axes direction-checked)."""
+    """Flatten the scoreboard into its numeric cfg16 metrics (the
+    ``metrics`` of the scoreboard ``GET /cluster/soak`` serves)."""
     m: Dict[str, float] = {}
     ch = (board.get("halves") or {}).get("chaos")
     cl = (board.get("halves") or {}).get("clean")
@@ -929,7 +928,7 @@ def run(mini: bool = True, scoreboard_path: Optional[str] = None,
         base_dir: Optional[str] = None,
         halves: tuple = ("chaos", "clean")) -> dict:
     """Run the full soak (chaos + clean halves), write the scoreboard
-    JSON + markdown, and remember it for bench cfg16."""
+    JSON + markdown, and remember it for ``GET /cluster/soak``."""
     global LAST
     scoreboard_path = scoreboard_path or os.environ.get(
         "GEOMESA_TPU_SOAKCELLS_SCOREBOARD", SCOREBOARD_DEFAULT)

@@ -27,20 +27,20 @@ The run is scored into a scoreboard (JSON + rendered markdown):
     byte-identical durability-dir fingerprints across the surviving
     fleet at exit.
 
-The scoreboard's numeric metrics surface as bench cfg11 and fold into
-perf/baselines.json, so an SLO/recovery regression gates a PR exactly
-like a kernel perf regression.  ``faulted=False`` replays the same
-traffic with paced writes and no chaos: zero incidents allowed.
+The scoreboard's numeric metrics (``scoreboard_metrics``, the cfg11
+names) are part of the scoreboard ``GET /fleet/soak`` serves.
+``faulted=False`` replays the same traffic with paced writes and no
+chaos: zero incidents allowed.
 
 Knobs (``GEOMESA_TPU_SOAK_*``): SOAK_PHASE_S (per-phase drive window),
 SOAK_WAIT_S (incident/catch-up wait ceiling), SOAK_FOLLOWERS,
 SOAK_CATCHUP_BUDGET_S, and SOAK_STRETCH — a multiplier on injected
-chaos magnitudes used by the gate self-test (stretch > 1 makes the
-lag-spike genuinely worse, so ``perfwatch --check`` must fail).
+chaos magnitudes (stretch > 1 makes the lag-spike genuinely worse, so
+the catch-up and burn-rate axes of the scoreboard move with it).
 
 obs/soakcells.py is this soak's cluster-v2 sibling: the same
 launch/drive/score skeleton over a SHARDED fleet of replicated cells
-behind the shard-aware router, scored as bench cfg16 (cell failover,
+behind the shard-aware router, scored under the cfg16 names (cell failover,
 ownership handoff, cross-cell split-brain, dark-shard envelopes).
 """
 from __future__ import annotations
@@ -1066,9 +1066,8 @@ def run_fleet_soak(base_dir: Optional[str] = None, faulted: bool = True,
 
 
 def scoreboard_metrics(board: dict) -> dict:
-    """Flatten the scoreboard into the numeric cfg11 metrics that fold
-    into perf/baselines.json (names carry perfwatch direction
-    suffixes; exact-match metrics are pinned in perfwatch._OVERRIDES)."""
+    """Flatten the scoreboard into its numeric cfg11 metrics (the
+    ``metrics`` of the scoreboard ``GET /fleet/soak`` serves)."""
     m: Dict[str, float] = {}
     ch = (board.get("halves") or {}).get("chaos")
     cl = (board.get("halves") or {}).get("clean")

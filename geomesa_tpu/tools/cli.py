@@ -22,9 +22,6 @@ mutating commands load → act → save.
     geomesa-tpu router        --endpoint NAME=HOST:PORT ... [--port P]
     geomesa-tpu fleet         status --addr HOST:PORT [--addr ...] [--json]
     geomesa-tpu soak          [--mini] [--scoreboard PATH] [--half chaos|clean]
-    geomesa-tpu perfwatch     check|update|show [--run BENCH_summary.json]
-                              [--baseline perf/baselines.json] [--k 3]
-                              [--report out.json]
     geomesa-tpu recover       --dir DURABILITY_DIR
     geomesa-tpu describe / list / remove-schema
 """
@@ -596,42 +593,6 @@ def cmd_debug(args):
         print(json.dumps(traces[: args.limit], indent=2))
 
 
-def cmd_perfwatch(args):
-    """Perf regression watch (the bench.py --check logic as a standalone
-    command, so a saved BENCH_summary.json gates without re-running the
-    bench): ``check`` compares a run summary against the baseline store
-    with noise-aware (median + k*MAD) thresholds and exits 3 on confirmed
-    regressions; ``update`` folds a run into the rolling baselines;
-    ``show`` prints the baseline medians/MADs."""
-    from geomesa_tpu.obs import perfwatch as pw
-    if args.action == "show":
-        b = pw.load_baselines(args.baseline)
-        print(json.dumps({
-            "updated_ts": b.get("updated_ts"), "runs": b.get("runs"),
-            "meta": b.get("meta"),
-            "metrics": {k: {kk: v[kk] for kk in ("median", "mad",
-                                                 "direction")
-                            if kk in v}
-                        for k, v in sorted(b.get("metrics", {}).items())},
-        }, indent=2))
-        return
-    with open(args.run) as fh:
-        summary = json.load(fh)
-    if args.action == "update":
-        try:
-            b = pw.load_baselines(args.baseline)
-        except (FileNotFoundError, ValueError):
-            b = pw.empty_baselines()
-        pw.save_baselines(pw.update_baselines(b, summary), args.baseline)
-        print(f"baselines updated -> {args.baseline}")
-        return
-    report = pw.check_summary(summary, args.baseline, k=args.k,
-                              report_path=args.report)
-    print(pw.render(report))
-    if not report["ok"]:
-        raise SystemExit(3)
-
-
 def cmd_config(args):
     from geomesa_tpu import config as cfg
     for name, d in cfg.describe().items():
@@ -1072,22 +1033,6 @@ def build_parser() -> argparse.ArgumentParser:
                          "every --addr node (without it, only this "
                          "process's rings are searched)")
     sp.set_defaults(fn=cmd_debug)
-
-    sp = sub.add_parser(
-        "perfwatch",
-        help="noise-aware bench regression gate: check a BENCH_summary "
-             "against committed baselines (median + k*MAD), update the "
-             "rolling baselines, or show them")
-    sp.add_argument("action", choices=("check", "update", "show"))
-    sp.add_argument("--run", default="BENCH_summary.json",
-                    help="flat run summary emitted by bench.py")
-    sp.add_argument("--baseline", default=os.path.join("perf",
-                                                       "baselines.json"))
-    sp.add_argument("--report", default=None,
-                    help="write the structured regression report here")
-    sp.add_argument("--k", type=float, default=None,
-                    help="MAD multiplier (default GEOMESA_TPU_PERFWATCH_K)")
-    sp.set_defaults(fn=cmd_perfwatch)
 
     sp = sub.add_parser("serve", help="REST/GeoJSON API over a store")
     sp.add_argument("-s", "--store", required=True)

@@ -171,6 +171,34 @@ def test_prepare_density_matches_oneshot(store, data):
         np.testing.assert_allclose(np.asarray(o), g1.weights)
 
 
+_MASS_FILTERS = {
+    "bbox": ("BBOX(geom, -50, -20, 50, 30)", lambda d: True),
+    "bbox_during": (
+        "BBOX(geom, -50, -20, 50, 30) AND dtg DURING "
+        "2022-01-02T00:00:00Z/2022-01-05T00:00:00Z",
+        lambda d: (d["dtg"] > np.datetime64("2022-01-02", "ms").astype(np.int64))
+        & (d["dtg"] < np.datetime64("2022-01-05", "ms").astype(np.int64))),
+    "bbox_residual": ("BBOX(geom, -50, -20, 50, 30) AND val < 60",
+                      lambda d: d["val"] < 60),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_MASS_FILTERS))
+def test_density_mass_is_the_count_of_the_same_filter(store, data, case):
+    """Every row a filter matches lands in exactly one cell of a grid that
+    holds the filter's box, and no other row lands in any: the grid's mass
+    is the count of the same filter (and the brute-force count)."""
+    from geomesa_tpu.aggregates.density import prepare_density
+    ecql, rest = _MASS_FILTERS[case]
+    want = int(np.sum((data["x"] >= -50) & (data["x"] <= 50)
+                      & (data["y"] >= -20) & (data["y"] <= 30) & rest(data)))
+    assert want > 0
+    grid = prepare_density(store.planner("tr"), ecql,
+                           (-60, -30, 60, 40), 64, 32)()
+    assert int(grid.weights.sum(dtype=np.float64)) == want
+    assert store.count("tr", ecql) == want
+
+
 def test_density_pruned_blocks_path(monkeypatch):
     """Range-pruned density (block gather + scatter) matches the host grid."""
     from geomesa_tpu.index import prune

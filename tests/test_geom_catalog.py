@@ -375,7 +375,11 @@ def test_two_process_join_byte_equal_to_oracle(join_dryrun):
 
 def test_two_process_join_used_collectives(join_dryrun):
     """The workers actually went through the mesh: psum rounds counted on
-    every rank and every rank held a strict subset of the corpus."""
+    every rank, every rank held a strict subset of the corpus, and both
+    join ops report the two processes they ran across."""
     for r in join_dryrun["ranks"]:
         assert r["psum_rounds"] > 0
         assert 0 < r["local_rows"] < 4000
+        meta = r["battery"]["join_meta"]
+        assert {op: m["num_processes"] for op, m in meta.items()} == {
+            "st_contains": 2, "st_intersects": 2}

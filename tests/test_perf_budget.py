@@ -1,8 +1,8 @@
 """Wall-clock budget pins for the aggregate/process hot paths.
 
 Gated behind GEOMESA_TPU_PERF_TESTS=1 (absolute-time pins flake on loaded CI
-hosts — the advisor's r3 finding); bench.py enforces the real bars at 100M on
-TPU hardware every round. Run explicitly with:
+hosts — the advisor's r3 finding); speeds on the chip come from
+``benchmark/run.py`` and the ledger. Run explicitly with:
 
     GEOMESA_TPU_PERF_TESTS=1 python -m pytest tests/test_perf_budget.py
 """
@@ -80,8 +80,8 @@ def test_scheduler_coalescing_5x(world):
     from geomesa_tpu.trace import RING
 
     # cfg1-like range-pruned regime: distinct overlapping bbox+time queries
-    # whose covers are a small candidate fraction (the serving sweet spot —
-    # bench.py measures the full-scale version on real hardware)
+    # whose covers are a small candidate fraction (the serving sweet spot;
+    # the cell gdelt-z3-10m.count-c64 is the full-scale version on the chip)
     queries = [
         f"BBOX(geom, {-4 + 0.05 * i}, {6 + 0.025 * i}, {-1 + 0.05 * i}, "
         f"{9 + 0.025 * i}) AND "

@@ -67,6 +67,25 @@ def test_z3_pruned_parity_and_fraction():
     assert int(pq.count_async()) == len(expected)
 
 
+def test_explain_counts_the_blocks_the_scan_reads():
+    """``explain`` of a pruned plan reports the scan as it runs: the number
+    of candidate blocks is the number gathered, the scanned rows are those
+    blocks' rows, and every matching row lies in one of them."""
+    sft, table, x, y, dtg = _z3_setup()
+    idx = Z3Index(sft, table)
+    planner = QueryPlanner(sft, table, [idx])
+    plan = planner.plan(Q)
+    blocks = planner._pruned_blocks(plan)
+    assert plan.explain["candidate_blocks"] == len(blocks) > 0
+    assert plan.explain["scanned_rows"] == len(blocks) * prune.BLOCK_SIZE
+    assert plan.explain["scanned_fraction"] == round(
+        len(blocks) * prune.BLOCK_SIZE / len(table), 5)
+    matched = _brute(x, y, dtg)
+    assert 0 < matched.sum() <= plan.explain["candidate_rows"]
+    positions = np.flatnonzero(matched[idx.perm])   # in sorted order
+    assert np.isin(positions // prune.BLOCK_SIZE, blocks).all()
+
+
 def test_z3_pruned_vs_full_scan(monkeypatch):
     sft, table, x, y, dtg = _z3_setup(seed=9)
     idx = Z3Index(sft, table)

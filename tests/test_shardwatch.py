@@ -598,3 +598,44 @@ def test_cli_debug_balance_local_ledger(capsys):
     main(["debug", "balance"])
     out = json.loads(capsys.readouterr().out)
     assert out["active"] and "pts" in out["types"]
+
+
+# -- the real thing (slow: two worker processes a half) ------------------------
+
+
+def _rank0_drill(report):
+    r0 = next(r for r in report["ranks"] if r and r["process_id"] == 0)
+    drill = r0["drill"]
+    return drill, drill["balance"]["types"]["pts"]
+
+
+@pytest.mark.slow
+def test_two_process_balance_drill_verdict():
+    """The two-sided verdict on a real 2-process fleet: a Zipf storm that
+    rank 0 fires at the other rank's key range is flagged, opens exactly one
+    shard_imbalance incident that names the victim shard, and projects split
+    keys inside the victim's key range; the same number of events spread
+    evenly reads balanced and opens none. Both halves still equal the
+    single-process oracle, and the fleet verdict comes from both nodes."""
+    from geomesa_tpu.cluster.dryrun import run_dryrun
+    skew = run_dryrun(num_processes=2, n=8000, drill="skew", timeout_s=360)
+    ctrl = run_dryrun(num_processes=2, n=8000, drill="uniform",
+                      timeout_s=360)
+    assert skew["ok"], json.dumps(skew["checks"], indent=1)
+    assert ctrl["ok"], json.dumps(ctrl["checks"], indent=1)
+
+    drill, pts = _rank0_drill(skew)
+    victim = drill["victim"]
+    assert pts["score"]["over_bar"]
+    incidents = drill["imbalance_incidents"]
+    assert len(incidents) == 1, incidents
+    assert incidents[0]["suspect"]["shard"] == victim
+    lo, hi = pts["shards"][victim]["key_range"]
+    splits = pts["splits"]["boundaries"]
+    assert splits and all(lo < b["key"] <= hi + 1 for b in splits), splits
+    fleet = drill["fleet_balance"]
+    assert len(fleet["nodes"]) == 2 and not fleet.get("partial")
+
+    drill, pts = _rank0_drill(ctrl)
+    assert drill["imbalance_incidents"] == []
+    assert pts["score"]["max_over_mean"] <= 1.35

@@ -161,5 +161,9 @@ def test_cell_soak_two_halves_end_to_end(tmp_path):
     assert m["cfg16_doctor_precision"] == 1.0
     assert m["cfg16_doctor_recall"] == 1.0
     assert m["cfg16_clean_incidents"] == 0.0
+    assert m["cfg16_failover_within_budget"] == 1.0
+    assert m["cfg16_fingerprints_matched"] == 1.0
+    assert m["cfg16_shard_dark_fired"] == 1.0
+    assert m["cfg16_partial_envelope_seen"] == 1.0
     assert (tmp_path / "board.json").exists()
     assert (tmp_path / "board.md").exists()
