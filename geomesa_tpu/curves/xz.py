@@ -14,20 +14,23 @@ Spatial Extension' (Böhm, Klump, Kriegel), matching the reference semantics at
 
 One generic implementation covers both the 2-D quadtree (XZ2) and the 3-D
 octree (XZ3, spatial + binned-time). ``index`` is vectorized over numpy bbox
-arrays (the write path encodes millions of geometries at once); ``ranges``
-stays scalar host code, as in the reference.
+arrays (the write path encodes millions of geometries at once), and the
+decomposition takes a whole tree level a step, every cell of it against every
+window at once (``ranges_arrays``; at most ``g`` numpy steps a cover). The
+reference's cell-by-cell walk is the oracle in tests/test_curves.py.
 """
 
 from __future__ import annotations
 
 import math
-from collections import deque
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from geomesa_tpu.curves.binnedtime import TimePeriod, max_offset
-from geomesa_tpu.curves.ranges import IndexRange, merge_ranges
+from geomesa_tpu.curves.ranges import (IndexRange, merge_range_arrays,
+                                       to_ranges)
+from geomesa_tpu.metrics import REGISTRY as _metrics
 
 
 class XZSFC:
@@ -40,6 +43,15 @@ class XZSFC:
         self._los = np.array([b[0] for b in self.bounds])
         self._sizes = np.array([b[1] - b[0] for b in self.bounds])
         self.fan = 1 << self.dims  # children per cell: 4 (quad) or 8 (oct)
+        # child c of a cell sits at bit d of c along dim d, in half-sides
+        self._child_offsets = np.array(
+            [[(c >> d) & 1 for d in range(self.dims)]
+             for c in range(self.fan)], dtype=np.float64)
+        # codes below a cell of level i + 1, itself included
+        self._subtrees = np.array(
+            [self._seq_term(i) for i in range(self.g)], dtype=np.int64)
+        # a child's code is its parent's plus row [parent's level] of this
+        self._child_terms = 1 + np.arange(self.fan) * self._subtrees[:, None]
 
     # -- indexing ----------------------------------------------------------
 
@@ -99,80 +111,89 @@ class XZSFC:
 
     # -- query decomposition ----------------------------------------------
 
-    def ranges(
-        self,
-        queries: Sequence[Sequence[float]],
-        max_ranges: Optional[int] = None,
-    ) -> List[IndexRange]:
-        """Cover query windows with code ranges.
+    def ranges_arrays(self, queries, max_ranges: Optional[int] = None):
+        """Cover query windows with code ranges: merged (lo, hi, contained)
+        int64 / int64 / bool arrays, the form ``prune.ranges_to_slices``
+        takes with no per-range objects.
 
         queries: each (min_0..min_D-1, max_0..max_D-1) in user space.
+
+        One step a tree level. The frontier holds the level's candidate
+        cells in breadth-first order (lower corners and sequence codes);
+        a cell at ``level`` has side 0.5^level, and its *enlarged* element
+        doubles that side (XElement semantics). Every cell is tested against
+        every window in one comparison. A cell that touches a window emits
+        its own code and, unless a window contains it, expands to its
+        children; a contained one emits all codes prefixed by its own (lemma
+        3). ``max_ranges`` is the budget of entries emitted before merging:
+        an overlapping cell met once it is spent covers its whole subtree
+        coarsely and expands no further.
         """
         max_ranges = max_ranges or (1 << 62)
         q = np.asarray(queries, dtype=np.float64).reshape(-1, 2 * self.dims)
         wmins, wmaxs = self._normalize(q[:, : self.dims], q[:, self.dims:],
                                        lenient=False)   # (W, D) each
 
-        out: List[IndexRange] = []
-
-        def seq_code(point: np.ndarray, length: int) -> int:
-            cs = 0
-            lo = np.zeros(self.dims)
-            hi = np.ones(self.dims)
-            for i in range(length):
-                center = (lo + hi) / 2.0
-                quadrant = 0
-                for d in range(self.dims):
-                    if point[d] >= center[d]:
-                        quadrant |= 1 << d
-                        lo[d] = center[d]
-                    else:
-                        hi[d] = center[d]
-                cs += 1 + quadrant * self._seq_term(i)
-            return cs
-
-        def emit(cell_lo: np.ndarray, level: int, contained: bool) -> None:
-            lo_code = seq_code(cell_lo, level)
-            if contained:
-                # lemma 3: all codes prefixed by this cell's code. NB the
-                # reference adds the full subtree size with no -1
-                # (XZ2SFC.scala:297-306) — over-inclusive by one code, which
-                # the fine filter removes; we match it for parity.
-                hi_code = lo_code + self._seq_term(level - 1)
-            else:
-                hi_code = lo_code
-            out.append(IndexRange(lo_code, hi_code, contained))
-
-        # BFS over cells; a cell at `level` has side 0.5^level, and its
-        # *enlarged* element doubles that side (XElement semantics)
-        queue: deque = deque()
-        root_children = [
-            (np.array([(c >> d) & 1 for d in range(self.dims)]) * 0.5, 1)
-            for c in range(self.fan)
-        ]
-        queue.extend(root_children)
-
-        while queue:
-            cell_lo, level = queue.popleft()
+        lo = self._child_offsets * 0.5       # level 1: the root's children
+        codes = self._child_terms[0]
+        tested = []              # a level's (codes, contained, touches)
+        coarse = []              # (lo, hi) of subtrees a spent budget left
+        emitted = 0              # entries so far
+        for level in range(1, self.g + 1):
             side = 0.5 ** level
-            ext_hi = cell_lo + 2 * side  # enlarged element upper corner
-            # one pass over all windows, however many boxes the cover unions
-            if ((wmins <= cell_lo) & (wmaxs >= ext_hi)).all(axis=1).any():
-                emit(cell_lo, level, True)
-            elif ((wmaxs >= cell_lo) & (wmins <= ext_hi)).all(axis=1).any():
-                emit(cell_lo, level, False)
-                if level < self.g and len(out) < max_ranges:
-                    half = side / 2.0
-                    for c in range(self.fan):
-                        child = cell_lo + np.array(
-                            [((c >> d) & 1) * half for d in range(self.dims)])
-                        queue.append((child, level + 1))
-                elif level < self.g:
-                    # budget exhausted: cover the whole subtree coarsely
-                    lo_code = seq_code(cell_lo, level)
-                    out.append(IndexRange(lo_code, lo_code + self._seq_term(level - 1), False))
+            # [0] the cells' lower corners, [1] the upper corners of their
+            # enlarged elements: (2, n, 1, D) against the (W, D) windows
+            corners = lo[None, :, None, :] + np.array(
+                [0.0, 2 * side])[:, None, None, None]
+            # window min <= lower and max >= upper: contained; min <= upper
+            # and max >= lower: they touch, as a contained cell does too
+            contained, touches = ((wmins <= corners)
+                                  & (wmaxs >= corners)[::-1]).all(-1).any(-1)
+            tested.append((codes, contained, touches))
+            if level == self.g:
+                break
+            expand = touches & ~contained
+            entries = np.count_nonzero(touches)
+            if emitted + entries >= max_ranges:
+                # the budget is tested as each overlapping cell is met,
+                # after its own entry: a running count in breadth-first order
+                under = emitted + np.cumsum(touches) < max_ranges
+                spent = codes[expand & ~under]
+                coarse.append((spent, spent + self._subtrees[level - 1]))
+                entries += len(spent)
+                expand &= under
+            emitted += entries
+            codes = (codes[expand][:, None] + self._child_terms[level]
+                     ).reshape(-1)
+            if not len(codes):
+                break
+            lo = (lo[expand][:, None, :] + self._child_offsets * (side / 2.0)
+                  ).reshape(-1, self.dims)
 
-        return merge_ranges(out)
+        codes, contained, touches = (np.concatenate(a) for a in zip(*tested))
+        _metrics.inc("xz.cover.cells", len(codes))
+        subtree = np.repeat(self._subtrees[:len(tested)],
+                            [len(t[0]) for t in tested])
+        lo = codes[touches]
+        cont = contained[touches]
+        # NB the reference adds the full subtree size with no -1
+        # (XZ2SFC.scala:297-306) — over-inclusive by one code, which the
+        # fine filter removes; we match it for parity.
+        hi = lo + cont * subtree[touches]
+        if coarse:
+            spent_lo, spent_hi = (np.concatenate(a) for a in zip(*coarse))
+            lo = np.concatenate([lo, spent_lo])
+            hi = np.concatenate([hi, spent_hi])
+            cont = np.concatenate([cont, np.zeros(len(spent_lo), bool)])
+        return merge_range_arrays(lo, hi, cont)
+
+    def ranges(
+        self,
+        queries: Sequence[Sequence[float]],
+        max_ranges: Optional[int] = None,
+    ) -> List[IndexRange]:
+        """``ranges_arrays`` in the object form."""
+        return to_ranges(self.ranges_arrays(queries, max_ranges))
 
 
 class XZ2SFC(XZSFC):

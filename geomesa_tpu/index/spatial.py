@@ -1204,7 +1204,7 @@ class XZ3Index(BaseSpatialIndex):
         def cover(bx, w):
             qs = [(xmin, ymin, float(w[0]), xmax, ymax, float(w[1]))
                   for xmin, ymin, xmax, ymax in bx]
-            return sfc.ranges(qs, max_ranges=MAX_RANGES)
+            return sfc.ranges_arrays(qs, max_ranges=MAX_RANGES)
 
         return self._binned_row_slices(boxes, intervals, self.sorted_xz, cover)
 
@@ -1234,8 +1234,8 @@ class XZ2Index(BaseSpatialIndex):
     def _row_slices(self, boxes, intervals):
         from geomesa_tpu.index.prune import MAX_RANGES, ranges_to_slices
         sfc = XZ2SFC.apply(self.sft.xz_precision)
-        rs = sfc.ranges_bbox(boxes, max_ranges=MAX_RANGES)
-        return ranges_to_slices(self.sorted_xz, rs), len(rs)
+        rs = sfc.ranges_arrays(boxes, max_ranges=MAX_RANGES)
+        return ranges_to_slices(self.sorted_xz, rs), len(rs[0])
 
 
 class S2Index(BaseSpatialIndex):

@@ -2,6 +2,8 @@
 reference's Z3SFCTest / XZ2SFCTest (SURVEY.md §4: index/invert round-trips,
 range covers contain indexed points)."""
 
+from collections import deque
+
 import numpy as np
 import pytest
 
@@ -9,6 +11,7 @@ from geomesa_tpu.curves import (
     BitNormalizedDimension,
     TimePeriod,
     XZ2SFC,
+    XZ3SFC,
     Z2SFC,
     Z3SFC,
     max_offset,
@@ -18,6 +21,7 @@ from geomesa_tpu.curves import (
 )
 from geomesa_tpu.curves.ranges import IndexRange
 from geomesa_tpu.curves import zorder
+from geomesa_tpu.metrics import REGISTRY
 
 RNG = np.random.default_rng(42)
 
@@ -297,3 +301,155 @@ class TestManyBoxCovers:
         for box in boxes:
             want |= codes(sfc.ranges_bbox([box]))
         assert codes(sfc.ranges_bbox(boxes)) == want and want
+
+
+def _xz_ranges_walk(sfc, queries, max_ranges=None):
+    """The reference's XZ query decomposition, cell by cell (XZ2SFC.scala
+    ``ranges``: a breadth-first queue, one tree cell an iteration, the
+    sequence code walked from the root for every cell). It served
+    ``XZSFC.ranges`` until the by-level numpy form replaced it, and stays
+    here as that form's oracle. Returns (merged ranges, cells visited)."""
+    max_ranges = max_ranges or (1 << 62)
+    q = np.asarray(queries, dtype=np.float64).reshape(-1, 2 * sfc.dims)
+    wmins, wmaxs = sfc._normalize(q[:, : sfc.dims], q[:, sfc.dims:],
+                                  lenient=False)   # (W, D) each
+
+    out = []
+
+    def seq_code(point, length):
+        cs = 0
+        lo = np.zeros(sfc.dims)
+        hi = np.ones(sfc.dims)
+        for i in range(length):
+            center = (lo + hi) / 2.0
+            quadrant = 0
+            for d in range(sfc.dims):
+                if point[d] >= center[d]:
+                    quadrant |= 1 << d
+                    lo[d] = center[d]
+                else:
+                    hi[d] = center[d]
+            cs += 1 + quadrant * sfc._seq_term(i)
+        return cs
+
+    def emit(cell_lo, level, contained):
+        lo_code = seq_code(cell_lo, level)
+        if contained:
+            # lemma 3, with the reference's over-inclusive upper code
+            hi_code = lo_code + sfc._seq_term(level - 1)
+        else:
+            hi_code = lo_code
+        out.append(IndexRange(lo_code, hi_code, contained))
+
+    queue = deque(
+        (np.array([(c >> d) & 1 for d in range(sfc.dims)]) * 0.5, 1)
+        for c in range(sfc.fan))
+    cells = 0
+    while queue:
+        cell_lo, level = queue.popleft()
+        cells += 1
+        side = 0.5 ** level
+        ext_hi = cell_lo + 2 * side  # enlarged element upper corner
+        if ((wmins <= cell_lo) & (wmaxs >= ext_hi)).all(axis=1).any():
+            emit(cell_lo, level, True)
+        elif ((wmaxs >= cell_lo) & (wmins <= ext_hi)).all(axis=1).any():
+            emit(cell_lo, level, False)
+            if level < sfc.g and len(out) < max_ranges:
+                half = side / 2.0
+                for c in range(sfc.fan):
+                    child = cell_lo + np.array(
+                        [((c >> d) & 1) * half for d in range(sfc.dims)])
+                    queue.append((child, level + 1))
+            elif level < sfc.g:
+                # budget exhausted: cover the whole subtree coarsely
+                lo_code = seq_code(cell_lo, level)
+                out.append(IndexRange(
+                    lo_code, lo_code + sfc._seq_term(level - 1), False))
+    return merge_ranges(out), cells
+
+
+def _xz_cover_cells() -> int:
+    """The counter ``xz.cover.cells``: cells the XZ covers so far tested."""
+    return REGISTRY.snapshot()["counters"].get("xz.cover.cells", 0)
+
+
+_WEEK_S = float(max_offset(TimePeriod.WEEK))
+_XZ_CURVES = {"xz2-g12": lambda: XZ2SFC(g=12),
+              "xz2-g6": lambda: XZ2SFC(g=6),
+              "xz3-g12": lambda: XZ3SFC(g=12, period=TimePeriod.WEEK)}
+
+
+def _xz_windows(name: str, dims: int):
+    """The window sets of the parity cases as (xmin, ymin, xmax, ymax) boxes
+    (XZ3 adds a time window to each): ``cell-N`` are N windows of the OSM
+    cell's sizes (0.1-1 degree) around one urban cluster."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    if name.startswith("cell-"):
+        n = int(name.split("-")[1])
+        cx = 13.4 + rng.uniform(-2, 2, n)
+        cy = 52.5 + rng.uniform(-2, 2, n)
+        w = rng.uniform(0.1, 1.0, (2, n))
+        boxes = np.stack([cx, cy, cx + w[0], cy + w[1]], axis=1)
+    else:
+        boxes = np.array({
+            "point": [(-73.9857, 40.7484, -73.9857, 40.7484)],
+            "bounds": [(179.0, 89.0, 180.0, 90.0),
+                       (-180.0, -90.0, -179.0, -89.0)],
+            "world": [(-180.0, -90.0, 180.0, 90.0)],
+        }[name])
+    if dims == 2:
+        return boxes
+    if name == "world":
+        t0, t1 = np.zeros(1), np.full(1, _WEEK_S)
+    elif name == "point":
+        t0 = t1 = np.full(1, 86400.0)
+    else:   # a quarter of an hour to an hour, somewhere in the week
+        t0 = rng.uniform(0, _WEEK_S - 3600, len(boxes))
+        t1 = t0 + rng.uniform(900, 3600, len(boxes))
+    return np.column_stack([boxes[:, :2], t0, boxes[:, 2:], t1])
+
+
+class TestXZRangesParity:
+    """``XZSFC.ranges_arrays`` takes a tree level a step; the cell-by-cell
+    walk it replaced is the oracle, and the merged ranges are equal, the
+    range budget's coarse covers included."""
+
+    @pytest.mark.parametrize("max_ranges", [1, 7, 64, 2000, None])
+    @pytest.mark.parametrize(
+        "windows", ["cell-1", "cell-2", "cell-5", "cell-16", "point",
+                    "bounds", "world"])
+    @pytest.mark.parametrize("curve", list(_XZ_CURVES))
+    def test_ranges_equal_the_walk(self, curve, windows, max_ranges):
+        sfc = _XZ_CURVES[curve]()
+        if windows == "world" and max_ranges is None and sfc.g > 6:
+            # the world's edge cells overlap at every level: unbudgeted, the
+            # walk visits 4^g (8^g) of them. 20,000 spends as 2,000 does
+            max_ranges = 20_000
+        qs = _xz_windows(windows, sfc.dims)
+        want, cells = _xz_ranges_walk(sfc, qs, max_ranges)
+        before = _xz_cover_cells()
+        lo, hi, cont = sfc.ranges_arrays(qs, max_ranges)
+        assert _xz_cover_cells() - before == cells
+        assert lo.dtype == hi.dtype == np.int64 and cont.dtype == bool
+        assert want and len(lo) == len(want)
+        np.testing.assert_array_equal(lo, [r.lower for r in want])
+        np.testing.assert_array_equal(hi, [r.upper for r in want])
+        np.testing.assert_array_equal(cont, [r.contained for r in want])
+        assert sfc.ranges(qs, max_ranges) == want
+
+    @pytest.mark.parametrize("curve", list(_XZ_CURVES))
+    def test_window_out_of_bounds_raises(self, curve):
+        sfc = _XZ_CURVES[curve]()
+        qs = _xz_windows("cell-2", sfc.dims)
+        qs[1, 0], qs[1, sfc.dims] = -181.0, -179.5
+        with pytest.raises(ValueError):
+            sfc.ranges_arrays(qs)
+        with pytest.raises(ValueError):
+            sfc.ranges(qs, 64)
+
+    def test_ranges_bbox_is_the_object_form(self):
+        sfc = XZ2SFC.apply(12)
+        boxes = [tuple(b) for b in _xz_windows("cell-5", 2)]
+        want, _ = _xz_ranges_walk(sfc, boxes, 2000)
+        assert sfc.ranges_bbox(boxes, max_ranges=2000) == want
+        assert all(isinstance(r, IndexRange) for r in want)

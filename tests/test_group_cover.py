@@ -9,12 +9,15 @@ import numpy as np
 import pytest
 
 from geomesa_tpu import config
+from geomesa_tpu.curves import XZ2SFC, XZ3SFC
 from geomesa_tpu.datastore import TpuDataStore
 from geomesa_tpu.features.geometry import LINESTRING, GeometryArray
 from geomesa_tpu.features.table import FeatureTable
 from geomesa_tpu.index import prune
 from geomesa_tpu.obs.flight import RECORDER
 from geomesa_tpu.serve.scheduler import QueryScheduler, StoreBinding
+
+from test_curves import _xz_cover_cells, _xz_ranges_walk
 
 N = 200_000
 DURING = "dtg DURING 2020-01-05T00:00:00Z/2020-01-12T00:00:00Z"
@@ -150,6 +153,47 @@ def test_group_of_one_is_the_plans_own_cover(setup):
     got, _ = s.index.cover_blocks(list(fresh.explain["boxes"]),
                                   s.index.cover_intervals(fresh))
     np.testing.assert_array_equal(got, want)
+
+
+def test_xz_cover_is_the_cell_walks_cover(setup):
+    """An extent index hands ``XZSFC.ranges_arrays`` straight to the slice
+    search: the blocks and ``cover_ranges`` are those the reference's
+    cell-by-cell walk gives through the same search, and ``xz.cover.cells``
+    rises by the cells that walk visits. A point index never enters the XZ
+    decomposition."""
+    s = setup
+    boxes = [s.box(3 * i) for i in range(5)]
+    intervals = s.index.cover_intervals(s.planner.plan(s.query(boxes[0])))
+    c0 = _xz_cover_cells()
+    blocks, stats = s.index.cover_blocks(boxes, intervals)
+    rose = _xz_cover_cells() - c0
+    assert blocks is not None and len(blocks) > 0
+    if s.points:
+        assert rose == 0
+        return
+    g = s.index.sft.xz_precision
+    sfc = XZ3SFC.apply(g, s.index.period) if s.temporal else XZ2SFC.apply(g)
+    keys = s.index.sorted_xz
+    if s.temporal:
+        segs, covers, slices = s.index._bin_segments(), {}, []
+        for b, w in prune.bin_windows(intervals, s.index.period):
+            lo, hi = segs.segment(b)
+            if lo >= hi:
+                continue
+            if w not in covers:
+                covers[w] = _xz_ranges_walk(
+                    sfc, [(x0, y0, float(w[0]), x1, y1, float(w[1]))
+                          for x0, y0, x1, y1 in boxes], prune.MAX_RANGES)
+            slices.append(prune.ranges_to_slices(keys, covers[w][0],
+                                                 lo=lo, hi=hi))
+        walked, slices = list(covers.values()), np.concatenate(slices)
+        assert len(walked) == 2   # DURING's week spans two bins
+    else:
+        walked = [_xz_ranges_walk(sfc, boxes, prune.MAX_RANGES)]
+        slices = prune.ranges_to_slices(keys, walked[0][0])
+    np.testing.assert_array_equal(blocks, prune.slices_to_blocks(slices, N))
+    assert stats["cover_ranges"] == sum(len(r) for r, _ in walked)
+    assert rose == sum(n for _, n in walked) > 0
 
 
 def test_mixed_group_past_the_fraction_scans_unpruned_and_exact(setup):
