@@ -28,7 +28,7 @@ from geomesa_tpu.features.sft import SimpleFeatureType
 from geomesa_tpu.features.table import FeatureTable, StringColumn
 from geomesa_tpu.filter import ir
 from geomesa_tpu.index.api import QueryResult
-from geomesa_tpu.index.planner import QueryPlanner
+from geomesa_tpu.index.planner import QueryPlanner, tally_join
 from geomesa_tpu.index.spatial import INDEX_CLASSES, FullScanIndex
 
 _INDEX_BY_NAME = {c.name: c for c in INDEX_CLASSES}
@@ -39,6 +39,7 @@ _INDEX_BY_NAME = {c.name: c for c in INDEX_CLASSES}
 # one restored with identical generation counters
 import itertools as _itertools
 import os as _os
+import re
 
 _EPOCHS = _itertools.count(1)
 
@@ -1026,6 +1027,91 @@ class TpuDataStore:
         if delta is not None:
             c += len(self._delta_rows(delta, f, auths))
         return c
+
+    JOIN_OPS = ("st_intersects", "st_contains")
+
+    def join(self, type_name: str, with_type: str,
+             op: str = "st_intersects",
+             f: Union[str, ir.Filter] = "INCLUDE",
+             stats: Union[str, Sequence[str]] = "count",
+             auths: Optional[list] = None,
+             deadline_ms: Optional[float] = None) -> dict:
+        """Spatial join of the point type ``type_name`` with the polygons of
+        ``with_type``, aggregated by polygon (≙ the Spark SQL join planned by
+        geomesa-spark-sql's SpatialJoinStrategy, and the tutorials'
+        ShallowJoin):
+
+            SELECT c, count(*), sum(g.<attr>)... FROM <type_name> g
+            JOIN <with_type> c ON <op>(c.geom, g.geom) WHERE <f on g>
+            GROUP BY c
+
+        ``op``: ``st_intersects`` (a point on a polygon's boundary counts)
+        or ``st_contains`` (it does not). ``stats``: ``count`` and
+        ``sum(<Integer attribute>)`` terms, a list or comma-separated.
+        Exact: against the f64 coordinates of both types, writes to either
+        included (a pending polygon delta is flushed first: the polygons'
+        segments are read from the device). Returns ``io.export.join_answer``'s
+        dict: a row a polygon in the polygon table's order."""
+        from geomesa_tpu.filter import geom_batch as _gb
+        from geomesa_tpu.filter.parser import parse_ecql
+        from geomesa_tpu.io.export import join_answer
+        from geomesa_tpu.metrics import REGISTRY as _metrics
+        from geomesa_tpu.serve.resilience import deadline as _rdl
+        if op not in self.JOIN_OPS:
+            raise ValueError(f"join op {op!r}: one of {self.JOIN_OPS}")
+        sft, other = self.schemas[type_name], self.schemas[with_type]
+        if sft.geometry_attribute is None \
+                or sft.geometry_attribute.type_name != "Point":
+            raise ValueError(f"join: {type_name!r} is not a point type")
+        if other.geometry_attribute is None \
+                or other.geometry_attribute.type_name not in (
+                    "Polygon", "MultiPolygon"):
+            raise ValueError(f"join: {with_type!r} is not a polygon type")
+        terms = [t.strip() for t in (stats.split(",") if isinstance(
+            stats, str) else stats) if t.strip()]
+        attrs = []
+        for t in terms:
+            m = re.fullmatch(r"sum\((\w+)\)", t)
+            if m is None and t != "count":
+                raise ValueError(f"join stat {t!r}: count or sum(<attr>)")
+            if m is not None:
+                a = sft.attribute(m.group(1))
+                if a.type_name != "Integer":
+                    raise ValueError(
+                        f"join: sum({a.name}) needs an Integer attribute")
+                if a.name not in attrs:
+                    attrs.append(a.name)
+        _metrics.inc("query.joins")
+        with _trace.trace("query.join", type=type_name, other=with_type,
+                          filter=str(f)), _rdl.scope(deadline_ms):
+            fir = parse_ecql(f) if isinstance(f, str) else f
+            with self._lock:
+                # one consistent view of both sides
+                if self.tables.get(with_type) is None:
+                    return {"op": op, "polygons": 0, "rows": []}
+                polygons = self.planner(with_type)
+                if self.tables.get(type_name) is None:
+                    points = delta = None
+                else:
+                    points, delta = self._snapshot(type_name)
+            boundary = op == "st_intersects"
+            n = len(polygons.table)
+            counts = np.zeros(n, dtype=np.int64)
+            sums = np.zeros((len(attrs), n), dtype=np.int64)
+            if points is not None:
+                counts, sums = points.join_polygons(
+                    fir, polygons, boundary, tuple(attrs), auths=auths)
+            if delta is not None:
+                rows = self._delta_rows(delta, fir, auths)
+                x, y = delta.geometry().point_xy()
+                i, polys = _gb.points_in_polygons(
+                    x[rows], y[rows], polygons.table.geometry(), boundary)
+                tally_join(counts, sums, polys, [
+                    np.asarray(delta.columns[a])[rows[i]] for a in attrs])
+            _metrics.inc("join.pairs_matched", int(counts.sum()))
+            with _trace.span("serialize"):
+                return join_answer(op, polygons.table, counts,
+                                   dict(zip(attrs, sums)))
 
     def explain(self, type_name: str, f: Union[str, ir.Filter],
                 analyze: bool = False, auths: Optional[list] = None) -> dict:

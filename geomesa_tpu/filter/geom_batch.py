@@ -395,3 +395,99 @@ def _segs_to_points_min(segs: np.ndarray, pts: np.ndarray) -> np.ndarray:
                     / np.where(ll == 0, 1, ll), 0, 1)
     cx, cy = x1 + t * dx, y1 + t * dy
     return np.sqrt(np.min((px - cx) ** 2 + (py - cy) ** 2, axis=1))
+
+
+# -- point × polygon pairs (the join's refine) -------------------------------
+
+
+KEY_ROW = 1024.0   # a feature's share of the slab keys: over any y + 90
+
+
+def slab_order(y1: np.ndarray, y2: np.ndarray, owner: np.ndarray, n: int):
+    """(order, key, rise) that put the segments (y1 → y2) of ``n`` features
+    (``owner``: a segment's feature) in slab order, by (feature, lower end):
+    ``key`` = feature * 1024 + (lower end + 90) of the ordered segments,
+    f64, ascending, and ``rise`` the tallest segment of each feature, so
+    that the segments of feature p whose y-range holds y are among those
+    with key in [p * 1024 + 90 + y - rise[p], p * 1024 + 90 + y]: one
+    search a side. Ties stay as they were."""
+    key = owner * KEY_ROW + (np.minimum(y1, y2).astype(np.float64) + 90.0)
+    order = np.argsort(key, kind="stable")
+    rise = np.zeros(n)
+    np.maximum.at(rise, owner, np.abs(y2 - y1).astype(np.float64))
+    return order, key[order], rise
+
+
+def _slabs(arr: geo.GeometryArray):
+    """(x1, y1, x2, y2, key, rise) of every boundary segment of the
+    polygonal ``arr`` in ``slab_order``. Kept on the array, which is treated
+    as immutable."""
+    cached = getattr(arr, "_slab_cache", None)
+    if cached is None:
+        segs, owner = build_segments(arr, np.arange(len(arr)))
+        order, key, rise = slab_order(segs[:, 1], segs[:, 3], owner, len(arr))
+        cached = arr._slab_cache = (*segs[order].T, key, rise)
+    return cached
+
+
+def pairs_in_polygons(px: np.ndarray, py: np.ndarray, polygon: np.ndarray,
+                      arr: geo.GeometryArray, boundary: bool) -> np.ndarray:
+    """bool (k,): point (px[i], py[i]) lies in feature ``polygon[i]`` of the
+    polygonal ``arr``; a point on a ring counts where ``boundary`` (OGC
+    intersects) and does not where not (contains). ``points_in_polygon``'s
+    arithmetic (crossing parity over all rings, then the on-segment test)
+    over the point x segment couples that can matter alone: the segments of
+    the point's polygon whose y-range holds the point's y, found as one span
+    of the polygon's segments in the order of their lower end."""
+    px, py = np.asarray(px, np.float64), np.asarray(py, np.float64)
+    polygon = np.asarray(polygon, dtype=np.int64)
+    x1, y1, x2, y2, key, rise = _slabs(arr)
+    at = polygon * KEY_ROW + 90.0
+    # a little wide: the keys round at ~1e-7 of a degree for a million rows
+    lo = np.searchsorted(key, at + np.maximum(py - rise[polygon] - 1e-6,
+                                              -90.0))
+    hi = np.searchsorted(key, at + np.minimum(py + 1e-6, 90.0), side="right")
+    out = np.zeros(len(px), dtype=bool)
+    step = max(1, _CHUNK // max(1, int((hi - lo).max(initial=1))))
+    for c in range(0, len(px), step):
+        n = (hi - lo)[c: c + step]
+        i = np.repeat(np.arange(len(n)), n)
+        j = _expand_slices(lo[c: c + step], n)
+        x, y = px[c: c + step][i], py[c: c + step][i]
+        ax, ay, bx, by = x1[j], y1[j], x2[j], y2[j]
+        cross = (ay > y) != (by > y)
+        with np.errstate(divide="ignore", invalid="ignore"):
+            cross &= x < (bx - ax) * (y - ay) / (by - ay) + ax
+        inside = (np.bincount(i[cross], minlength=len(n)) & 1) > 0
+        t1, t2 = (bx - ax) * (y - ay), (by - ay) * (x - ax)
+        on = (np.abs(t1 - t2) <= gn._CROSS_ROUNDING
+              * (np.abs(t1) + np.abs(t2))) \
+            & (np.minimum(ax, bx) - 1e-12 <= x) \
+            & (x <= np.maximum(ax, bx) + 1e-12) \
+            & (np.minimum(ay, by) - 1e-12 <= y) \
+            & (y <= np.maximum(ay, by) + 1e-12)
+        on = np.bincount(i[on], minlength=len(n)) > 0
+        out[c: c + step] = (inside | on) if boundary else (inside & ~on)
+    return out
+
+
+def points_in_polygons(px: np.ndarray, py: np.ndarray,
+                       arr: geo.GeometryArray, boundary: bool
+                       ) -> Tuple[np.ndarray, np.ndarray]:
+    """(point, polygon) index arrays of every match of the points with every
+    feature of the polygonal ``arr``: the envelopes first, then
+    ``pairs_in_polygons`` over the couples they leave."""
+    px, py = np.asarray(px, np.float64), np.asarray(py, np.float64)
+    x0, y0, x1, y1 = arr.bboxes().T
+    pts, pol = [], []
+    step = max(1, _CHUNK // max(1, len(arr)))
+    for c in range(0, len(px), step):
+        x, y = px[c: c + step, None], py[c: c + step, None]
+        i, p = np.nonzero((x >= x0) & (x <= x1) & (y >= y0) & (y <= y1))
+        pts.append(i + c)
+        pol.append(p)
+    if not pts:
+        return np.empty(0, np.int64), np.empty(0, np.int64)
+    pts, pol = np.concatenate(pts), np.concatenate(pol)
+    keep = pairs_in_polygons(px[pts], py[pts], pol, arr, boundary)
+    return pts[keep], pol[keep]

@@ -17,7 +17,10 @@ index-key order with the serialized values (SURVEY.md §3.2 step 4). Here the
                    contiguous span and a tile of it one slice (the banded
                    intersects refine, scan.intersects_band_blocks). Ragged:
                    added after the build or merge of the per-row columns,
-                   never gathered or merged as one of them
+                   never gathered or merged as one of them. A polygon type
+                   also keeps ``__segy__``, the pool again with each row's
+                   segments sorted by their lower end (the join's slabs,
+                   scan.join_band_blocks)
   - attribute columns: numeric as int32/f32; strings as dictionary codes;
                    dates additionally as (bin, off) when they are the primary
                    temporal axis
@@ -308,6 +311,42 @@ def segment_pool(garr: GeometryArray, perm: np.ndarray, pad: int):
     return {SEG: gather(*xy, jnp.asarray(a), jnp.asarray(b)),
             WAY: jnp.asarray(np.stack([seg_off[:-1], seg_n.astype(np.int32),
                                        kind]))}, seg_off
+
+
+# rows of a polygon type's pool again, each row's segments sorted by their
+# lower end, in chunks of SEG_CHUNK: the plane the join reads a tile's slab
+# of a polygon from, whole chunks at a time (a gather of rows; a slice a
+# pair at its own offset is a loop of some 100,000 turns a join)
+SEGY = "__segy__"
+SEG_CHUNK = 64
+
+
+def segments_by_y(seg, seg_off: np.ndarray):
+    """(device plane, ykey, rise) of a pool's segments ``seg`` ((4, n + pad)
+    f32 on the device, rows at ``seg_off``): the same segments, every row's
+    in ``geom_batch.slab_order`` (by their lower end), so that the segments
+    of a row that can reach into a y-range are one span of it, as (chunks,
+    4, SEG_CHUNK): segment i is [i // SEG_CHUNK, :, i % SEG_CHUNK]; that
+    order's search keys, ascending over the whole pool, and each row's
+    tallest segment. Of the f32 plane's own values: what the kernel
+    compares."""
+    import jax
+
+    from geomesa_tpu.filter.geom_batch import slab_order
+    total = int(seg_off[-1])
+    host = np.asarray(seg)
+    take = np.arange(host.shape[1])
+    take[:total], ykey, rise = slab_order(
+        host[1, :total], host[3, :total],
+        np.repeat(np.arange(len(seg_off) - 1), np.diff(seg_off)),
+        len(seg_off) - 1)
+    chunks = -(-host.shape[1] // SEG_CHUNK)
+    gather = _merge_cache().get(
+        ("segments_by_y", host.shape[1]),
+        lambda: jax.jit(lambda s, t: jnp.pad(
+            s[:, t], ((0, 0), (0, chunks * SEG_CHUNK - s.shape[1]))
+        ).reshape(4, chunks, SEG_CHUNK).transpose(1, 0, 2)))
+    return gather(seg, jnp.asarray(take.astype(np.int32))), ykey, rise
 
 
 _MERGE_CACHE = None

@@ -56,6 +56,17 @@ def _pad_pow2(arr: np.ndarray, fill: int) -> np.ndarray:
     return out
 
 
+def tally_join(counts: np.ndarray, sums: np.ndarray, polys: np.ndarray,
+               values) -> None:
+    """Add matched (point, polygon) couples to a join's running answer:
+    ``polys`` the polygon of each, ``values`` the summed attributes' values
+    of its point, one array a row of ``sums``."""
+    counts += np.bincount(polys, minlength=len(counts))
+    for k, v in enumerate(values):
+        sums[k] += np.bincount(polys, weights=v,
+                               minlength=len(counts)).astype(np.int64)
+
+
 class QueryPlanner:
     """Planner + executor for one feature type."""
 
@@ -485,6 +496,177 @@ class QueryPlanner:
             rows = plan.index.map_rows(unc)
             return certain + int(batch_intersects(
                 self.table.geometry(), rows, res.geometry).sum())
+
+    # -- spatial join (≙ geomesa-spark-sql SpatialJoinStrategy) --------------
+
+    def join_polygons(self, f: Union[str, ir.Filter],
+                      other: "QueryPlanner", boundary: bool, stats: tuple,
+                      auths=None):
+        """This point type's rows that pass ``f``, grouped by the polygons
+        of ``other``'s table that hold them: (counts (P,), sums
+        (len(stats), P)), int64, in that table's order; ``stats`` names
+        Integer attributes. A point on a polygon's boundary counts where
+        ``boundary`` (st_intersects) and not where not (st_contains).
+
+        Planned and covered as a count of ``f``. Then the gate pairs the
+        candidate blocks' tiles (a block's rows in ascending y) with the
+        polygons whose envelope they meet and, of each, the slab of
+        segments that can reach the tile's y-range; one grouped launch
+        classifies every (point, polygon) couple of those pairs against the
+        slabs in ``other``'s y-sorted pool (f32, with certainty bands) and
+        reduces the certain ones; the host settles the uncertain couples in
+        f64. A plan the device cannot mask alone, or a polygon type without
+        a pool, joins on the host."""
+        from geomesa_tpu.filter import geom_batch as _gb
+        from geomesa_tpu.index import scan as _scan
+        f = f if isinstance(f, ir.Filter) else parse_ecql(f)
+        polygons = other.table.geometry()
+        counts = np.zeros(len(polygons), dtype=np.int64)
+        sums = np.zeros((len(stats), len(polygons)), dtype=np.int64)
+        values = [np.asarray(self.table.columns[a]) for a in stats]
+        px, py = self.table.geometry().point_xy()
+
+        def add(rows, polys):
+            tally_join(counts, sums, polys, [v[rows] for v in values])
+
+        plan = self._apply_auths(self.plan(f), auths)
+        if plan.empty or len(polygons) == 0:
+            return counts, sums
+        pool = next((i for i in other.indexes
+                     if getattr(i, "seg_ykey", None) is not None), None)
+
+        def on_the_host():
+            with _trace.span("join.refine", kind="refine") as sp:
+                rows = self.select_indices(f, plan=plan, auths=auths)
+                # a window's end inside an offset unit: the plan's windows
+                # hold the whole unit (time_windows)
+                rows = rows[_evaluate_at(f, self.table, rows)]
+                sp.set(pairs=len(rows) * len(polygons))
+                i, polys = _gb.points_in_polygons(px[rows], py[rows],
+                                                  polygons, boundary)
+                add(rows[i], polys)
+            return counts, sums
+
+        if pool is None or plan.residual_host is not None \
+                or plan.candidate_slices is not None \
+                or "xf" not in getattr(plan.index, "device", {}):
+            return on_the_host()
+
+        idx = plan.index
+        blocks = self._pruned_blocks(plan)
+        with _trace.span("join.gate") as sp:
+            bsz = min(_prune.BLOCK_SIZE, len(self.table))
+            tile = min(_scan.JOIN_TILE, bsz)
+            env = idx.join_envelopes(bsz, tile)
+            blocks = _prune.gate_blocks(
+                env, blocks, plan.windows, plan.explain.get("boxes")
+                if plan.boxes_loose is not None else None)
+            pairs = _prune.gate_slabs(
+                env, blocks, pool.polygon_envelopes(), pool.seg_ykey,
+                pool.seg_rise, _scan.SEG_CHUNK)
+            sp.set(blocks=len(blocks), polygons=len(np.unique(pairs[:, 4])),
+                   pairs=len(pairs))
+        _metrics.inc("join.block_polygon_pairs", len(pairs))
+        # a slab of more segments than the pool's pad cannot be read in one
+        # slice: such a pair is the host's
+        wide = pairs[:, 3] > _scan.POOL_TILE
+        host, pairs, unc = pairs[wide], pairs[~wide], None
+        late = np.empty(0, dtype=np.int64)
+        if len(pairs):
+            _rdl.check_current("join.device")
+            pcols = pool.device.columns
+            with _trace.span("join.device", blocks=len(blocks),
+                             pairs=len(pairs)) as sp:
+                strict = None if plan.windows is None else idx.time_windows(
+                    plan.explain["intervals"], strict=True)
+                inside, open_, psums, passed, unc, late, launches = \
+                    idx.kernels.join_band_blocks(
+                        plan.primary_kind, plan.boxes_loose, plan.windows,
+                        plan.residual_device, strict, pcols[_scan.SEGY],
+                        [pcols[k] for k in ("bxmin", "bymin", "bxmax",
+                                            "bymax")],
+                        idx._dev_perm, blocks.astype(np.int32), bsz, pairs,
+                        stats)
+                if idx._dev_perm is None:
+                    late = late if late is None else idx.perm[late]
+                    if unc is not None:
+                        unc[:, 1] = idx.perm[unc[:, 1]]
+                edges = int(np.take(_scan.JOIN_WIDTHS, np.searchsorted(
+                    _scan.JOIN_WIDTHS, pairs[:, 3])).sum()) * tile
+                sp.set(points=len(blocks) * bsz, edges=edges,
+                       certain=int(inside.sum()), uncertain=int(open_.sum()),
+                       launches=launches)
+            _metrics.inc("join.launches", launches)
+            _metrics.inc("join.points_scanned", len(blocks) * bsz)
+            _metrics.inc("join.point_pairs", int(passed.sum()))
+            _metrics.inc("join.edge_tests", edges)
+            _metrics.inc("join.segments_read",
+                         int((pairs[:, 3] - pairs[:, 2]).sum()))
+            _metrics.inc("join.pairs_uncertain", int(open_.sum()))
+            if late is None:
+                # more rows in the windows' boundary units than the kernel
+                # lists: only the host's filter can tell them apart
+                return on_the_host()
+            if unc is None:
+                # more uncertain couples than the kernel lists: every pair
+                # that has one is the host's, whole
+                _metrics.inc("join.overflow_fallbacks")
+                redo = open_ > 0
+                host = np.concatenate([host, pairs[redo]])
+                pairs, inside, psums = pairs[~redo], inside[~redo], \
+                    psums[:, ~redo]
+            at = pool.map_rows(pairs[:, 4])
+            counts += np.bincount(at, weights=inside,
+                                  minlength=len(counts)).astype(np.int64)
+            for k in range(len(stats)):
+                np.add.at(sums[k], at, psums[k])
+        with _trace.span("join.refine", kind="refine") as sp:
+            n_refined = 0
+            if len(late):
+                # rows of a window's boundary unit (a second, for weeks):
+                # in no tile's numbers, theirs are the host's
+                late = late[_evaluate_at(f, self.table, late)]
+                if auths is not None:
+                    late = self._fid_vis_filter(late, auths)
+                i, polys = _gb.points_in_polygons(px[late], py[late],
+                                                  polygons, boundary)
+                add(late[i], polys)
+                n_refined += len(late) * len(polygons)
+            if unc is not None and len(unc):
+                rows = unc[:, 1]
+                polys = pool.map_rows(pairs[unc[:, 0], 4])
+                hit = _gb.pairs_in_polygons(px[rows], py[rows], polys,
+                                            polygons, boundary)
+                add(rows[hit], polys[hit])
+                n_refined += len(rows)
+            if len(host):
+                # the rows of the host's tiles: a block's, in ascending y
+                # of the f32 plane, as the kernel cuts them
+                per = -(-bsz // tile)
+                which, t = np.divmod(host[:, 0], per)
+                theirs = pool.map_rows(host[:, 4])
+                rows, polys = [], []
+                for j in np.unique(which):
+                    lo = int(blocks[j]) * bsz
+                    block = idx.map_rows(np.arange(
+                        lo, min(lo + bsz, len(self.table))))
+                    block = block[np.argsort(py[block].astype(np.float32),
+                                             kind="stable")]
+                    for k in np.flatnonzero(which == j):
+                        mine = block[t[k] * tile: (t[k] + 1) * tile]
+                        rows.append(mine)
+                        polys.append(np.full(len(mine), theirs[k]))
+                rows, polys = np.concatenate(rows), np.concatenate(polys)
+                ok = _evaluate_at(f, self.table, rows) & ~np.isin(rows, late)
+                if auths is not None:
+                    ok &= np.isin(rows, self._fid_vis_filter(rows, auths))
+                rows, polys = rows[ok], polys[ok]
+                hit = _gb.pairs_in_polygons(px[rows], py[rows], polys,
+                                            polygons, boundary)
+                add(rows[hit], polys[hit])
+                n_refined += len(rows)
+            sp.set(pairs=n_refined)
+        return counts, sums
 
     def select_indices(self, f: Union[str, ir.Filter],
                        plan: Optional[IndexScanPlan] = None,

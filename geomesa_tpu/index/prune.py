@@ -175,3 +175,76 @@ class BinSegments:
 
     def all_bins(self) -> np.ndarray:
         return self.bins
+
+
+# -- the join's gate ----------------------------------------------------------
+
+# slack of the gate's envelope tests in degrees: a tile's envelope is of the
+# f32 planes, within 2.5e-5 of the f64 coordinates (scan._IN_DELTA), and a
+# segment's y-tie band is 3e-5 (scan._DY_BAND)
+GATE_SLACK = 1e-3
+
+
+def gate_blocks(env: dict, blocks: Optional[np.ndarray],
+                windows: Optional[np.ndarray], boxes) -> np.ndarray:
+    """Sorted ids of the gather blocks that may hold a row of a plan: those
+    of the cover's ``blocks`` (None: the whole table) whose time span meets
+    one of the plan's ``windows`` ((T, 4) int32 bin/offset bounds, pads with
+    bin_lo > bin_hi) and whose envelope meets one of its ``boxes``
+    (user-space (xmin, ymin, xmax, ymax)). ``env``: the index's
+    ``join_envelopes``. A superset: the kernel applies the exact mask to
+    what it gathers."""
+    if blocks is None:
+        blocks = np.arange(len(env["xmin"]))
+    blocks = np.asarray(blocks, dtype=np.int64)
+    if windows is not None and "tmin" in env:
+        w = windows[windows[:, 0] <= windows[:, 2]].astype(np.int64)
+        lo, hi = (w[:, 0] << 32) + w[:, 1], (w[:, 2] << 32) + w[:, 3]
+        blocks = blocks[np.any((env["tmax"][blocks, None] >= lo)
+                               & (env["tmin"][blocks, None] <= hi), axis=1)]
+    if boxes:
+        b = np.asarray(boxes, dtype=np.float64)
+        x0, x1 = env["xmin"][blocks].min(1), env["xmax"][blocks].max(1)
+        y0, y1 = env["ymin"][blocks].min(1), env["ymax"][blocks].max(1)
+        blocks = blocks[np.any(
+            (x1[:, None] + GATE_SLACK >= b[:, 0])
+            & (x0[:, None] - GATE_SLACK <= b[:, 2])
+            & (y1[:, None] + GATE_SLACK >= b[:, 1])
+            & (y0[:, None] - GATE_SLACK <= b[:, 3]), axis=1)]
+    return blocks
+
+
+def gate_slabs(env: dict, blocks: np.ndarray, poly_env: np.ndarray,
+               ykey: np.ndarray, rise: np.ndarray, chunk: int) -> np.ndarray:
+    """The join's pairs, (k, 5) int64 [tile, first chunk, first segment in
+    it, past the last, polygon]: every tile of the candidate ``blocks`` (a block's rows in
+    ascending y, ``env``'s tiles; tile t of ``blocks[j]`` is j * tiles a
+    block + t) against every polygon whose envelope (``poly_env``: (P, 4)
+    f64) its own meets, with the span of that polygon's y-sorted segments
+    (``ykey``, ``rise``: geom_batch.slab_order) that can reach into the
+    tile's y-range, as the kernel reads it: whole chunks of ``chunk``
+    segments from the one that holds the span's first. In two steps, so that P polygons cost a tile x P test
+    only where the tile's block met the polygon."""
+    from geomesa_tpu.filter.geom_batch import KEY_ROW
+    if len(blocks) == 0 or len(poly_env) == 0:
+        return np.empty((0, 5), dtype=np.int64)
+    x0, x1 = env["xmin"][blocks] - GATE_SLACK, env["xmax"][blocks] + GATE_SLACK
+    y0, y1 = env["ymin"][blocks] - GATE_SLACK, env["ymax"][blocks] + GATE_SLACK
+    per = x0.shape[1]
+    px0, py0, px1, py1 = poly_env.T
+    j, p = np.nonzero((x1.max(1)[:, None] >= px0) & (x0.min(1)[:, None] <= px1)
+                      & (y1.max(1)[:, None] >= py0)
+                      & (y0.min(1)[:, None] <= py1))
+    tile = (j[:, None] * per + np.arange(per)).reshape(-1)
+    p = np.repeat(p, per)
+    x0, x1, y0, y1 = (a.reshape(-1) for a in (x0, x1, y0, y1))
+    keep = ((x1[tile] >= px0[p]) & (x0[tile] <= px1[p])
+            & (y1[tile] >= py0[p]) & (y0[tile] <= py1[p]))
+    tile, p = tile[keep], p[keep]
+    at = p * KEY_ROW + 90.0
+    lo = np.searchsorted(ykey, at + np.maximum(y0[tile] - rise[p], -90.0))
+    hi = np.searchsorted(ykey, at + np.minimum(y1[tile], 90.0), side="right")
+    keep = hi > lo
+    first = lo // chunk
+    return np.stack([tile, first, lo - first * chunk, hi - first * chunk, p],
+                    axis=1)[keep]

@@ -32,7 +32,7 @@ import numpy as np
 
 from geomesa_tpu import trace as _trace
 from geomesa_tpu.filter import ir
-from geomesa_tpu.index.device import SEG, WAY
+from geomesa_tpu.index.device import SEG, SEG_CHUNK, SEGY, WAY
 from geomesa_tpu.obs import attrib as _attrib
 from geomesa_tpu.obs import profiling as _prof
 
@@ -489,6 +489,51 @@ def _classify_segments(ax, ay, bx, by, edges, n_edges):
                       ~inside & ~punc]).astype(jnp.int32)
 
 
+def _sort_tiles(y, x, *payload):
+    """Rows of every block (the last axis) in ascending ``y``: (y, x,
+    payload...), sorted together. Stable, so the order follows from ``y``
+    alone: the join's kernel and the envelopes its gate reads
+    (``BaseSpatialIndex.join_envelopes``) sort each for itself and meet the
+    same tiles."""
+    from jax import lax
+    return lax.sort((y, x) + payload, dimension=1, is_stable=True, num_keys=1)
+
+
+def _classify_pairs(px, py, m, seg, box, pairs, width: int):
+    """One step of the grouped join: ``pairs`` (G, 5) int32 [tile, first
+    chunk, first segment in it, past the last, polygon row] of one edge
+    bucket (a pad has no segments and so no point), against the tiles'
+    points ``px``/``py``/``m`` (tiles, rows) → (G, rows) int32 flags, bit 0
+    a point surely inside its pair's polygon, bit 1 one the f32 band cannot
+    settle. A pair reads ``width`` segments of ``seg`` ((chunks, 4,
+    SEG_CHUNK)) from its first chunk on, whole chunks, those outside its own
+    span masked out: the slab of its polygon's edges, sorted by their lower
+    end, that can meet the tile's y-range, so the others neither cross a
+    point's ray nor tie its y. Edges
+    ride the second axis and are summed away (crossings in the low 16 bits,
+    uncertain edges above them): nothing of shape (pairs, edges, rows)
+    outlives the reduce. A point outside the polygon's envelope by more than
+    the inputs' rounding is surely outside whatever the slab says."""
+    slot, poly = pairs[:, 0], pairs[:, 4]
+    segs = seg[pairs[:, 1:2] + jnp.arange(width // SEG_CHUNK,
+                                          dtype=jnp.int32)[None, :]]
+    e = jnp.arange(width, dtype=jnp.int32)[None, :]
+    evalid = ((e >= pairs[:, 2:3]) & (e < pairs[:, 3:4]))[:, :, None]
+    x, y = px[slot][:, None, :], py[slot][:, None, :]
+    cross, unc = _pip_edge_band(
+        x, y, *(segs[:, :, k, :].reshape(-1, width, 1) for k in range(4)))
+    acc = jnp.sum((cross & evalid).astype(jnp.int32)
+                  + ((unc & evalid).astype(jnp.int32) << 16), axis=1)
+    x, y = x[:, 0], y[:, 0]
+    b = box[:, poly]
+    near = ((x >= b[0][:, None] - _BOX_GAP) & (x <= b[2][:, None] + _BOX_GAP)
+            & (y >= b[1][:, None] - _BOX_GAP) & (y <= b[3][:, None] + _BOX_GAP)
+            & m[slot])
+    settled = (acc >> 16) == 0
+    return ((near & settled & ((acc & 1) == 1)).astype(jnp.int32)
+            | ((near & ~settled).astype(jnp.int32) << 1))
+
+
 def _running_sum(x):
     """Inclusive running sum over the last two axes taken as one: within a
     row, plus the rows before it."""
@@ -683,7 +728,7 @@ class ScanKernels:
                 return out, jnp.sum(m)
         elif mode in ("count_blocks", "count_multi_blocks", "select_blocks",
                       "density_blocks", "topk_blocks",
-                      "intersects_band_blocks"):
+                      "intersects_band_blocks", "join_band_blocks"):
             # range-pruned gather scan: block ids (pad = -1) expand to row
             # indices with an iota, candidate rows gather from HBM, and the
             # FULL exact mask re-applies — so the host cover only needs to be
@@ -815,6 +860,111 @@ class ScanKernels:
                         jnp.stack([jnp.sum(hit), jnp.sum(unc),
                                    jnp.sum(m)]).astype(jnp.int32),
                         rows.astype(jnp.int32)])
+            elif mode == "join_band_blocks":
+                # grouped point-in-polygon: the candidate blocks' points
+                # against every polygon slab the gate paired them with, in
+                # one launch. A block's rows are sorted by y and cut into
+                # tiles of JOIN_TILE (y-narrow, so a tile meets few of a
+                # polygon's edges); a pair is a tile against the segments of
+                # one polygon, read from ``seg`` (the polygon type's
+                # y-sorted twin of its pool), that can meet the tile's
+                # y-range. Pairs come sorted by the bucket their segment
+                # count falls in (``JOIN_WIDTHS``; ``n_pairs``: a bucket's
+                # first pair and its count; padded to whole turns); a bucket
+                # runs JOIN_STEP_EDGES / width pairs a turn for as many
+                # turns as it has pairs, so the shapes are the tier's and
+                # the work the request's.
+                # Out: per pair [points surely inside, points uncertain,
+                # (low 16 bits, high bits) of each stat's sum over the
+                # former], the rows of a tile the filter passed, the
+                # uncertain (pair, point) couples compacted to ``unc_cap``
+                # (their pairs, then their rows), and the time
+                # band: how many rows pass the plan's windows and not the
+                # ``strict`` ones (a window's end inside an offset unit),
+                # and the first JOIN_TIME_CAP of them. Those are in no
+                # tile's count: the host has their milliseconds.
+                unc_cap, pair_cap, stat_cols = capacity[3:]
+                k_out = 2 + 2 * len(stat_cols)
+                tsz = min(JOIN_TILE, bsz)
+                wide = -(-bsz // tsz) * tsz      # a block padded to tiles
+
+                def run(cols, boxes, windows, rparams, strict, seg, box,
+                        perm, block_ids, pairs, n_pairs):
+                    from jax import lax
+                    valid, rowids, g = expand_blocks(cols, block_ids)
+                    # the rows the host gets are the table's where the
+                    # index keeps its permutation on the device: a gather
+                    # here, not a round trip behind the next join's launch
+                    at_table = (lambda r: r) if perm is None else (
+                        lambda r: perm[jnp.clip(r, 0, n - 1)])
+                    loose = mask_fn(g, boxes, windows, rparams,
+                                    residual_fn) & valid
+                    m = loose & _time_mask(g, strict) if has_time else loose
+                    band = (loose & ~m).reshape(nblk, bsz)
+                    box = jnp.stack(box)
+                    blocked = lambda a, fill: jnp.pad(
+                        a.reshape(nblk, bsz), ((0, 0), (0, wide - bsz)),
+                        constant_values=fill)
+                    # rows that are none of the block's sort last; a row
+                    # the filter dropped keeps its place, marked
+                    y = blocked(jnp.where(valid, g["yf"], jnp.inf), jnp.inf)
+                    marked = jnp.where(m, rowids, -1 - rowids)
+                    py, px, marked, *vals = _sort_tiles(
+                        y, blocked(g["xf"], 0.0), blocked(marked, -1),
+                        *(blocked(g[k], 0) for k in stat_cols))
+                    py, px, marked = (a.reshape(-1, tsz)
+                                      for a in (py, px, marked))
+                    vals = [v.reshape(-1, tsz) for v in vals]
+                    passed = marked >= 0
+                    out = jnp.zeros((pair_cap, k_out), jnp.int32)
+                    open_ = jnp.zeros((pair_cap, tsz), bool)
+                    for b, width in enumerate(JOIN_WIDTHS):
+                        group = JOIN_STEP_EDGES // width
+                        first, count = n_pairs[b, 0], n_pairs[b, 1]
+
+                        def step(i, carry, width=width, group=group,
+                                 first=first):
+                            out, open_ = carry
+                            at = first + i * group
+                            pr = lax.dynamic_slice(pairs, (at, 0), (group, 5))
+                            f = _classify_pairs(px, py, passed, seg, box, pr,
+                                                width)
+                            inside = (f & 1) == 1
+                            rows = [jnp.sum(inside, axis=1),
+                                    jnp.sum(f >> 1, axis=1)]
+                            for v in vals:
+                                v = jnp.where(inside, v[pr[:, 0]], 0)
+                                rows += [jnp.sum(v & 0xFFFF, axis=1),
+                                         jnp.sum(v >> 16, axis=1)]
+                            return (lax.dynamic_update_slice(
+                                        out, jnp.stack(rows, axis=1)
+                                        .astype(jnp.int32), (at, 0)),
+                                    lax.dynamic_update_slice(
+                                        open_, (f >> 1) == 1, (at, 0)))
+
+                        out, open_ = lax.fori_loop(
+                            0, -(-count // group), step, (out, open_))
+                    # the j-th uncertain couple: the pair whose running
+                    # count of them first reaches j + 1, and the point of
+                    # its tile whose own does
+                    upto = jnp.cumsum(out[:, 1])
+                    j = jnp.arange(unc_cap, dtype=jnp.int32)
+                    at = jnp.clip(jnp.searchsorted(upto, j + 1, side="left"),
+                                  0, pair_cap - 1)
+                    nth = j - (upto[at] - out[at, 1])
+                    lane = jnp.argmax(jnp.cumsum(
+                        open_[at].astype(jnp.int32), axis=1)
+                        == nth[:, None] + 1, axis=1)
+                    row = marked[pairs[at, 0], lane]
+                    late = _first_set(band, JOIN_TIME_CAP)
+                    return jnp.concatenate([
+                        out.reshape(-1),
+                        jnp.sum(passed, axis=1).astype(jnp.int32),
+                        at.astype(jnp.int32),
+                        at_table(row).astype(jnp.int32),
+                        jnp.sum(band)[None].astype(jnp.int32),
+                        at_table(rowids[jnp.clip(
+                            late, 0, nblk * bsz - 1)]).astype(jnp.int32)])
             elif mode == "density_blocks":
                 # pruned heat-map: candidate blocks gather (contiguous HBM
                 # bursts) + masked scatter of only nb*block_size rows
@@ -1191,6 +1341,103 @@ class ScanKernels:
         return certain, np.concatenate(
             [o[3: 3 + int(o[1])] for o in outs]).astype(np.int64), facts
 
+    def join_band_blocks(self, primary_kind, boxes, windows, residual,
+                         strict, seg, box, perm, blocks: np.ndarray,
+                         block_size: int, pairs: np.ndarray,
+                         stat_cols: tuple):
+        """The grouped point-in-polygon join over candidate ``blocks``
+        (sorted unique ids) and the gate's ``pairs`` (k, 5) [tile, first
+        chunk, first segment in it, past the last, polygon row]: a tile is
+        ``JOIN_TILE`` rows of a block in ascending y, counted through
+        ``blocks`` (tile t of the j-th block is j * tiles-a-block + t); the
+        segments are a span of ``seg``, the polygon type's y-sorted segment
+        plane, that ends within ``POOL_TILE`` of its first chunk; ``box``: that type's four f32 envelope planes. ``strict``: the
+        plan's windows cut to whole offset units (``time_windows``); a row
+        between the two is in no pair's numbers. ``perm``: the index's
+        permutation on the device, or None where the host has it; with it
+        the rows returned are the table's, without it this index's. One
+        launch for up to ``JOIN_MAX_BLOCKS`` blocks, whatever the number of
+        polygons.
+
+        Returns (inside, open, sums, rows, uncertain, late, launches): per
+        pair the points surely inside and the points the f32 band left open,
+        (stats, pairs) int64 sums of ``stat_cols`` over the former (widened
+        here from the device's 16-bit halves), per pair the rows of its tile
+        that the filter passed, the open couples as an (u, 2) array [pair,
+        row], or None where a launch's exceeded
+        ``JOIN_UNC_CAP``, and the rows between the two sets of windows, or
+        None where a launch's exceeded ``JOIN_TIME_CAP``."""
+        rp = [jnp.asarray(p) for p in residual[1]] if residual else []
+        dbx, dw, ds = _dev(boxes), _dev(windows), _dev(strict)
+        k_out = 2 + 2 * len(stat_cols)
+        tsz = min(JOIN_TILE, block_size)
+        per = -(-block_size // tsz)
+        out = np.zeros((len(pairs), k_out), dtype=np.int64)
+        rows = np.zeros(len(pairs), dtype=np.int64)
+        bucket = np.searchsorted(JOIN_WIDTHS, pairs[:, 3])
+        group = JOIN_STEP_EDGES // np.asarray(JOIN_WIDTHS)
+        uncertain, late, launches = [], [], 0
+        for lo in range(0, len(blocks), JOIN_MAX_BLOCKS):
+            chunk = blocks[lo: lo + JOIN_MAX_BLOCKS]
+            mine = np.flatnonzero((pairs[:, 0] >= lo * per)
+                                  & (pairs[:, 0] < (lo + len(chunk)) * per))
+            mine = mine[np.argsort(bucket[mine], kind="stable")]
+            # a bucket's pairs, then pads up to a whole turn of its loop
+            count = np.bincount(bucket[mine], minlength=len(group))
+            room = -(-count // group) * group
+            first = np.cumsum(room) - room
+            at = np.arange(len(mine)) + np.repeat(
+                first - (np.cumsum(count) - count), count)
+            nb = join_tier(len(chunk), 64, 4)
+            cap = join_tier(int(room.sum()), 1 << 14, 16)
+            ids = np.full(nb, -1, dtype=np.int32)
+            ids[: len(chunk)] = chunk
+            sent = np.zeros((cap, 5), dtype=np.int32)
+            sent[at] = pairs[mine]
+            sent[at, 0] -= lo * per
+            spans = np.stack([first, count], axis=1).astype(np.int32)
+            fn = self._get("join_band_blocks", primary_kind,
+                           windows is not None,
+                           residual[0] if residual else "none",
+                           residual[2] if residual else None,
+                           0 if boxes is None else boxes.shape[0],
+                           0 if windows is None else windows.shape[0],
+                           (nb, block_size, 0, JOIN_UNC_CAP, cap,
+                            tuple(stat_cols)))
+            with _attrib.kernel(f"join_band_blocks.{primary_kind}", nb):
+                got = np.asarray(_fetch(
+                    fn, self.cols, dbx, dw, rp, ds, seg, box, perm,
+                    jnp.asarray(ids),
+                    jnp.asarray(sent), jnp.asarray(spans)))
+            launches += 1
+            body = cap * k_out
+            res = got[:body].reshape(cap, k_out)[at]
+            out[mine] = res
+            rows[mine] = got[body:][sent[at, 0]]
+            tail = got[body + nb * per + 2 * JOIN_UNC_CAP:]
+            if late is None or int(tail[0]) > JOIN_TIME_CAP:
+                late = None
+            else:
+                late.append(tail[1: 1 + int(tail[0])].astype(np.int64))
+            if uncertain is None or int(res[:, 1].sum()) > JOIN_UNC_CAP:
+                uncertain = None
+                continue
+            n_open = int(res[:, 1].sum())
+            back = np.empty(cap, dtype=np.int64)    # a sent pair's own
+            back[at] = mine
+            pair = got[body + nb * per:][: n_open]
+            row = got[body + nb * per + JOIN_UNC_CAP:][: n_open].astype(
+                np.int64)
+            uncertain.append(np.stack([back[pair], row], axis=1))
+        sums = (out[:, 3::2] << 16) + out[:, 2::2]
+        if uncertain is not None:
+            uncertain = np.concatenate(uncertain) if uncertain \
+                else np.empty((0, 2), dtype=np.int64)
+        if late is not None:
+            late = np.concatenate(late) if late \
+                else np.empty(0, dtype=np.int64)
+        return out[:, 0], out[:, 1], sums.T, rows, uncertain, late, launches
+
     def topk_nearest_blocks(self, primary_kind, boxes, windows, residual,
                             qx: float, qy: float, m: int,
                             blocks: np.ndarray, block_size: int):
@@ -1292,6 +1539,35 @@ BAND_MIN_EDGES = 16
 # candidate blocks one launch of the banded refine takes: bounds its
 # temporaries (some 50 B a segment) and the tiers it can meet
 BAND_MAX_BLOCKS = 256
+
+
+# the grouped join (``join_band_blocks``). The rows of a gather block, sorted
+# by y, are cut into tiles of JOIN_TILE: a tile of a Z-ordered block spans
+# tens of degrees of x and under one of y, so of a polygon of 1,000 edges it
+# can meet the ~100 whose y-range reaches into its own, and those are one
+# span of the polygon's segments sorted by their lower end (``SEGY``). A
+# pair reads the bucket of JOIN_WIDTHS at or over its span (``POOL_TILE`` at
+# most: the pool's pad); a turn of the kernel's loop takes JOIN_STEP_EDGES /
+# width pairs.
+JOIN_TILE = 128
+JOIN_WIDTHS = (64, 128, 256, 512, 1024, 2048, 4096)
+JOIN_STEP_EDGES = 1 << 16
+JOIN_MAX_BLOCKS = 1 << 10    # blocks a launch: 4.2M rows
+JOIN_UNC_CAP = 1 << 15       # uncertain (point, polygon) couples a launch
+JOIN_TIME_CAP = 1 << 12      # rows of the windows' boundary units a launch
+
+
+def join_tier(n: int, floor: int, step: int) -> int:
+    """Padded length of a join launch's block list (64, 256, 1,024) and of
+    its pair list (16,384, 262,144, ...): ``floor`` times a power of
+    ``step``, wide enough apart that the 1.0-3.3M rows of a week or two of
+    events, and their 45,000-150,000 pairs with 256 polygons, are one
+    program."""
+    tier = floor
+    while tier < n:
+        tier *= step
+    return tier
+
 
 
 def band_launches(blocks: np.ndarray, block_size: int, seg_off: np.ndarray,

@@ -24,7 +24,8 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from geomesa_tpu.curves.binnedtime import TimePeriod, max_offset, time_to_binned_time
+from geomesa_tpu.curves.binnedtime import (TimePeriod, binned_time_to_millis,
+                                           max_offset, time_to_binned_time)
 from geomesa_tpu.curves.normalize import NormalizedLat, NormalizedLon
 from geomesa_tpu.curves.sfc import Z2SFC, Z3SFC
 from geomesa_tpu.curves.xz import XZ2SFC, XZ3SFC
@@ -840,14 +841,7 @@ class BaseSpatialIndex:
 
         windows = None
         if iv is not None and not iv.unconstrained:
-            w = np.empty((len(iv.intervals), 4), dtype=np.int32)
-            i32 = (1 << 31) - 1  # open-ended intervals overflow the bin i32
-            for i, (lo, hi) in enumerate(iv.intervals):
-                blo, olo = time_to_binned_time(lo, self.period)
-                bhi, ohi = time_to_binned_time(hi, self.period)
-                w[i] = (max(-i32, int(blo)), int(olo),
-                        min(i32, int(bhi)), int(ohi))
-            windows = pad_windows(w)
+            windows = self.time_windows(iv.intervals)
 
         avail = set(self.device.columns)
         dev_res, host_res = split_residual(residual, self.sft, self.vocabs,
@@ -870,6 +864,27 @@ class BaseSpatialIndex:
                      "residual_device": dev_res, "residual_host": host_res},
         )
 
+    def time_windows(self, intervals, strict: bool = False) -> np.ndarray:
+        """(T, 4) int32 [bin_lo, off_lo, bin_hi, off_hi] device windows of
+        inclusive epoch-ms ``intervals``, padded. The device keeps a row's
+        time as (bin, offset) in the period's offset unit (a second, for
+        weeks), so an end that falls inside a unit cannot be told on the
+        device: the plan's windows hold that whole unit, a superset, and the
+        ``strict`` ones only the units that lie wholly inside the interval,
+        a subset. A caller that has to be exact at such an end takes the
+        rows between the two to the host (planner.join_polygons)."""
+        w = np.empty((len(intervals), 4), dtype=np.int32)
+        i32 = (1 << 31) - 1  # open-ended intervals overflow the bin i32
+        for i, (lo, hi) in enumerate(intervals):
+            blo, olo = (int(v) for v in time_to_binned_time(lo, self.period))
+            bhi, ohi = (int(v) for v in time_to_binned_time(hi, self.period))
+            if strict and abs(blo) < i32 and abs(bhi) < i32:
+                olo += int(binned_time_to_millis(blo, olo, self.period)) < lo
+                ohi -= int(binned_time_to_millis(bhi, ohi + 1,
+                                                 self.period)) - 1 > hi
+            w[i] = (max(-i32, blo), olo, min(i32, bhi), ohi)
+        return pad_windows(w)
+
     def _cost(self, ext, iv) -> float:
         """Heuristic strategy cost (≙ StrategyDecider index heuristics —
         lower is better; spatio-temporal beats spatial beats full scan)."""
@@ -891,11 +906,15 @@ class BaseSpatialIndex:
         (scan.intersects_band_blocks); ``seg_off`` stays on the host too,
         where a query's blocks become spans of the pool. Extent indexes
         only. Built whole at every build and merge: an append to an extent
-        type uploads the pool again."""
+        type uploads the pool again. A polygon type's gets a twin,
+        ``__segy__``, each row's segments in the order of their lower end,
+        for the join (scan.join_band_blocks), with the host's search keys
+        ``seg_ykey`` and each row's tallest segment ``seg_rise``."""
         self.seg_off = None
         if self.points or self.geom is None:
             return
-        from geomesa_tpu.index.device import segment_pool
+        from geomesa_tpu.index.device import (SEG, SEGY, segment_pool,
+                                              segments_by_y)
         from geomesa_tpu.index.scan import POOL_TILE
         from geomesa_tpu.obs.profiling import PROGRESS as _progress
         with _progress.phase("segment_pool", rows=len(self.table),
@@ -904,6 +923,71 @@ class BaseSpatialIndex:
             if built is not None:
                 planes, self.seg_off = built
                 self.device.columns.update(planes)
+                if self.sft.geometry_attribute.type_name in (
+                        "Polygon", "MultiPolygon"):
+                    # a polygon type can be a join's polygon side
+                    self.device.columns[SEGY], self.seg_ykey, self.seg_rise \
+                        = segments_by_y(planes[SEG], self.seg_off)
+
+    def join_envelopes(self, block: int, tile: int) -> dict:
+        """Host copies of what the join's gate reads of this point index
+        (prune.gate_slabs), reduced on the device once an index and kept.
+        Per ``block``-row run, in row order: ``tmin``/``tmax``, its first and
+        last instant as bin * 2^32 + offset (a run that straddles two bins
+        holds all of both; a temporal index only). Per tile, ``tile`` rows of
+        a run in ascending y as the join's kernel sorts them
+        (scan._sort_tiles), (runs, tiles a run): ``xmin``/``xmax``/``ymin``/
+        ``ymax``, the f32 planes' own extremes, an empty tile's +inf/-inf."""
+        cached = getattr(self, "_join_env", None)
+        if cached is not None and cached[0] == (block, tile):
+            return cached[1]
+        import jax.numpy as jnp
+        from geomesa_tpu.index.scan import _sort_tiles
+        cols = self.device.columns
+        n = int(cols["xf"].shape[0])
+        nb = -(-n // block)
+        wide = -(-block // tile) * tile
+        valid = cols.get("__valid__")
+
+        def blocked(name, fill, width=block):
+            c = cols[name]
+            if valid is not None:
+                c = jnp.where(valid, c, fill)
+            c = jnp.pad(c, (0, nb * block - n), constant_values=fill)
+            return jnp.pad(c.reshape(nb, block), ((0, 0), (0, width - block)),
+                           constant_values=fill)
+
+        y, x = _sort_tiles(blocked("yf", np.inf, wide),
+                           blocked("xf", 0.0, wide))
+        real = (y < np.inf).reshape(nb, -1, tile)
+        y, x = y.reshape(nb, -1, tile), x.reshape(nb, -1, tile)
+        ext = {"ymin": jnp.min(jnp.where(real, y, np.inf), axis=2),
+               "ymax": jnp.max(jnp.where(real, y, -np.inf), axis=2),
+               "xmin": jnp.min(jnp.where(real, x, np.inf), axis=2),
+               "xmax": jnp.max(jnp.where(real, x, -np.inf), axis=2)}
+        if "bin" in cols:
+            i31 = (1 << 31) - 1
+            for name, plane in (("b", "bin"), ("o", "off")):
+                ext[name + "min"] = jnp.min(blocked(plane, i31), axis=1)
+                ext[name + "max"] = jnp.max(blocked(plane, -i31), axis=1)
+        env = {k: np.asarray(v) for k, v in ext.items()}
+        if "bin" in cols:
+            one = env["bmin"] == env["bmax"]
+            lo = np.where(one, env.pop("omin").astype(np.int64), 0)
+            hi = np.where(one, env.pop("omax").astype(np.int64),
+                          (1 << 32) - 1)
+            env["tmin"] = (env.pop("bmin").astype(np.int64) << 32) + lo
+            env["tmax"] = (env.pop("bmax").astype(np.int64) << 32) + hi
+        self._join_env = ((block, tile), env)
+        return env
+
+    def polygon_envelopes(self) -> np.ndarray:
+        """(rows, 4) f64 [xmin, ymin, xmax, ymax] of this extent index's
+        features in its row order, kept on the host for the join's gate."""
+        cached = getattr(self, "_row_env", None)
+        if cached is None:
+            cached = self._row_env = self.table.geometry().bboxes()[self.perm]
+        return cached
 
     # range pruning ---------------------------------------------------------
 

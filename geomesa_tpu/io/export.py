@@ -72,6 +72,24 @@ def export(table: FeatureTable, fmt: str, path: Optional[str] = None):
     raise ValueError(f"Unknown export format {fmt!r} (have {FORMATS})")
 
 
+def join_answer(op: str, polygons: FeatureTable, counts, sums: dict) -> dict:
+    """The body of a join: a row a feature of the polygon table, in its
+    order, a polygon that matched nothing with zeros. ``counts``: (P,)
+    integers; ``sums``: attribute → (P,) integers."""
+    name = polygons.columns.get("name")
+    if isinstance(name, StringColumn):
+        vocab = name.vocab
+        name = [vocab[c] if c >= 0 else None
+                for c in np.asarray(name.codes).tolist()]
+    rows = []
+    for i, (fid, n) in enumerate(zip(polygons.fids,
+                                     np.asarray(counts).tolist())):
+        rows.append({"fid": fid, "name": None if name is None else name[i],
+                     "count": n,
+                     "sum": {a: int(v[i]) for a, v in sums.items()}})
+    return {"op": op, "polygons": len(rows), "rows": rows}
+
+
 def _out(path: Optional[str]):
     return open(path, "w", newline="") if path else io.StringIO()
 

@@ -28,6 +28,15 @@ Routes:
   GET  /types/{t}/count?cql=           → {"count": n}  (concurrent requests
                                          coalesce through the micro-batching
                                          scheduler, serve/scheduler.py)
+  GET  /types/{t}/join?with={p}&op=st_intersects|st_contains&cql=
+       &stats=count,sum(attr),...      → the points of {t} that pass cql,
+                                         grouped by the polygons of type {p}
+                                         that hold them: {"op", "polygons",
+                                         "rows": [{"fid", "name", "count",
+                                         "sum": {attr: n}}, ...]}, a row a
+                                         polygon in {p}'s order, exact; one
+                                         grouped device launch a join
+                                         (datastore.join)
   GET  /types/{t}/explain?cql=&analyze=1 → query plan JSON (+ dry-run trace
                                          tree; analyze=1 EXECUTES the plan
                                          and annotates spans with device ms
@@ -572,6 +581,13 @@ class GeoJsonApi:
                                  "columns": cols}
                 from geomesa_tpu.io.export import export
                 return 200, json.loads(export(res.table, "geojson"))
+            if rest == ["join"] and method == "GET":
+                if "with" not in query:
+                    return 400, {"error": "missing ?with=<polygon type>"}
+                return 200, self.store.join(
+                    t, query["with"][0],
+                    op=query.get("op", ["st_intersects"])[0], f=cql,
+                    stats=query.get("stats", ["count"])[0], auths=auths)
             if rest == ["features"] and method == "POST":
                 fc = json.loads(body or b"{}")
                 n = self._ingest_geojson(t, fc)
@@ -682,12 +698,13 @@ class GeoJsonApi:
 
 
 def _route_family(path: str) -> str:
-    """``count`` for /types/{t}/count, else the first path word: the name
-    an ``http.request.*`` timer carries, so that polls of /metrics and
-    /events never dilute the count route's."""
+    """``count`` for /types/{t}/count, ``join`` for /types/{t}/join, else
+    the first path word: the name an ``http.request.*`` timer carries, so
+    that polls of /metrics and /events never dilute a measured route's."""
     parts = [p for p in path.split("/") if p]
-    if len(parts) == 3 and parts[0] == "types" and parts[2] == "count":
-        return "count"
+    if len(parts) == 3 and parts[0] == "types" \
+            and parts[2] in ("count", "join"):
+        return parts[2]
     return re.sub(r"\W", "_", parts[0]) if parts else "root"
 
 
