@@ -2,8 +2,9 @@
 
 layer: planner, cover (index/prune.py, curves/) · source: program_counter
 moves: qps
-``sched.stage.cover``: the cover-cache look-up and, on a miss, the range
-decomposition of every planned request."""
+``sched.stage.cover``: one cover a group of the cycle (``_cover_group``: the
+range decomposition of all the group's boxes together and their candidate
+blocks); a lone repeated plan keeps its own and costs nothing here."""
 
 import os
 import sys

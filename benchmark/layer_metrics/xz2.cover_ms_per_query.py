@@ -2,11 +2,11 @@
 
 layer: planner, cover (curves/xz.py, index/prune.py) · source: program_counter
 moves: p50_ms
-Seconds the timer ``range_decompose`` gained (a lone plan's cover: the
-breadth-first walk of ``XZSFC.ranges`` in Python and the ranges' search in
-the sorted codes, on the completer thread) over the observations
-``query.count`` gained, ``before`` → ``after``. A program without the
-timer reads None."""
+Seconds the timer ``range_decompose`` gained (a lone plan's cover:
+``XZSFC.ranges_arrays``, a level of the tree a numpy step since PR 31, and
+the ranges' search in the sorted codes, on the completer thread) over the
+observations ``query.count`` gained, ``before`` → ``after``. A program
+without the timer reads None."""
 
 import os
 import sys

@@ -35,6 +35,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 SNAPSHOTS = ("/healthz", "/metrics", "/scheduler")
 TRACE_SLICE_S = 3.0   # a trace of the whole window is too large to reduce
+TRACER_LEAD_S = 0.25  # longer than any program of a cell runs on the device
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
@@ -173,42 +174,66 @@ def set_up(config: dict, data, corpus: dict, rehearsal: bool):
 
 def trace_slice(jax, port: int, seconds: float):
     """Profile TRACE_SLICE_S of the window, a second in; returns the trace
-    directory, the slice on both clocks and its batched dispatches."""
+    directory, the slice as the host marks it and the batched dispatches the
+    recorder held as it closed. The marks are Unix nanoseconds, the clock the
+    profiler session dates its collection by: they lie inside the collection,
+    and the trace places them among the device's operations."""
     tdir = tempfile.mkdtemp(prefix="geomesa_bench_trace_")
     time.sleep(min(1.0, max(0.0, seconds - TRACE_SLICE_S)))
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     opts.host_tracer_level = 0
     jax.profiler.start_trace(tdir, profiler_options=opts)
-    lo, wall_lo = time.perf_counter(), time.time() * 1000
+    on = time.time_ns()
+    # the device's tracer records a program from its launch on: the one in
+    # flight as the tracer comes on is not in the trace (a join's 60-80 ms
+    # read as idle time; PERF.md, PR 34), so the slice opens when that one
+    # has ended
+    time.sleep(TRACER_LEAD_S)
+    lo = time.time_ns()
     time.sleep(min(TRACE_SLICE_S, seconds))
-    hi, wall_hi = time.perf_counter(), time.time() * 1000
+    hi = time.time_ns()
     # the recorder's ring holds some seconds of events and stop_trace may
-    # take as long: fetch the slice's dispatches while it runs
+    # take ten times as long: fetch the slice's dispatches while it runs
     fetched = []
-    fetch = threading.Thread(target=lambda: fetched.append(http_json(
+    fetch = threading.Thread(target=lambda: fetched.extend(http_json(
         port, "/events?kind=batch&limit=100000")["events"]))
     fetch.start()
     jax.profiler.stop_trace()
     fetch.join()
-    say(f"phase stop_trace: {time.perf_counter() - hi:.3f} s")
-    events = [e for e in (fetched[0] if fetched else [])
-              if wall_lo <= e["ts_ms"] <= wall_hi]
-    return tdir, lo, hi, events
+    say(f"phase stop_trace: {(time.time_ns() - hi) / 1e9:.3f} s; the tracer "
+        f"was on from Unix ns {on}")
+    return tdir, (lo, hi), fetched
 
 
-def reduce_trace(tdir: str, window_s: float):
+def reduce_trace(tdir: str, marks: tuple, keep: str):
+    """The slice reduced on the trace's own clock (trace_reduce), or None and
+    one line where the profiler wrote no trace, or one that does not place
+    the marks: a share of a window that is only the host's would put busy
+    time and window on two clocks. ``keep`` names a directory the
+    ``.xplane.pb`` is copied to."""
     sys.path.insert(0, HERE)
     import trace_reduce
     xplanes = [os.path.join(d, f) for d, _, fs in os.walk(tdir)
                for f in fs if f.endswith(".xplane.pb")]
-    trace = trace_reduce.reduce_file(xplanes[0], window_s) if xplanes else None
+    trace = None
+    try:
+        if not xplanes:
+            raise ValueError("the profiler wrote no .xplane.pb")
+        if keep:
+            os.makedirs(keep, exist_ok=True)
+            shutil.copy(xplanes[0], keep)
+        trace = trace_reduce.reduce_file(xplanes[0], marks=marks)
+    except ValueError as e:
+        print(f"run.py: {e}", file=sys.stderr)
     shutil.rmtree(tdir, ignore_errors=True)
-    if trace is None or trace["busy_s"] <= 0:
-        print("run.py: the trace shows no operation on the device",
-              file=sys.stderr)
-        return None
-    say(f"trace: {json.dumps(trace['planes'])} slice {window_s:.3f} s")
+    if trace:
+        start, stop = trace["span_unix_s"]
+        say(f"trace: {json.dumps(trace['planes'])} window "
+            f"{trace['window_s']:.6f} s, Unix ns {marks[0]}..{marks[1]}; the "
+            f"profiler's collection opened {1e3 * (marks[0] / 1e9 - start):.3f}"
+            f" ms before it and closed {1e3 * (stop - marks[1] / 1e9):.3f} ms "
+            f"after it")
     return trace
 
 
@@ -250,6 +275,9 @@ def main(argv=None) -> int:
                    help="put this control of the data module in the "
                         "program's place when the answers are compared; "
                         "`correct` then has to read false")
+    p.add_argument("--keep-trace", default="",
+                   help="copy the traced run's .xplane.pb into this "
+                        "directory, to reduce it again by hand")
     p.add_argument("--rehearse-rows", type=int, default=0,
                    help="run at this many rows on any backend; prints no "
                         "metric under its name")
@@ -285,8 +313,8 @@ def main(argv=None) -> int:
     setup_s = t0 - T_PROCESS
     t1 = t0 + args.seconds
     if args.trace:
-        tdir, slice_lo, slice_hi, batch_events = trace_slice(
-            jax, port, args.seconds)
+        tdir, marks, batch_events = trace_slice(jax, port, args.seconds)
+        unix_less_perf = time.time() - time.perf_counter()
     time.sleep(max(0.0, t1 - time.perf_counter()))
     after = snapshot(port)
     records, still_out = running.stop()
@@ -364,12 +392,24 @@ def main(argv=None) -> int:
 
     metrics, trace = {}, None
     if args.trace:
-        trace = reduce_trace(tdir, slice_hi - slice_lo)
-        if trace is None and not rehearsal:
+        trace = reduce_trace(tdir, marks, args.keep_trace)
+        if trace is None:
             return 1
-        if trace:
+        # one slice for every reader, the one the trace placed: in Unix time
+        # for ts_ms, on the window's clock for the requests
+        unix_lo, unix_hi = marks[0] / 1e9, marks[1] / 1e9
+        slice_lo, slice_hi = unix_lo - unix_less_perf, unix_hi - unix_less_perf
+        batch_events = [e for e in batch_events
+                        if 1e3 * unix_lo <= e["ts_ms"] <= 1e3 * unix_hi]
+        if trace["busy_s"] > 0:
             device["busy_s"] = trace["busy_s"]
             device["window_s"] = trace["window_s"]
+        else:
+            print("run.py: the trace shows no operation on the device inside "
+                  "the slice", file=sys.stderr)
+            if not rehearsal:
+                return 1
+            trace = None
         # all that was read, for whatever reader a later PR adds: times are
         # seconds from the window's opening
         ctx = {"cell": cell, "config": config, "traffic": traffic,
@@ -406,6 +446,11 @@ def main(argv=None) -> int:
         line["rehearsal"] = metrics
     if args.control:
         line["control"] = args.control
+    if trace and not 0 < device["busy_s"] <= device["window_s"]:
+        print(f"run.py: device.busy_s {device['busy_s']!r} is not above 0 "
+              f"and at most device.window_s {device['window_s']!r}",
+              file=sys.stderr)
+        return 1
     if trace:
         line["breakdown"] = {"device_ops": trace["device_ops"],
                              "idle_gaps": trace["idle_gaps"]}

@@ -104,7 +104,7 @@ def test_every_new_reader_has_its_entry():
     for name in NEW:
         m = entries[name]
         assert m["source"] == "program_counter"
-        assert m["workloads"] == ["gdelt-z3-10m.count-c64"]
+        assert "gdelt-z3-10m.count-c64" in m["workloads"]
         assert m["moves"] in ("qps", "p50_ms")
         assert os.path.exists(os.path.join(HERE, "layer_metrics",
                                            name + ".py"))

@@ -58,11 +58,42 @@ def parent_ctx(cell_name: str, pages: dict) -> dict:
             "requests": [(0.1, 0.2, True)]}
 
 
-def test_the_new_readers_are_the_ones_the_issue_names():
-    assert {m["name"] for m in NEW_READERS} == ADDED | {
-        "refine.host_ms_per_query", "xz2.cover_ms_per_query",
-        "select.serialize_ms_per_query"}
-    assert all(m["workloads"] in ([OSM], [SELECT]) for m in NEW_READERS)
+# what BENCHMARK.json lists cell by cell (PR 34): an entry may be added to a
+# cell by any PR; one that is here may not lose its cell or its reader
+SCHED = {"sched.batch_mean", "sched.queue_wait_ms",
+         "sched.collector.idle_pct", "sched.collector.plan_pct",
+         "sched.collector.cover_pct", "sched.collector.prepare_pct",
+         "sched.collector.offcpu_pct", "sched.resolve_ms_per_dispatch",
+         "sched.inflight_pct"}
+LISTED = {
+    "gdelt-z3-10m.count-c64": SCHED | {
+        "count_multi_blocks_roofline", "device.busy_ms_per_query",
+        "device.idle_pct", "device.hbm_peak_gb", "rest.self_ms_per_query"},
+    OSM: ADDED - {"select.rows_per_query"} | {
+        "refine.host_ms_per_query", "xz2.cover_ms_per_query"},
+    SELECT: {"select.serialize_ms_per_query", "select.rows_per_query",
+             "select.device_ms_per_query",
+             "select.blocks_gathered_per_query"},
+    "gdelt-countries-10m.join-c4": {
+        "join.device_ms_per_query", "join.host_ms_per_query",
+        "join.edge_tests_per_point", "join.uncertain_pct",
+        "join_pip_roofline"},
+    "gdelt-z3-10m.count-windows-c64": SCHED | {"sched.dispatches_per_cycle"},
+}
+
+
+def test_every_cell_of_the_benchmark_is_held():
+    assert {c["name"] for c in BENCH["workloads"]} >= set(LISTED)
+
+
+@pytest.mark.parametrize("cell", sorted(LISTED))
+def test_the_new_readers_are_the_ones_the_issue_names(cell):
+    """Every entry the benchmark listed for the cell is still listed for it,
+    under a reader of its own that run.py can load."""
+    listed = {m["name"] for m in BENCH["per_layer"] if cell in m["workloads"]}
+    assert LISTED[cell] <= listed, sorted(LISTED[cell] - listed)
+    for name in sorted(LISTED[cell]):
+        assert callable(run.load_module("layer_metrics", name).read), name
 
 
 @pytest.mark.parametrize("recorded", sorted(PARENT))

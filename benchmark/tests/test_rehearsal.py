@@ -55,15 +55,59 @@ def test_traced_rehearsal_reads_the_counters(capsys):
     assert "device.idle_pct" not in line["rehearsal"]
 
 
+@pytest.mark.parametrize("busy_s, rc", [(3.000001, 1), (3.0, 0)])
+def test_no_line_says_busier_than_its_window(capsys, monkeypatch, busy_s, rc):
+    """The driver refuses a traced line whose device.busy_s passes its
+    window_s: run.py holds that itself and prints no result (exit 1, one
+    line on stderr); busy for the whole of the window is a line."""
+    trace = {"busy_s": busy_s, "window_s": 3.0, "idle_share": 1 - busy_s / 3,
+             "planes": {}, "op_s": {}, "gap_s": {}, "device_ops": [],
+             "idle_gaps": []}
+
+    def load_json(*parts):
+        # a trace that shows the device at work wants the device's peaks
+        got = real_json(*parts)
+        return dict(got, cpu=got["TPU v5 lite"]) \
+            if parts[-1] == "peaks.json" else got
+
+    real_json = run.load_json
+    monkeypatch.setattr(run, "load_json", load_json)
+    monkeypatch.setattr(run, "reduce_trace", lambda *a: dict(trace))
+    got = run.main(["--workload", CELLS[0], "--seed", "2147483659",
+                    "--seconds", "3", "--trace", "1", "--rehearse-rows",
+                    "50000"])
+    out, err = capsys.readouterr()
+    assert got == rc
+    last = out.strip().splitlines()[-1]
+    if rc:
+        assert not last.startswith("{")
+        assert [ln for ln in err.splitlines() if ln.startswith("run.py:")] \
+            == ["run.py: device.busy_s 3.000001 is not above 0 and at most "
+                "device.window_s 3.0"]
+    else:
+        device = json.loads(last)["device"]
+        assert device["busy_s"] == device["window_s"] == 3.0
+
+
 @pytest.mark.parametrize("cell", CELLS)
 def test_wrong_reference_is_seen(capsys, monkeypatch, cell):
     real = run.load_module
 
+    class Another:
+        """What a wrong reference expects: never what was answered, be the
+        answer a count, a set of features or a join's rows."""
+        __hash__ = None
+
+        def __eq__(self, other):
+            return False
+
+        def __ne__(self, other):
+            return True
+
     def load(kind, name):
         mod = real(kind, name)
         if kind == "ops":
-            exp = mod.expected
-            mod.expected = lambda ref, params, a: exp(ref, params, a) + 1
+            mod.expected = lambda ref, params, a: Another()
         return mod
 
     monkeypatch.setattr(run, "load_module", load)
@@ -72,19 +116,54 @@ def test_wrong_reference_is_seen(capsys, monkeypatch, cell):
     assert line["compared"]["wrong_answers"]["value"] > 0
 
 
+def _one_more(n):
+    return n + 1
+
+
+def _first_row_one_more(body):
+    rows = [dict(r) for r in body["rows"]]
+    rows[0]["count"] += 1
+    return dict(body, rows=rows)
+
+
+def _first_feature_gone(text):
+    body = json.loads(text)
+    return json.dumps(dict(body, features=body["features"][1:]))
+
+
+# where the program produces a cell's answer, by the kind of its operation:
+# the store's entry point the route calls, or what serialises its result
+PRODUCED = {"count": ("geomesa_tpu.datastore.TpuDataStore.count_coalesced",
+                      _one_more),
+            "join": ("geomesa_tpu.datastore.TpuDataStore.join",
+                     _first_row_one_more),
+            "select": ("geomesa_tpu.io.export.export", _first_feature_gone)}
+
+
 @pytest.mark.parametrize("cell", CELLS)
 def test_answer_altered_where_it_is_produced_is_seen(capsys, monkeypatch,
                                                      cell):
-    """Every 7th count the store hands the REST layer is one too many."""
-    from geomesa_tpu.datastore import TpuDataStore
-    real, calls = TpuDataStore.count_coalesced, [0]
+    """Every 7th answer the program produces is altered before the REST
+    layer sends it: a count one too many, a join's first row one too many, a
+    feature collection without its first feature."""
+    import importlib
+    _, _, traffic = run.find_cell(run.load_json(
+        os.path.dirname(HERE), "BENCHMARK.json"), cell)
+    target, alter = PRODUCED[traffic["operation"].split("_")[0]]
+    owner, name = target.rsplit(".", 1)
+    try:
+        owner = importlib.import_module(owner)
+    except ModuleNotFoundError:
+        module, cls = owner.rsplit(".", 1)
+        owner = getattr(importlib.import_module(module), cls)
+    real, calls = getattr(owner, name), [0]
 
-    def altered(self, *a, **kw):
-        n = real(self, *a, **kw)
+    def altered(*a, **kw):
+        out = real(*a, **kw)
         calls[0] += 1
-        return n + 1 if calls[0] % 7 == 0 else n
+        return alter(out) if calls[0] % 7 == 0 else out
 
-    monkeypatch.setattr(TpuDataStore, "count_coalesced", altered)
+    monkeypatch.setattr(owner, name, altered)
     line = rehearse(capsys, cell)
     assert line["correct"] is False
     assert line["compared"]["wrong_answers"]["value"] > 0
