@@ -77,16 +77,16 @@ import numpy as np
 from geomesa_tpu import config
 from geomesa_tpu import trace as _trace
 from geomesa_tpu.filter import ir
-from geomesa_tpu.filter.extract import extract_bboxes, extract_intervals
 from geomesa_tpu.index import prune as _prune
 from geomesa_tpu.index.scan import (EMPTY_BOX, EMPTY_WINDOW, PRIMARY_FNS,
                                     ModuleKernelCache, ScanKernels,
                                     Unsupported, _LazyBlockGather, _fetch,
                                     _grid_scatter, _pip_band, _time_mask,
-                                    pad_boxes, pad_windows, program_name,
-                                    split_residual)
-from geomesa_tpu.index.spatial import _boxes_fp62, _strip_handled
-from geomesa_tpu.curves.binnedtime import time_to_binned_time
+                                    pad_boxes, pad_windows, program_name)
+from geomesa_tpu.index.bind import EMPTY as _EMPTY_BIND
+from geomesa_tpu.index.bind import ShapeBinder, _pow2
+from geomesa_tpu.index.bind import collect_values as _collect_values
+from geomesa_tpu.index.bind import shape_key as _shape_key
 from geomesa_tpu.metrics import REGISTRY
 from geomesa_tpu.obs import attrib as _attrib
 from geomesa_tpu.serve.resilience import deadline as _rdl
@@ -138,10 +138,6 @@ _RUNG_STEP = 1
 # (< 5e-4 total) plus the radius literal's own f32 cast (≤ 2.2e-5). Rows
 # inside the band re-evaluate on host in exact f64.
 _DIST_BAND = np.float32(1e-3)
-
-
-def _pow2(x: int) -> int:
-    return max(1, 1 << max(0, int(x) - 1).bit_length())
 
 
 def _tier(capacity: Optional[int]) -> int:
@@ -1144,55 +1140,6 @@ def try_union_density(planner, plan, auths, grid_bbox, width: int,
 # -- shape-keyed recipe fast path (skip planning entirely) --------------------
 
 
-def _shape_key(f: ir.Filter) -> str:
-    """Value-free structural signature of a filter tree — the same
-    normalization discipline the scheduler's plan cache uses: two queries
-    with this key in common differ only in geometry/time/constant VALUES."""
-    if isinstance(f, ir.And):
-        return "and(" + ",".join(_shape_key(c) for c in f.children) + ")"
-    if isinstance(f, ir.Or):
-        return "or(" + ",".join(_shape_key(c) for c in f.children) + ")"
-    if isinstance(f, ir.Not):
-        return f"not({_shape_key(f.child)})"
-    if isinstance(f, ir.Include):
-        return "inc"
-    if isinstance(f, ir.Exclude):
-        return "exc"
-    if isinstance(f, ir.BBox):
-        return f"bbox:{f.attr}"
-    if isinstance(f, ir.Intersects):
-        return f"ints:{f.attr}:{f.geometry[0]}"
-    if isinstance(f, ir.During):
-        return f"during:{f.attr}:{int(f.lo_inclusive)}{int(f.hi_inclusive)}"
-    if isinstance(f, ir.Cmp):
-        return f"cmp{f.op}:{f.attr}"
-    if isinstance(f, ir.In):
-        return f"in{_pow2(len(f.values))}:{f.attr}"
-    if isinstance(f, ir.Func):
-        return f"fn:{f.name}({_func_args_sig(f.args)})"
-    if isinstance(f, ir.FuncCmp):
-        return f"fc{f.op}:{f.name}({_func_args_sig(f.args)})"
-    raise Unsupported(type(f).__name__)
-
-
-def _func_args_sig(args: tuple) -> str:
-    """Value-free signature of st_* call arguments: attributes by name,
-    geometry literals by type code, scalars as 'f' — two calls with this
-    signature in common differ only in literal VALUES, the same normalization
-    the rest of the shape key uses."""
-    parts = []
-    for a in args:
-        if isinstance(a, str):
-            parts.append(f"a:{a}")
-        elif isinstance(a, tuple):
-            parts.append(f"l{a[0]}")
-        elif isinstance(a, ir.FuncExpr):
-            parts.append(f"{a.name}({_func_args_sig(a.args)})")
-        else:
-            parts.append("f")
-    return ",".join(parts)
-
-
 def _auths_key(auths) -> Optional[tuple]:
     return None if auths is None else tuple(sorted(auths))
 
@@ -1233,87 +1180,6 @@ def _recipes(planner) -> _RecipeCache:
         cache = _RecipeCache()
         planner._fused_recipes = cache
     return cache
-
-
-_EMPTY_BIND = object()   # bind result: provably-empty query (count 0)
-
-
-def _boxes_fp62_fast(boxes) -> Optional[np.ndarray]:
-    """Scalar twin of ``spatial._boxes_fp62`` for the handful-of-boxes case:
-    pure-python IEEE-754 math (bit-identical to the numpy path — python
-    floats ARE C doubles, and floor(ldexp(frac, 62)) of an integral float
-    converts to int exactly) without ~40µs of small-array numpy dispatch.
-    None on anything unusual (NaN coordinates) → caller uses the array path.
-    """
-    import math
-    out = np.empty((len(boxes), 8), dtype=np.int32)
-    m62 = (1 << 62) - 1
-    m31 = (1 << 31) - 1
-    try:
-        for i, (xmin, ymin, xmax, ymax) in enumerate(boxes):
-            row = out[i]
-            for j, (c, lo, hi) in enumerate(
-                    ((xmin, -180.0, 360.0), (xmax, -180.0, 360.0),
-                     (ymin, -90.0, 180.0), (ymax, -90.0, 180.0))):
-                frac = (float(c) - lo) / hi
-                frac = 0.0 if frac < 0.0 else (1.0 if frac > 1.0 else frac)
-                v = min(math.floor(math.ldexp(frac, 62)), m62)
-                row[2 * j] = v >> 31
-                row[2 * j + 1] = v & m31
-    except (ValueError, OverflowError):   # NaN / inf coordinate
-        return None
-    return out
-
-
-def _collect_values(f: Optional[ir.Filter], sft, string_vocabs,
-                    out: list) -> None:
-    """Value-collecting twin of ``_lower_residual``'s walk: appends this
-    query's residual constants to ``out`` in the SAME traversal order the
-    lowering allocated its layout slots, raising ``Unsupported`` under the
-    same conditions. Used by the template rebind (``_rebind``), which then
-    shape-checks every value against the template's slots — any drift falls
-    back to the full ``_build``."""
-    if f is None:
-        return
-    if isinstance(f, (ir.Include, ir.Exclude)):
-        return
-    if isinstance(f, (ir.And, ir.Or)):
-        for c in f.children:
-            _collect_values(c, sft, string_vocabs, out)
-        return
-    if isinstance(f, ir.Not):
-        _collect_values(f.child, sft, string_vocabs, out)
-        return
-    if isinstance(f, ir.Cmp):
-        attr = sft.attribute(f.attr)
-        if attr.type_name == "String":
-            vocab = string_vocabs.get(f.attr)
-            if vocab is None:
-                raise Unsupported("no vocab")
-            try:
-                out.append(vocab.index(f.value))
-            except ValueError:
-                out.append(-1)
-            return
-        if attr.type_name not in _EXACT_DEVICE_TYPES:
-            raise Unsupported("inexact cmp")
-        out.append(f.value)
-        return
-    if isinstance(f, ir.In):
-        attr = sft.attribute(f.attr)
-        if attr.type_name == "String":
-            vocab = string_vocabs.get(f.attr)
-            if vocab is None:
-                raise Unsupported("no vocab")
-            codes = [vocab.index(v) for v in f.values if v in vocab] or [-1]
-        elif attr.type_name in ("Int", "Integer"):
-            codes = [int(v) for v in f.values]
-        else:
-            raise Unsupported("IN on non-int/string")
-        size = max(1, 1 << (len(codes) - 1).bit_length())
-        out.append(codes + [codes[-1]] * (size - len(codes)))
-        return
-    raise Unsupported(type(f).__name__)
 
 
 def _rebind(recipe, boxes, gate, windows, dev_ir) -> Optional[_Program]:
@@ -1360,68 +1226,33 @@ def _rebind(recipe, boxes, gate, windows, dev_ir) -> Optional[_Program]:
 class Recipe:
     """Bind instructions for one (filter shape, auths): everything needed to
     turn a NEW same-shape filter into a packed fused count dispatch without
-    touching ``planner.plan()`` — extract boxes/intervals, window them,
+    touching ``planner.plan()`` — take its boxes, windows and device
+    residual out (``bind.ShapeBinder``, the scheduler's binder too),
     re-lower the residual (values only; the structure key must reproduce),
     pack, go. Any drift (box count, window count, residual key, host
     residual appearing) returns None and the slow path serves the query
     exactly."""
 
-    __slots__ = ("index", "sft", "geom", "dtg", "period", "vocabs",
-                 "n_boxes", "n_windows", "res_key", "vis", "template_plan",
-                 "tmpl")
+    __slots__ = ("binder", "index", "sft", "vocabs", "res_key", "vis",
+                 "template_plan", "tmpl")
 
     def __init__(self, plan, planner, res_key, vis):
         self.tmpl = None   # (program, layout) after the first full _build
+        self.binder = ShapeBinder(plan)
         self.index = plan.index
         self.sft = planner.sft
-        self.geom = plan.index.geom
-        self.dtg = plan.index.dtg
-        self.period = plan.index.period
         self.vocabs = plan.index.vocabs
-        self.n_boxes = len(plan.boxes_loose)
-        self.n_windows = 0 if plan.windows is None else len(plan.windows)
         self.res_key = res_key
         self.vis = vis
         self.template_plan = plan
 
     def bind(self, f: ir.Filter):
         """→ (boxes, gate, windows, dev_ir) | _EMPTY_BIND | None."""
-        if self.geom is None:
-            return None
-        ext = extract_bboxes(f, self.geom)
-        if len(ext.boxes) == 0:
-            return _EMPTY_BIND
-        if ext.unconstrained:
-            return None
-        boxes = (_boxes_fp62_fast(ext.boxes) if len(ext.boxes) <= 4
-                 else None)
-        if boxes is None:
-            boxes = _boxes_fp62(ext.boxes)
-        if len(boxes) & (len(boxes) - 1):
-            boxes = pad_boxes(boxes)
-        if len(boxes) != self.n_boxes:
-            return None
-        windows = None
-        iv = extract_intervals(f, self.dtg) if self.dtg else None
-        if iv is not None and len(iv.intervals) == 0:
-            return _EMPTY_BIND
-        if iv is not None and not iv.unconstrained:
-            w = np.empty((len(iv.intervals), 4), dtype=np.int32)
-            i32 = (1 << 31) - 1   # open-ended intervals overflow the bin i32
-            for i, (lo, hi) in enumerate(iv.intervals):
-                blo, olo = time_to_binned_time(lo, self.period)
-                bhi, ohi = time_to_binned_time(hi, self.period)
-                w[i] = (max(-i32, int(blo)), int(olo),
-                        min(i32, int(bhi)), int(ohi))
-            windows = pad_windows(w)
-        if (0 if windows is None else len(windows)) != self.n_windows:
-            return None
-        residual = _strip_handled(f, self.geom, self.dtg, True)
-        dev_ir, host_ir = split_residual(
-            residual, self.sft, self.vocabs, set(self.index.device.columns))
-        if host_ir is not None:
-            return None   # refine shapes go through the planner
-        return boxes, _gate_of(ext.boxes, len(boxes)), windows, dev_ir
+        got = self.binder.extract(f)
+        if got is None or got is _EMPTY_BIND:
+            return got
+        ext_boxes, boxes, _, windows, dev_ir = got
+        return boxes, _gate_of(ext_boxes, len(boxes)), windows, dev_ir
 
 
 class FusedPrepared:

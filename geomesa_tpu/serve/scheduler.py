@@ -27,6 +27,18 @@ Caching in front of the batcher:
                (the trace tree shows no ``plan`` span). Keyed by auths so a
                privileged query's visibility-folded plan can never serve an
                unprivileged caller (tests/test_security.py).
+  templates    in the same LRU, under the same key with the filter's
+               *shape* (``bind.shape_key``) in the filter's place: a
+               groupable count's first plan with the values taken out
+               (``bind.PlanTemplate``). A filter that misses the exact key
+               and meets its shape's template is not planned again: its
+               box, interval and constants are bound into the template
+               (no index is asked for a plan, nothing is priced), and the
+               plan that comes out equals the planner's on that index. A
+               shape whose first plan the collector would not group holds
+               None there and is planned in full every time, as is a
+               filter whose values do not bind (``sched.plan.bound`` /
+               ``.full`` / ``.bind_failed``).
 
 It invalidates through the datastore's per-type generation counter: every
 mutation (ingest append, LSM flush, age-off, update, delete, schema change)
@@ -81,6 +93,8 @@ from geomesa_tpu import trace as _trace
 from geomesa_tpu.durability import faults as _faults
 from geomesa_tpu.filter import ir
 from geomesa_tpu.filter.parser import parse_ecql
+from geomesa_tpu.index import bind as _bind
+from geomesa_tpu.index.scan import PRIMARY_FNS, Unsupported
 from geomesa_tpu.metrics import REGISTRY as _metrics
 from geomesa_tpu.obs import attrib as _attrib
 from geomesa_tpu.obs import flight as _flight
@@ -164,19 +178,24 @@ class LruCache:
         self.hits = 0
         self.misses = 0
 
-    def get(self, key):
-        """Cached value or the module ``_MISS`` sentinel."""
+    def get(self, key, tally: bool = True):
+        """Cached value or the module ``_MISS`` sentinel. ``tally=False``
+        leaves the hit/miss counters alone (a look-up that is no request's
+        own: the scheduler's shape templates)."""
         with self._lock:
             if self._cap > 0 and key in self._d:
                 self._d.move_to_end(key)
-                self.hits += 1
                 hit = True
                 out = self._d[key]
             else:
-                self.misses += 1
                 hit = False
                 out = _MISS
-        _metrics.inc(f"{self._prefix}.hits" if hit else f"{self._prefix}.misses")
+            if tally:
+                self.hits += hit
+                self.misses += not hit
+        if tally:
+            _metrics.inc(f"{self._prefix}.hits" if hit
+                         else f"{self._prefix}.misses")
         return out
 
     def peek(self, key) -> bool:
@@ -256,7 +275,7 @@ class Request:
     __slots__ = ("type_name", "f_ir", "f_key", "auths", "auths_key",
                  "planner", "delta", "generation", "epoch", "future",
                  "t_submit", "t_closed", "t_plan", "t_launch",
-                 "plan", "queue_wait_s", "scan_s", "staged",
+                 "plan", "plan_bound", "queue_wait_s", "scan_s", "staged",
                  "batched", "batch_size", "deadline", "priority",
                  "cancelled", "degraded",
                  # flight-recorder dimensions (obs/flight.py wide events)
@@ -286,7 +305,8 @@ class Request:
         self.future: Future = Future()
         # instants on trace.py's clock (perf_counter_ns): submitted, its
         # batch closed, its own (plan start, plan end) on a plan-cache
-        # miss, its dispatch launched (a single: handed to the
+        # miss (``plan_bound``: bound into its shape's template, not
+        # planned), its dispatch launched (a single: handed to the
         # completer). The stages below are their differences, in seconds:
         # queue_wait = submit → closed, scan = launch → resolved
         self.t_submit = _pcn()
@@ -294,6 +314,7 @@ class Request:
         self.t_plan: Optional[Tuple[int, int]] = None
         self.t_launch: Optional[int] = None
         self.plan = None
+        self.plan_bound = False
         self.queue_wait_s: Optional[float] = None
         self.scan_s: Optional[float] = None
         # spans the completer timed inside a single's ``scan``
@@ -326,6 +347,29 @@ class Request:
         return self.future.result(timeout=timeout)
 
 
+def _groupable(plan) -> bool:
+    """True for a plan the collector fuses with others of its group key: a
+    device-exact scan whose primary is one box (``_plan_loop``). The plans
+    a shape's template is kept for (``_plan_request``)."""
+    return (plan.device_exact and plan.primary_kind in PRIMARY_FNS
+            and plan.boxes_loose is not None
+            and plan.boxes_loose.shape == (1, 8))
+
+
+def _group_key(plan) -> tuple:
+    """What two groupable plans have in common when one dispatch can serve
+    both: the index's kernels, the primary, the time windows and the device
+    residual with its parameters, byte for byte; only the box differs."""
+    rd = plan.residual_device
+    wkey = None if plan.windows is None \
+        else (plan.windows.shape[0], plan.windows.tobytes())
+    rkey = (rd[0], tuple(
+        (np.asarray(p).dtype.str, np.asarray(p).shape,
+         np.asarray(p).tobytes()) for p in rd[1])) \
+        if rd else None
+    return id(plan.index.kernels), plan.primary_kind, wkey, rkey
+
+
 # -- the dispatch cycle, timed ------------------------------------------------
 
 
@@ -340,7 +384,10 @@ class _Cycle:
     """One turn of the collector thread, as instants on ``perf_counter_ns``:
     ``idle`` (blocked in ``queue.get()`` with nothing queued) → ``window``
     (first request → batch closed) → the planning loop → one ``_Dispatch``
-    per fused group. ``plan`` is the sum over the loop's plan-cache misses;
+    per fused group. ``plan`` is the sum over the loop's plan-cache misses
+    (``plan_bound`` of them bound into their shape's template, ``plan_full``
+    planned by ``_plan``, ``bind_failed`` of those after a template was
+    there and the values did not bind);
     ``cover`` the sum over its groups' covers (range decomposition and
     blocks, one for all the boxes of a group: ``cover_misses`` of them);
     ``group`` is the rest of the loop (group keys, deadline checks), so the
@@ -350,6 +397,7 @@ class _Cycle:
 
     __slots__ = ("t_idle", "t_first", "t_closed", "t_loop", "t_loop_end",
                  "plan_ns", "cover_ns", "plan_misses", "cover_misses",
+                 "plan_bound", "plan_full", "bind_failed",
                  "loop_cpu_ns", "size")
 
     def __init__(self, t_idle: int, t_first: int):
@@ -358,6 +406,7 @@ class _Cycle:
         self.t_closed = self.t_loop = self.t_loop_end = t_first
         self.plan_ns = self.cover_ns = self.loop_cpu_ns = 0
         self.plan_misses = self.cover_misses = 0
+        self.plan_bound = self.plan_full = self.bind_failed = 0
         self.size = 0
 
     def stages(self) -> Dict[str, Tuple[int, int]]:
@@ -498,6 +547,7 @@ class QueryScheduler:
         self._n_single = 0
         self._n_group_covers = 0
         self._n_cover_boxes = 0
+        self._n_plan = {"bound": 0, "full": 0, "bind_failed": 0}
         # completer-thread-only: the last cycles that took over
         # _SLOW_CYCLE_S, whole (replaced, never mutated: readers take the
         # reference)
@@ -664,8 +714,10 @@ class QueryScheduler:
         the collector and completer left on it. queue_wait, batch_host,
         scan and wake follow one another, so with ``submit`` they partition
         the latency. The request's own planning is part of batch_host's
-        interval and nests under it: ``plan``, fed to its timer here and
-        nowhere else (the collector calls the planner's untimed ``_plan``).
+        interval and nests under it: ``plan`` (``bound=True`` where the
+        values were bound into the shape's template), fed to its timer here
+        and nowhere else (the collector calls the planner's untimed
+        ``_plan``).
         The cover is its group's, not the request's: the collector feeds
         ``range_decompose`` once per group cover (``_cover_group``)."""
         rec = _trace.record
@@ -677,7 +729,8 @@ class QueryScheduler:
                        (launch - closed) / 1e9, launch)
             if req.t_plan is not None:
                 t0, t1 = req.t_plan
-                rec("plan", "plan", (t1 - t0) / 1e9, t1, parent=host)
+                rec("plan", "plan", (t1 - t0) / 1e9, t1,
+                    {"bound": True} if req.plan_bound else None, host)
             if req.scan_s is not None:
                 resolved = launch + int(req.scan_s * 1e9)
                 scan = rec("scan", "scan", req.scan_s, resolved,
@@ -798,6 +851,7 @@ class QueryScheduler:
                 self._n_cover_boxes / self._n_group_covers, 2)
             if self._n_group_covers else 0.0,
             "plan_cache": self.plans.stats(),
+            "plan": dict(self._n_plan),
             "result_cache": self.results.stats(),
             "healthy": self.healthy(),
             "admission": self.admission.stats(),
@@ -896,9 +950,15 @@ class QueryScheduler:
         """Fill ``req.plan`` via the plan cache (auths-folded). Plans only:
         the candidate-block cover is its group's (``_cover_group``). A cache
         hit leaves ``req.t_plan`` None — the trace shows no plan stage at
-        all. A miss is timed here, once: the planner's untimed ``_plan`` is
-        called, and the seconds reach the ``plan`` timer through the
-        request's own trace (``_record_stages``)."""
+        all. A miss looks for the filter shape's template under the same
+        key (store incarnation, type, generation, auths: a reload, a merge
+        that swaps the planner or other auths never meet a stale one) and
+        binds the request's values into it; without one, or where they do
+        not bind, the planner's untimed ``_plan`` is called, and the first
+        such plan of a shape becomes its template if the collector would
+        group it (None if not: such shapes are planned every time). Bind or
+        plan, a miss is timed here, once: the seconds reach the ``plan``
+        timer through the request's own trace (``_record_stages``)."""
         pkey = (req.epoch, req.type_name, req.generation, req.f_key,
                 req.auths_key)
         plan = self.plans.get(pkey)
@@ -909,7 +969,32 @@ class QueryScheduler:
         req.plan_cache_hit = False
         t0 = _pcn()
         planner = req.planner
-        plan = planner._apply_auths(planner._plan(req.f_ir), req.auths)
+        plan = tkey = None
+        tmpl = _MISS
+        # an interceptor may rewrite the filter or veto a plan by its values
+        if not planner.interceptors:
+            try:
+                tkey = (req.epoch, req.type_name, req.generation,
+                        ("shape", _bind.shape_key(req.f_ir)), req.auths_key)
+            except Unsupported:
+                pass    # no shape is kept for it (a FID filter)
+            else:
+                tmpl = self.plans.get(tkey, tally=False)
+                if tmpl is not _MISS and tmpl is not None:
+                    plan = tmpl.bind(req.f_ir)
+                    if plan is None:
+                        cyc.bind_failed += 1
+                    else:
+                        cyc.plan_bound += 1
+                        req.plan_bound = True
+        if plan is None:
+            cyc.plan_full += 1
+            base = planner._plan(req.f_ir)
+            plan = planner._apply_auths(base, req.auths)
+            # emptiness is a property of the values, not of the shape
+            if tkey is not None and tmpl is _MISS and not plan.empty:
+                self.plans.put(tkey, _bind.PlanTemplate.of(base, plan)
+                               if _groupable(plan) else None)
         t1 = _pcn()
         req.t_plan = (t0, t1)
         cyc.plan_ns += t1 - t0
@@ -971,6 +1056,11 @@ class QueryScheduler:
                         self._fail(r, e)
         cyc.t_loop_end = _pcn()
         cyc.loop_cpu_ns = time.thread_time_ns() - cpu0
+        for name, n in (("bound", cyc.plan_bound), ("full", cyc.plan_full),
+                        ("bind_failed", cyc.bind_failed)):
+            if n:
+                self._n_plan[name] += n
+                _metrics.inc("sched.plan." + name, n)
         if _trace.enabled():
             # one observation a cycle, before any of its groups is launched
             # (a dispatch's own stages: the completer, in `_publish`)
@@ -999,8 +1089,6 @@ class QueryScheduler:
         """The per-request part of a dispatch: deadline checks, the plan
         through its cache, the fused-kernel group key. Python and numpy
         only, nothing that should sleep."""
-        from geomesa_tpu.index.scan import PRIMARY_FNS
-
         degrade_floor = config.DEADLINE_DEGRADE_MS.get()
         closed = cyc.t_closed
         for r in batch:
@@ -1028,19 +1116,8 @@ class QueryScheduler:
                 self._fail(r, e)
                 continue
             plan = r.plan
-            if (plan.device_exact and plan.primary_kind in PRIMARY_FNS
-                    and plan.boxes_loose is not None
-                    and plan.boxes_loose.shape == (1, 8)):
-                rd = plan.residual_device
-                wkey = None if plan.windows is None \
-                    else (plan.windows.shape[0], plan.windows.tobytes())
-                rkey = (rd[0], tuple(
-                    (np.asarray(p).dtype.str, np.asarray(p).shape,
-                     np.asarray(p).tobytes()) for p in rd[1])) \
-                    if rd else None
-                gkey = (id(plan.index.kernels), plan.primary_kind,
-                        wkey, rkey)
-                groups.setdefault(gkey, []).append(r)
+            if _groupable(plan):
+                groups.setdefault(_group_key(plan), []).append(r)
             else:
                 self._n_single += 1
                 _metrics.inc("scheduler.singles")

@@ -16,10 +16,11 @@ from geomesa_tpu.features.sft import SimpleFeatureType
 from geomesa_tpu.features.table import FeatureTable
 from geomesa_tpu.filter.evaluate import evaluate
 from geomesa_tpu.filter.parser import parse_ecql
+from geomesa_tpu.index import bind as _bind
 from geomesa_tpu.index import compiled as fused
 from geomesa_tpu.index.planner import QueryPlanner
 from geomesa_tpu.index.scan import ROUNDS
-from geomesa_tpu.index.spatial import Z3Index
+from geomesa_tpu.index.spatial import Z3Index, _boxes_fp62
 
 
 def _unshadow_block_size():
@@ -287,15 +288,15 @@ def test_scalar_fp62_matches_array_path():
         boxes = np.stack([x0, y0,
                           np.minimum(180, x0 + rng.uniform(0, 50, k)),
                           np.minimum(90, y0 + rng.uniform(0, 40, k))], 1)
-        fast = fused._boxes_fp62_fast(boxes)
+        fast = _bind.boxes_fp62_fast(boxes)
         assert fast is not None
-        assert np.array_equal(fast, fused._boxes_fp62(boxes))
+        assert np.array_equal(fast, _boxes_fp62(boxes))
     # exact world bounds are representable in both paths
     edge = np.array([[-180.0, -90.0, 180.0, 90.0]])
-    assert np.array_equal(fused._boxes_fp62_fast(edge),
-                          fused._boxes_fp62(edge))
+    assert np.array_equal(_bind.boxes_fp62_fast(edge),
+                          _boxes_fp62(edge))
     # NaN coordinates decline the fast path (array path clamps them)
-    assert fused._boxes_fp62_fast(
+    assert _bind.boxes_fp62_fast(
         np.array([[np.nan, 0.0, 10.0, 10.0]])) is None
 
 

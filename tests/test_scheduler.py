@@ -114,21 +114,86 @@ def test_count_future_async_api(store):
 # -- plan cache, covers -------------------------------------------------------
 
 
-def test_plan_cache_hit_skips_plan_stage_in_trace(store):
+def _traces_of(q):
+    """The ring's traces of scheduled counts of ``q``, oldest first: found
+    by the request's own attribute, not by their place in the ring (another
+    thread's trace may land between two of them)."""
     from geomesa_tpu.trace import RING
+    return sorted((t for t in RING.recent(256)
+                   if t["root"].get("attrs", {}).get("scheduled")
+                   and t["root"]["attrs"].get("filter") == q),
+                  key=lambda t: t["id"])
+
+
+def _span(node, name):
+    if node["name"] == name:
+        return node
+    for c in node.get("children", ()):
+        found = _span(c, name)
+        if found is not None:
+            return found
+    return None
+
+
+def test_plan_cache_hit_skips_plan_stage_in_trace(store):
     sched = store.scheduler()
     q = "BBOX(geom, -3, -3, 17, 17) AND " + DURING
-    RING.clear()
     n1 = sched.count("t", q)
     n2 = sched.count("t", q)
     assert n1 == n2
-    traces = RING.recent(2)  # newest first
-    first, second = traces[1], traces[0]
+    first, second = _traces_of(q)
     assert "plan" in first["stages_ms"], "cold query must show a plan stage"
     assert "plan" not in second["stages_ms"], \
         "plan-cache hit must skip the plan stage entirely"
     assert "queue_wait" in second["stages_ms"]
     assert "scan" in second["stages_ms"]
+
+
+def test_bound_request_has_a_bound_plan_span_and_counts(store):
+    """A filter that misses the exact key and meets its shape's template:
+    a ``plan`` span with ``bound=True`` (it times the bind), a tick of
+    ``sched.plan.bound``; the shape's first request has the span without
+    the attribute and ticks ``sched.plan.full``; an exact-key hit has no
+    ``plan`` span and ticks neither."""
+    from geomesa_tpu.metrics import REGISTRY
+    sched = store.scheduler()
+    qs = [f"BBOX(geom, {-5 - i}, -3, 17, 17) AND {DURING} AND v < {70 + i}"
+          " AND name = 'a'" for i in range(3)]
+
+    def ticks():
+        c = REGISTRY.snapshot()["counters"]
+        return [c.get("sched.plan." + k, 0)
+                for k in ("bound", "full", "bind_failed")]
+
+    seen = [ticks()]
+    for q in (qs[0], qs[1], qs[2], qs[1]):
+        assert sched.count("t", q) == store.count("t", q)
+        seen.append(ticks())
+    steps = [[b - a for a, b in zip(x, y)] for x, y in zip(seen, seen[1:])]
+    assert steps == [[0, 1, 0], [1, 0, 0], [1, 0, 0], [0, 0, 0]]
+    (full,), (bound, hit), (bound2,) = (_traces_of(q) for q in qs)
+    assert "bound" not in _span(full["root"], "plan").get("attrs", {})
+    for t in (bound, bound2):
+        assert _span(t["root"], "plan")["attrs"]["bound"] == "True"
+        assert _span(t["root"], "batch_host") is not None
+    assert _span(hit["root"], "plan") is None
+    assert "plan" not in hit["stages_ms"] and "scan" in hit["stages_ms"]
+
+
+def test_stats_carry_the_plan_counters(store):
+    sched = store.scheduler()
+    before = sched.stats()["plan"]
+    assert set(before) == {"bound", "full", "bind_failed"}
+    qs = [f"BBOX(geom, -20, {-9 - i}, 3, 12) AND {DURING} AND v >= {i}"
+          for i in range(6)]
+    assert store.count_many("t", qs) == [store.count("t", q) for q in qs]
+    after = sched.stats()["plan"]
+    moved = {k: after[k] - before[k] for k in after}
+    assert moved["bound"] + moved["full"] == 6
+    assert moved["bind_failed"] == 0 and moved["full"] >= 1
+    # the template look-up is no request's own: the cache's tallies are
+    # the exact key's alone
+    assert sched.plans.stats()["misses"] >= after["bound"] + after["full"]
 
 
 def test_lone_repeat_keeps_its_cover_on_the_cached_plan(store):
@@ -336,6 +401,8 @@ def test_web_count_coalesces(store):
         st = get("/scheduler")
         assert st["queries"] >= 12
         assert "batch_size_hist" in st and "plan_cache" in st
+        assert set(st["plan"]) == {"bound", "full", "bind_failed"}
+        assert st["plan"]["bound"] + st["plan"]["full"] >= 1
     finally:
         httpd.shutdown()
 
