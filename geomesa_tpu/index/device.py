@@ -314,11 +314,19 @@ def segment_pool(garr: GeometryArray, perm: np.ndarray, pad: int):
 
 
 # rows of a polygon type's pool again, each row's segments sorted by their
-# lower end, in chunks of SEG_CHUNK: the plane the join reads a tile's slab
-# of a polygon from, whole chunks at a time (a gather of rows; a slice a
-# pair at its own offset is a loop of some 100,000 turns a join)
+# lower end, in chunks of SEG_CHUNK that start every SEG_STEP segments: the
+# plane the join reads a tile's slab of a polygon from, whole chunks at a
+# time (a gather of rows; a slice a pair at its own offset is a loop of some
+# 100,000 turns a join). A pair reads from the chunk that starts at most
+# SEG_STEP - 1 segments before its slab, then every SEG_CHUNK / SEG_STEP-th.
+# The gather's cost follows the chunks it moves more than their segments: on
+# a v5e, for the same tests, chunks of 16 took the join's launch 1.23 times
+# as long as chunks of 64 and chunks of 32 1.08 times; chunks of 128 came
+# within 0.5% of chunks of 64 on JOIN_WIDTHS, at twice the plane. So the
+# chunk stays 64 and only where it starts is fine
 SEGY = "__segy__"
 SEG_CHUNK = 64
+SEG_STEP = 16
 
 
 def segments_by_y(seg, seg_off: np.ndarray):
@@ -326,8 +334,9 @@ def segments_by_y(seg, seg_off: np.ndarray):
     f32 on the device, rows at ``seg_off``): the same segments, every row's
     in ``geom_batch.slab_order`` (by their lower end), so that the segments
     of a row that can reach into a y-range are one span of it, as (chunks,
-    4, SEG_CHUNK): segment i is [i // SEG_CHUNK, :, i % SEG_CHUNK]; that
-    order's search keys, ascending over the whole pool, and each row's
+    4, SEG_CHUNK): chunk q holds segments q * SEG_STEP onwards, so that
+    segment i is [q, :, i - q * SEG_STEP] of each chunk q that holds it;
+    that order's search keys, ascending over the whole pool, and each row's
     tallest segment. Of the f32 plane's own values: what the kernel
     compares."""
     import jax
@@ -340,12 +349,17 @@ def segments_by_y(seg, seg_off: np.ndarray):
         host[1, :total], host[3, :total],
         np.repeat(np.arange(len(seg_off) - 1), np.diff(seg_off)),
         len(seg_off) - 1)
-    chunks = -(-host.shape[1] // SEG_CHUNK)
-    gather = _merge_cache().get(
-        ("segments_by_y", host.shape[1]),
-        lambda: jax.jit(lambda s, t: jnp.pad(
-            s[:, t], ((0, 0), (0, chunks * SEG_CHUNK - s.shape[1]))
-        ).reshape(4, chunks, SEG_CHUNK).transpose(1, 0, 2)))
+    chunks = -(-host.shape[1] // SEG_STEP)
+
+    def by_chunks(s, t):
+        at = (SEG_STEP * jnp.arange(chunks, dtype=jnp.int32)[:, None]
+              + jnp.arange(SEG_CHUNK, dtype=jnp.int32))
+        return jnp.pad(s[:, t], (
+            (0, 0), (0, chunks * SEG_STEP + SEG_CHUNK - s.shape[1]))
+        )[:, at].transpose(1, 0, 2)
+
+    gather = _merge_cache().get(("segments_by_y", host.shape[1]),
+                                lambda: jax.jit(by_chunks))
     return gather(seg, jnp.asarray(take.astype(np.int32))), ykey, rise
 
 

@@ -563,7 +563,7 @@ class QueryPlanner:
                 if plan.boxes_loose is not None else None)
             pairs = _prune.gate_slabs(
                 env, blocks, pool.polygon_envelopes(), pool.seg_ykey,
-                pool.seg_rise, _scan.SEG_CHUNK)
+                pool.seg_rise, _scan.SEG_STEP)
             sp.set(blocks=len(blocks), polygons=len(np.unique(pairs[:, 4])),
                    pairs=len(pairs))
         _metrics.inc("join.block_polygon_pairs", len(pairs))
@@ -593,6 +593,7 @@ class QueryPlanner:
                         unc[:, 1] = idx.perm[unc[:, 1]]
                 edges = int(np.take(_scan.JOIN_WIDTHS, np.searchsorted(
                     _scan.JOIN_WIDTHS, pairs[:, 3])).sum()) * tile
+                slab = int((pairs[:, 3] - pairs[:, 2]).sum())
                 sp.set(points=len(blocks) * bsz, edges=edges,
                        certain=int(inside.sum()), uncertain=int(open_.sum()),
                        launches=launches)
@@ -600,8 +601,9 @@ class QueryPlanner:
             _metrics.inc("join.points_scanned", len(blocks) * bsz)
             _metrics.inc("join.point_pairs", int(passed.sum()))
             _metrics.inc("join.edge_tests", edges)
-            _metrics.inc("join.segments_read",
-                         int((pairs[:, 3] - pairs[:, 2]).sum()))
+            # the tests the pairs' own slabs need, of those the kernel ran
+            _metrics.inc("join.slab_tests", slab * tile)
+            _metrics.inc("join.segments_read", slab)
             _metrics.inc("join.pairs_uncertain", int(open_.sum()))
             if late is None:
                 # more rows in the windows' boundary units than the kernel

@@ -215,15 +215,16 @@ def gate_blocks(env: dict, blocks: Optional[np.ndarray],
 
 
 def gate_slabs(env: dict, blocks: np.ndarray, poly_env: np.ndarray,
-               ykey: np.ndarray, rise: np.ndarray, chunk: int) -> np.ndarray:
+               ykey: np.ndarray, rise: np.ndarray, step: int) -> np.ndarray:
     """The join's pairs, (k, 5) int64 [tile, first chunk, first segment in
     it, past the last, polygon]: every tile of the candidate ``blocks`` (a block's rows in
     ascending y, ``env``'s tiles; tile t of ``blocks[j]`` is j * tiles a
     block + t) against every polygon whose envelope (``poly_env``: (P, 4)
     f64) its own meets, with the span of that polygon's y-sorted segments
     (``ykey``, ``rise``: geom_batch.slab_order) that can reach into the
-    tile's y-range, as the kernel reads it: whole chunks of ``chunk``
-    segments from the one that holds the span's first. In two steps, so that P polygons cost a tile x P test
+    tile's y-range, as the kernel reads it: from the chunk that starts at
+    the last multiple of ``step`` at or before the span's first segment
+    (``device.segments_by_y``). In two steps, so that P polygons cost a tile x P test
     only where the tile's block met the polygon."""
     from geomesa_tpu.filter.geom_batch import KEY_ROW
     if len(blocks) == 0 or len(poly_env) == 0:
@@ -245,6 +246,6 @@ def gate_slabs(env: dict, blocks: np.ndarray, poly_env: np.ndarray,
     lo = np.searchsorted(ykey, at + np.maximum(y0[tile] - rise[p], -90.0))
     hi = np.searchsorted(ykey, at + np.minimum(y1[tile], 90.0), side="right")
     keep = hi > lo
-    first = lo // chunk
-    return np.stack([tile, first, lo - first * chunk, hi - first * chunk, p],
+    first = lo // step
+    return np.stack([tile, first, lo - first * step, hi - first * step, p],
                     axis=1)[keep]

@@ -32,7 +32,7 @@ import numpy as np
 
 from geomesa_tpu import trace as _trace
 from geomesa_tpu.filter import ir
-from geomesa_tpu.index.device import SEG, SEG_CHUNK, SEGY, WAY
+from geomesa_tpu.index.device import SEG, SEG_CHUNK, SEG_STEP, SEGY, WAY
 from geomesa_tpu.obs import attrib as _attrib
 from geomesa_tpu.obs import profiling as _prof
 
@@ -506,7 +506,8 @@ def _classify_pairs(px, py, m, seg, box, pairs, width: int):
     points ``px``/``py``/``m`` (tiles, rows) → (G, rows) int32 flags, bit 0
     a point surely inside its pair's polygon, bit 1 one the f32 band cannot
     settle. A pair reads ``width`` segments of ``seg`` ((chunks, 4,
-    SEG_CHUNK)) from its first chunk on, whole chunks, those outside its own
+    SEG_CHUNK), a chunk starting every SEG_STEP segments) from its first
+    chunk on, whole chunks, those outside its own
     span masked out: the slab of its polygon's edges, sorted by their lower
     end, that can meet the tile's y-range, so the others neither cross a
     point's ray nor tie its y. Edges
@@ -515,8 +516,8 @@ def _classify_pairs(px, py, m, seg, box, pairs, width: int):
     outlives the reduce. A point outside the polygon's envelope by more than
     the inputs' rounding is surely outside whatever the slab says."""
     slot, poly = pairs[:, 0], pairs[:, 4]
-    segs = seg[pairs[:, 1:2] + jnp.arange(width // SEG_CHUNK,
-                                          dtype=jnp.int32)[None, :]]
+    segs = seg[pairs[:, 1:2] + (SEG_CHUNK // SEG_STEP) * jnp.arange(
+        width // SEG_CHUNK, dtype=jnp.int32)[None, :]]
     e = jnp.arange(width, dtype=jnp.int32)[None, :]
     evalid = ((e >= pairs[:, 2:3]) & (e < pairs[:, 3:4]))[:, :, None]
     x, y = px[slot][:, None, :], py[slot][:, None, :]
@@ -1548,9 +1549,14 @@ BAND_MAX_BLOCKS = 256
 # span of the polygon's segments sorted by their lower end (``SEGY``). A
 # pair reads the bucket of JOIN_WIDTHS at or over its span (``POOL_TILE`` at
 # most: the pool's pad); a turn of the kernel's loop takes JOIN_STEP_EDGES /
-# width pairs.
+# width pairs. A bucket's time follows the chunks it gathers as much as its
+# tests (v5e): buckets of 16, 32 and 48 read from a chunk of 64 took the
+# launch 1.06 times as long as running those pairs at 64, so the widths are
+# whole chunks in steps of x1.5 (x2 at the first), and a pair runs at most
+# 1.5 times what it reads, not 2 times as on powers of two
 JOIN_TILE = 128
-JOIN_WIDTHS = (64, 128, 256, 512, 1024, 2048, 4096)
+JOIN_WIDTHS = (64, 128, 192, 256, 384, 512, 768, 1024, 1536, 2048, 3072,
+               4096)
 JOIN_STEP_EDGES = 1 << 16
 JOIN_MAX_BLOCKS = 1 << 10    # blocks a launch: 4.2M rows
 JOIN_UNC_CAP = 1 << 15       # uncertain (point, polygon) couples a launch

@@ -305,22 +305,179 @@ def test_launches_do_not_grow_with_the_polygons(n_polygons):
     ds.close()
 
 
-def test_the_gate_drops_pairs_and_cuts_slabs_of_two_buckets(store):
+def _gate(ds):
+    """(polygon pool, tile envelopes, the gate's pairs, rows a tile) of an
+    unfiltered join of ``ds``'s gdelt with its countries."""
     from geomesa_tpu.index import prune
-    planner = store.planners["gdelt"]
-    idx = planner.indexes[0]
-    pool = next(i for i in store.planners["countries"].indexes
+    planner = ds.planners["gdelt"]
+    pool = next(i for i in ds.planners["countries"].indexes
                 if i.name == "xz2")
-    seg_n = np.diff(pool.seg_off)
     bsz = min(prune.BLOCK_SIZE, len(planner.table))
-    env = idx.join_envelopes(bsz, scan.JOIN_TILE)
+    tile = min(scan.JOIN_TILE, bsz)
+    env = planner.indexes[0].join_envelopes(bsz, tile)
     blocks = prune.gate_blocks(env, None, None, None)
-    pairs = prune.gate_slabs(env, blocks, pool.polygon_envelopes(),
-                             pool.seg_ykey, pool.seg_rise, scan.SEG_CHUNK)
+    return pool, env, prune.gate_slabs(
+        env, blocks, pool.polygon_envelopes(), pool.seg_ykey, pool.seg_rise,
+        scan.SEG_STEP), tile
+
+
+def _combs(rows, seed):
+    """Events over six combs: a zigzag of k teeth over a base, every edge
+    of the zigzag as tall as the teeth and the sides taller, so that a tile
+    that meets a comb's envelope meets all its k + 3 edges: slabs of six
+    sizes, from under a chunk to some hundreds of segments. The last events
+    sit on vertices and on the midpoints of edges."""
+    rng = np.random.default_rng(seed)
+    off, xy = [0], []
+    for c, k in enumerate((10, 37, 85, 175, 350, 700)):
+        x0 = -21.0 + 7 * c
+        i = np.arange(k + 1)
+        ring = np.vstack([np.stack([x0 + i * (6 / k),
+                                    np.where(i % 2 == 0, 6.0, -4.0)], 1),
+                          [[x0 + 6, -6.0], [x0, -6.0]]])
+        ring = np.round(ring * 1024) / 1024
+        xy.append(np.vstack([ring, ring[:1]]))
+        off.append(off[-1] + len(ring) + 1)
+    a = np.concatenate(xy)
+    off = np.asarray(off, dtype=np.int64)
+    x, y = rng.uniform(-22, 22, rows), rng.uniform(-8, 8, rows)
+    firsts = rng.integers(0, len(a) - 1, 150)
+    firsts = firsts[~np.isin(firsts + 1, off)]
+    placed = np.concatenate([a[firsts], (a[firsts] + a[firsts + 1]) / 2])
+    x[-len(placed):], y[-len(placed):] = placed[:, 0], placed[:, 1]
+    mentions = rng.geometric(0.18, rows).astype(np.int32)
+    return {"x": x, "y": y, "dtg": BASE + rng.integers(0, 30 * DAY, rows),
+            "NumMentions": mentions,
+            "NumArticles": mentions + rng.integers(0, 3, rows,
+                                                   dtype=np.int32),
+            "polygons": {"off": off, "xy": a,
+                         "names": [f"comb{c}" for c in range(len(off) - 1)]}}
+
+
+def _in_f64(c, boundary):
+    """Every polygon's (name, count, sums) by geom_batch.points_in_polygons
+    over the f64 coordinates."""
+    from geomesa_tpu.filter import geom_batch
+    pol = c["polygons"]
+    n = len(pol["names"])
+    level = np.arange(n + 1, dtype=np.int64)
+    i, p = geom_batch.points_in_polygons(
+        c["x"], c["y"], GeometryArray(np.full(n, POLYGON, np.int8), level,
+                                      level, pol["off"], pol["xy"]),
+        boundary)
+    return [(pol["names"][k], int(np.sum(p == k)),
+             int(c["NumMentions"][i[p == k]].astype(np.int64).sum()),
+             int(c["NumArticles"][i[p == k]].astype(np.int64).sum()))
+            for k in range(n)]
+
+
+@pytest.fixture(scope="module")
+def combs():
+    return _combs(6000, seed=17)
+
+
+@pytest.fixture(scope="module")
+def comb_store(combs):
+    ds = _store(combs)
+    yield ds
+    ds.close()
+
+
+@pytest.mark.parametrize("op", ["st_intersects", "st_contains"])
+def test_slabs_of_many_widths_join_exactly(comb_store, combs, op):
+    _, _, pairs, _ = _gate(comb_store)
+    assert len(np.unique(np.searchsorted(scan.JOIN_WIDTHS,
+                                         pairs[:, 3]))) >= 4
+    before = _counters()
+    body = comb_store.join("gdelt", "countries", op, "INCLUDE", STATS)
+    assert _rows(body) == _in_f64(combs, op == "st_intersects")
+    assert _gained(before, "join.launches") == 1
+    assert _gained(before, "join.overflow_fallbacks") == 0
+
+
+@pytest.mark.parametrize("which", ["store", "comb_store"])
+def test_a_pair_runs_in_the_smallest_width_over_what_it_reads(
+        request, monkeypatch, which):
+    ds = request.getfixturevalue(which)
+    _, _, pairs, _ = _gate(ds)
+    widths = np.asarray(scan.JOIN_WIDTHS)
+    read, span = pairs[:, 3], pairs[:, 3] - pairs[:, 2]
+    width = widths[np.searchsorted(widths, read)]
+    # its span fits the width, and it reads less than a step before it
+    assert np.all(span <= width)
+    assert np.all((pairs[:, 2] >= 0) & (pairs[:, 2] < scan.SEG_STEP))
+    # the ladder: whole chunks up to the pool's pad, a step x1.5 (x2 first)
+    assert np.all(widths % scan.SEG_CHUNK == 0)
+    assert widths[0] == scan.SEG_CHUNK and widths[-1] == scan.POOL_TILE
+    assert np.all(width <= np.maximum(2 * scan.SEG_CHUNK, 1.5 * read))
+    # what the kernel was sent: each bucket the pairs that read more than
+    # the width below it and at most its own
+    sent, real = [], scan._fetch
+
+    def spy(fn, *args):
+        if np.shape(args[-1]) == (len(widths), 2):
+            sent.append((np.asarray(args[-2]), np.asarray(args[-1])))
+        return real(fn, *args)
+
+    monkeypatch.setattr(scan, "_fetch", spy)
+    ds.join("gdelt", "countries", "st_intersects", "INCLUDE", "count")
+    (got, spans), = sent
+    assert np.array_equal(spans[:, 1], np.bincount(
+        np.searchsorted(widths, read), minlength=len(widths)))
+    for b, (first, count) in enumerate(spans):
+        r = got[first: first + count, 3]
+        assert np.all(r <= widths[b]) and (b == 0 or np.all(r > widths[b - 1]))
+
+
+@pytest.mark.parametrize("which", ["store", "comb_store"])
+def test_the_y_sorted_plane_starts_a_chunk_every_step(request, which):
+    """``__segy__``: chunk q is the y-sorted pool from segment q * SEG_STEP
+    on, so the chunks a pair reads, every SEG_CHUNK / SEG_STEP-th from its
+    first, are its polygon's segments in one run; each polygon's are its
+    pool's, ordered by their lower end."""
+    ds = request.getfixturevalue(which)
+    pool, _, pairs, _ = _gate(ds)
+    segy = np.asarray(pool.device.columns[scan.SEGY])
+    seg = np.asarray(pool.device.columns[scan.SEG])
+    step, chunk = scan.SEG_STEP, scan.SEG_CHUNK
+    assert segy.shape[1:] == (4, chunk)
+    flat = segy[:, :, :step].transpose(1, 0, 2).reshape(4, -1)
+    assert flat.shape[1] >= seg.shape[1]
+    at = step * np.arange(len(segy))[:, None] + np.arange(chunk)
+    flat_padded = np.pad(flat, ((0, 0), (0, at.max() + 1 - flat.shape[1])))
+    assert np.array_equal(segy, flat_padded[:, at].transpose(1, 0, 2))
+    for a, b in zip(pool.seg_off[:-1], pool.seg_off[1:]):
+        assert sorted(map(tuple, seg[:, a:b].T)) \
+            == sorted(map(tuple, flat[:, a:b].T))
+        assert np.all(np.diff(np.minimum(flat[1, a:b], flat[3, a:b])) >= 0)
+    # what a pair reads: its first chunk, then every chunk / step-th
+    for t, first, lo, hi, _ in pairs[:: max(1, len(pairs) // 200)]:
+        rows = first + (chunk // step) * np.arange(-(-hi // chunk))
+        got = segy[rows].transpose(1, 0, 2).reshape(4, -1)[:, lo:hi]
+        assert np.array_equal(got, flat[:, first * step + lo:
+                                        first * step + hi])
+
+
+@pytest.mark.parametrize("which", ["store", "comb_store"])
+def test_slab_tests_count_what_the_pairs_own_slabs_need(request, which):
+    ds = request.getfixturevalue(which)
+    _, _, pairs, tile = _gate(ds)
+    before = _counters()
+    ds.join("gdelt", "countries", "st_intersects", "INCLUDE", "count")
+    assert _gained(before, "join.slab_tests") \
+        == int((pairs[:, 3] - pairs[:, 2]).sum()) * tile
+    assert _gained(before, "join.edge_tests") == int(np.take(
+        scan.JOIN_WIDTHS, np.searchsorted(scan.JOIN_WIDTHS,
+                                          pairs[:, 3])).sum()) * tile
+
+
+def test_the_gate_drops_pairs_and_cuts_slabs_of_two_buckets(store):
+    pool, env, pairs, _ = _gate(store)
+    seg_n = np.diff(pool.seg_off)
     tiles = env["xmin"].size
     assert 0 < len(pairs) < tiles * len(seg_n)
     # a slab is a span of its polygon's segments, mostly a part of them
-    first = pairs[:, 1] * scan.SEG_CHUNK
+    first = pairs[:, 1] * scan.SEG_STEP
     assert np.all(first + pairs[:, 2] >= pool.seg_off[pairs[:, 4]])
     assert np.all(first + pairs[:, 3] <= pool.seg_off[pairs[:, 4] + 1])
     assert (pairs[:, 3] - pairs[:, 2]).sum() \
