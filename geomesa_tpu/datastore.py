@@ -669,9 +669,10 @@ class TpuDataStore:
                 return self.reindex_status(type_name)  # already running
             self._reindex_status[type_name] = {"state": "running",
                                                "attempts": 0}
-            t = threading.Thread(target=self._reindex_run,
-                                 args=(type_name,),
-                                 name=f"reindex-{type_name}", daemon=True)
+            from geomesa_tpu.obs import hostcpu
+            t = hostcpu.thread("program.other", target=self._reindex_run,
+                               args=(type_name,),
+                               name=f"reindex-{type_name}", daemon=True)
             self._reindex_threads[type_name] = t
         t.start()
         return self.reindex_status(type_name)

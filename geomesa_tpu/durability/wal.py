@@ -801,9 +801,10 @@ class WriteAheadLog:
         with self._lock:
             if self._syncer is not None or self._closed:
                 return
-            t = threading.Thread(target=self._sync_loop,
-                                 name=f"geomesa-wal-sync-{self.name}",
-                                 daemon=True)
+            from geomesa_tpu.obs import hostcpu
+            t = hostcpu.thread("program.other", target=self._sync_loop,
+                               name=f"geomesa-wal-sync-{self.name}",
+                               daemon=True)
             self._syncer = t
         t.start()
 

@@ -351,7 +351,8 @@ def _block_summaries(index, bsz: int):
         hi = jnp.int32((1 << 31) - 1)
         summ["binmin"] = jnp.min(blocked(cols["bin"], hi), axis=1)
         summ["binmax"] = jnp.max(blocked(cols["bin"], lo), axis=1)
-    jax.block_until_ready(summ)
+    with _trace.waiting():
+        jax.block_until_ready(summ)
     index._fused_summ = (bsz, summ)
     return summ
 
@@ -394,7 +395,9 @@ class _Program:
         ``fused.blocks_alive`` / ``fused.blocks_gathered`` and the rung to
         ``STATS``. Returns the result on the host."""
         import jax
-        out, n_alive = jax.device_get(_fetch(self.dispatch))
+        ready = _fetch(self.dispatch)
+        with _trace.waiting():   # the read-back's copy
+            out, n_alive = jax.device_get(ready)
         n_alive = int(n_alive)
         which = sum(n_alive > r for r in self.rungs)   # the switch's index
         gathered = (self.rungs + (self.nb,))[which]
@@ -1341,7 +1344,6 @@ def fast_prepare(planner, f: ir.Filter, auths) -> Optional[FusedPrepared]:
             r.tmpl = (prog, prog.layout)
     STATS["shape_hits"] += 1
     STATS["queries"] += 1
-    REGISTRY.inc("fused.shape_hits")
     REGISTRY.inc("fused.queries")
     return FusedPrepared(planner, r, f, auths, prog)
 

@@ -1330,9 +1330,11 @@ class ScanKernels:
                            (len(b), block_size, 0, unc_cap, ne, len(tiles)))
             with _attrib.kernel(f"intersects_band_blocks.{primary_kind}",
                                 len(b)):
-                outs.append(np.asarray(_fetch(
+                ready = _fetch(
                     fn, self.cols, dbx, dw, rp, dep, n_edges,
-                    jnp.asarray(b), jnp.asarray(tiles), jnp.asarray(shift))))
+                    jnp.asarray(b), jnp.asarray(tiles), jnp.asarray(shift))
+            with _trace.waiting():   # the read-back's copy
+                outs.append(np.asarray(ready))
             facts["segments"] += n_seg
         certain = sum(int(o[0]) for o in outs)
         facts["candidate_ways"] = sum(int(o[2]) for o in outs)
@@ -1406,10 +1408,12 @@ class ScanKernels:
                            (nb, block_size, 0, JOIN_UNC_CAP, cap,
                             tuple(stat_cols)))
             with _attrib.kernel(f"join_band_blocks.{primary_kind}", nb):
-                got = np.asarray(_fetch(
+                ready = _fetch(
                     fn, self.cols, dbx, dw, rp, ds, seg, box, perm,
                     jnp.asarray(ids),
-                    jnp.asarray(sent), jnp.asarray(spans)))
+                    jnp.asarray(sent), jnp.asarray(spans))
+            with _trace.waiting():   # the read-back's copy
+                got = np.asarray(ready)
             launches += 1
             body = cap * k_out
             res = got[:body].reshape(cap, k_out)[at]

@@ -120,7 +120,8 @@ def _stream_encode_upload(encode_chunk, n: int, chunk_rows: int,
             except BaseException as e:  # noqa: BLE001 - re-raised below
                 state["error"] = e
 
-    th = threading.Thread(target=uploader, daemon=True)
+    from geomesa_tpu.obs import hostcpu
+    th = hostcpu.thread("program.other", target=uploader, daemon=True)
     th.start()
     host_kept: List[dict] = []
     failed = False
@@ -475,7 +476,9 @@ class BaseSpatialIndex:
             except Exception:
                 pass  # the lazy properties will retry synchronously
 
-        self._perm_thread = threading.Thread(target=fetch, daemon=True)
+        from geomesa_tpu.obs import hostcpu
+        self._perm_thread = hostcpu.thread("program.other", target=fetch,
+                                           daemon=True)
         self._perm_thread.start()
 
     def map_rows(self, idx: np.ndarray) -> np.ndarray:

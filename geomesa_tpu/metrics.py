@@ -60,22 +60,31 @@ def sanitize_metric_name(name: str) -> str:
 
 class Histogram:
     """Log-scale fixed-bucket duration histogram (count/total/max +
-    percentiles). Not internally locked — the registry lock covers it."""
+    percentiles). ``cpu_s`` sums the thread CPU time of the ``cpu_count``
+    observations that carry one (a root trace reads its thread's CPU clock;
+    other spans do not). Not internally locked — the
+    registry lock covers it."""
 
-    __slots__ = ("count", "total_s", "max_s", "buckets")
+    __slots__ = ("count", "total_s", "max_s", "buckets", "cpu_s",
+                 "cpu_count")
 
     def __init__(self):
         self.count = 0
         self.total_s = 0.0
         self.max_s = 0.0
         self.buckets = [0] * _N_BUCKETS
+        self.cpu_s = 0.0
+        self.cpu_count = 0
 
-    def observe(self, seconds: float) -> None:
+    def observe(self, seconds: float, cpu_s: Optional[float] = None) -> None:
         self.count += 1
         self.total_s += seconds
         if seconds > self.max_s:
             self.max_s = seconds
         self.buckets[bucket_index(seconds)] += 1
+        if cpu_s is not None:
+            self.cpu_s += cpu_s
+            self.cpu_count += 1
 
     def percentile(self, q: float) -> float:
         """Upper bound (seconds) of the bucket holding the ceil(q*count)-th
@@ -94,6 +103,7 @@ class Histogram:
         return {
             "count": self.count,
             "total_s": round(self.total_s, 6),
+            "cpu_s": round(self.cpu_s, 6),
             "mean_ms": round(self.total_s / self.count * 1000, 3)
             if self.count else 0.0,
             "max_ms": round(self.max_s * 1000, 3),
@@ -199,8 +209,8 @@ class MetricsRegistry:
 
     def feed_tree(self, root, trace_id: Optional[int] = None) -> None:
         """Defer a whole span tree (an object with ``walk()`` yielding nodes
-        with ``name``/``duration_ms``) to the next drain — the trace-close
-        hot-path feed: ONE locked list append now, histogram math at
+        with ``name``/``duration_ms``/``cpu_ms``) to the next drain — the
+        trace-close hot-path feed: ONE locked list append now, histogram math at
         snapshot time. Reporters consequently see trace-span timer events at
         drain time (they poll snapshots anyway, the dropwizard model).
         ``trace_id`` tags the tree so retained traces become exemplars.
@@ -268,12 +278,13 @@ class MetricsRegistry:
                     keep = False
             for s in root.walk():
                 seconds = s.duration_ms / 1000.0
+                cpu_ms = s.cpu_ms
+                self._timers[s.name].observe(
+                    seconds, None if cpu_ms is None else cpu_ms / 1000.0)
                 pairs.append((s.name, seconds))
                 if keep:
                     self._exemplars.setdefault(s.name, {})[
                         bucket_index(seconds)] = (tid, seconds)
-        for name, seconds in pairs:
-            self._timers[name].observe(seconds)
         return pairs if self._reporters else None
 
     def timer_good_total(self, name: str, threshold_s: float):
@@ -429,7 +440,8 @@ class MetricsRegistry:
             pairs = self._drain_locked()
             reporters = list(self._reporters) if pairs else None
             counters = dict(self._counters)
-            timers = {k: (h.to_dict(), list(h.buckets), h.total_s)
+            timers = {k: (h.to_dict(), list(h.buckets), h.total_s,
+                          h.cpu_s, h.cpu_count)
                       for k, h in self._timers.items()}
             values = {k: (h.to_value_dict(), list(h.buckets), h.total_s)
                       for k, h in self._values.items()}
@@ -503,7 +515,8 @@ class MetricsRegistry:
             lines.append(f"# TYPE {m} "
                          f"{'counter' if name.endswith('_total') else 'gauge'}")
             lines.append(f"{m} {float(g):g}")
-        for name, (h, buckets, total_s) in sorted(timers.items()):
+        for name, (h, buckets, total_s, cpu_s, cpu_count) in \
+                sorted(timers.items()):
             m = sane(name) + "_seconds"
             lines.append(f"# TYPE {m} summary")
             if h["count"]:
@@ -517,6 +530,12 @@ class MetricsRegistry:
             lines.append(f"# TYPE {mh} histogram")
             self._bucket_lines(lines, mh, buckets, h["count"], total_s,
                                exemplars.get(name))
+            if cpu_count:
+                # the thread CPU time of the observations that read it
+                mc = sane(name) + "_cpu_seconds"
+                lines.append(f"# TYPE {mc} summary")
+                lines.append(f"{mc}_count {cpu_count}")
+                lines.append(f"{mc}_sum {cpu_s:.9g}")
         for name, (h, buckets, total) in sorted(values.items()):
             m = sane(name)  # raw units: no _seconds suffix
             lines.append(f"# TYPE {m} summary")

@@ -1,17 +1,17 @@
-"""Per-kernel device cost attribution.
+"""Per-kernel host cost attribution.
 
 The trace layer splits ``device_scan`` (dispatch) from ``device_wait``
-(block_until_ready) per QUERY; this module attributes the same costs per
-KERNEL — (kernel id, batch tier) — so a fleet-wide p99 regression can be
-charged to the one fused kernel that got slower, not just "the device".
+(block_until_ready) per QUERY; this module attributes host costs per
+KERNEL — (kernel id, batch tier). Time blocked in ``block_until_ready`` is
+host time, not device time, and is charged to no kernel: a device trace
+gives a kernel's device time.
 
 Attributed series (all land in metrics.REGISTRY under ``kernel.<id>.b<tier>.*``
 so they ride the existing /metrics + Prometheus surfaces and
 ``snapshot_prefixed("kernel.")`` filtering):
 
-  .dispatches        device dispatch count (counter)
-  .device_wait       block-until-ready seconds (histogram timer → p50/p99)
-  .dispatch          host-side enqueue seconds (histogram timer)
+  .dispatch          host-side enqueue seconds (histogram timer; its count
+                     is the labeled fetches)
   .transfer_bytes    host→device bytes shipped for the dispatch (counter)
   .compiles          XLA compilations triggered (counter)
   .compile           compilation seconds (histogram timer)
@@ -24,9 +24,7 @@ Wiring:
 
   - ``ScanKernels._get`` wraps every newly-jitted kernel in
     ``compile_probe`` → first invocation records compile count/time;
-  - the scheduler measures the completer's device wait per fused batch
-    directly (``record_dispatch``) and the upload bytes per group
-    (``record_transfer``);
+  - the scheduler charges the upload bytes per group (``record_transfer``);
   - direct-path entry points label the ambient thread
     (``with kernel("count.point_boxes", 1): ...``) and the trace layer's
     device hook charges each ``device_fetch`` to that label.
@@ -56,17 +54,6 @@ def _series(kernel_id: str, tier: int, metric: str) -> str:
     # cached: the hot device hook would otherwise build 2-3 f-strings per
     # dispatch; the set of (kernel, tier, metric) names is small and stable
     return f"kernel.{kernel_id}.b{int(tier)}.{metric}"
-
-
-def record_dispatch(kernel_id: str, tier: int, wait_s: float,
-                    dispatch_s: float = 0.0, n: int = 1) -> None:
-    """Charge one device round trip to (kernel id, batch tier)."""
-    if not enabled():
-        return
-    _metrics.inc(_series(kernel_id, tier, "dispatches"), n)
-    _metrics.observe(_series(kernel_id, tier, "device_wait"), wait_s)
-    if dispatch_s > 0:
-        _metrics.observe(_series(kernel_id, tier, "dispatch"), dispatch_s)
 
 
 def record_transfer(kernel_id: str, tier: int, nbytes: int) -> None:
@@ -153,22 +140,21 @@ _PENDING_FETCH_MAX = 4096
 _flush_lock = threading.Lock()
 
 
-def _on_device_fetch(dispatch_s: float, wait_s: float) -> None:
+def _on_device_fetch(dispatch_s: float) -> None:
     """trace.set_device_hook slot: charge an ambient-labeled fetch. The
     enabled() gate was already paid when the label was installed; the
     registry feed is deferred (see flush)."""
     lab = _local.label
     if lab is None:
         return
-    _pending_fetches.append((lab, dispatch_s, wait_s))
+    _pending_fetches.append((lab, dispatch_s))
     if len(_pending_fetches) > _PENDING_FETCH_MAX:
         flush()
 
 
 def flush() -> None:
-    """Fold pending labeled fetches into the registry (wait + dispatch
-    timers per (kernel id, tier); the wait histogram's count IS the
-    dispatch count). Runs from the registry's pre-drain hook and any
+    """Fold pending labeled fetches into the registry (the dispatch timer
+    per (kernel id, tier)). Runs from the registry's pre-drain hook and any
     attribution reader."""
     if not _pending_fetches:
         return
@@ -176,11 +162,8 @@ def flush() -> None:
         pending = _pending_fetches[:]
         # concurrent appends land past the copied prefix and survive
         del _pending_fetches[: len(pending)]
-    batch = []
-    for (kid, tier), dispatch_s, wait_s in pending:
-        batch.append((_series(kid, tier, "device_wait"), wait_s))
-        batch.append((_series(kid, tier, "dispatch"), dispatch_s))
-    _metrics.observe_batch(batch)
+    _metrics.observe_batch([(_series(kid, tier, "dispatch"), dispatch_s)
+                            for (kid, tier), dispatch_s in pending])
 
 
 def install() -> None:

@@ -95,9 +95,10 @@ class Follower:
         _metrics.set_gauge("replication.lag_ms",
                            lambda: round(self.lag_ms, 1))
         self._install_slo()
-        self._thread = threading.Thread(target=self._run,
-                                        name=f"geomesa-repl-{self.id}",
-                                        daemon=True)
+        from geomesa_tpu.obs import hostcpu
+        self._thread = hostcpu.thread("program.other", target=self._run,
+                                      name=f"geomesa-repl-{self.id}",
+                                      daemon=True)
         if connect:
             self._thread.start()
 

@@ -30,6 +30,7 @@ from geomesa_tpu.durability import faults
 from geomesa_tpu.durability import snapshot as _snap
 from geomesa_tpu.durability import wal as _wal
 from geomesa_tpu.metrics import REGISTRY as _metrics
+from geomesa_tpu.obs import hostcpu
 from geomesa_tpu.replication import fence as _fence
 from geomesa_tpu.replication import protocol as _p
 
@@ -64,8 +65,9 @@ class LogShipper:
         self._srv.bind((host, int(port)))
         self._srv.listen(16)
         self.host, self.port = self._srv.getsockname()[:2]
-        self._accept_thread = threading.Thread(
-            target=self._accept_loop, name="geomesa-repl-ship", daemon=True)
+        self._accept_thread = hostcpu.thread(
+            "program.other", target=self._accept_loop,
+            name="geomesa-repl-ship", daemon=True)
         self._accept_thread.start()
         store.replication = self
         from geomesa_tpu import trace as _trace
@@ -138,9 +140,9 @@ class LogShipper:
             conn.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
             with self._lock:
                 self._conns.append(conn)
-            threading.Thread(target=self._session, args=(conn, addr),
-                             name="geomesa-repl-session",
-                             daemon=True).start()
+            hostcpu.thread("program.other", target=self._session,
+                           args=(conn, addr), name="geomesa-repl-session",
+                           daemon=True).start()
 
     def _session(self, conn: socket.socket, addr) -> None:
         fid = None
@@ -172,9 +174,9 @@ class LogShipper:
                 st["addr"] = f"{addr[0]}:{addr[1]}"
                 st["connected"] = True
                 st["acked_seq"] = max(st["acked_seq"], acked)
-            reader = threading.Thread(target=self._ack_loop,
-                                      args=(conn, fid),
-                                      name="geomesa-repl-acks", daemon=True)
+            reader = hostcpu.thread("program.other", target=self._ack_loop,
+                                    args=(conn, fid),
+                                    name="geomesa-repl-acks", daemon=True)
             reader.start()
             self._ship(conn, fid, acked)
         except (OSError, _p.ProtocolError):

@@ -694,8 +694,10 @@ class ReplicaRouter:
             if not cands:
                 meta[shard]["error"] = "no live member"
 
-        threads = [threading.Thread(target=one_shard, args=(c.shard,),
-                                    daemon=True) for c in topo.cells]
+        from geomesa_tpu.obs import hostcpu
+        threads = [hostcpu.thread("program.other", target=one_shard,
+                                  args=(c.shard,), daemon=True)
+                   for c in topo.cells]
         for th in threads:
             th.start()
         join_s = (float(deadline_ms) / 1000.0 + 5.0) \
@@ -1197,7 +1199,10 @@ def serve_router(router: ReplicaRouter, host: str = "127.0.0.1",
 
     httpd = BacklogHTTPServer((host, port), _RouterHandler)
     httpd.router_api = api
+    from geomesa_tpu.obs import hostcpu
     if background:
-        threading.Thread(target=httpd.serve_forever, daemon=True).start()
+        hostcpu.thread("http.listener", target=httpd.serve_forever,
+                       daemon=True).start()
         return httpd
+    hostcpu.mark(threading.current_thread(), "http.listener")
     httpd.serve_forever()

@@ -77,7 +77,6 @@ profiler trace; a request's own trace gets the partition submit → queue_wait
 
 from __future__ import annotations
 
-import contextlib
 import itertools
 import queue
 import threading
@@ -98,6 +97,7 @@ from geomesa_tpu.index.scan import PRIMARY_FNS, Unsupported
 from geomesa_tpu.metrics import REGISTRY as _metrics
 from geomesa_tpu.obs import attrib as _attrib
 from geomesa_tpu.obs import flight as _flight
+from geomesa_tpu.obs import hostcpu as _hostcpu
 from geomesa_tpu.obs import workload as _workload
 from geomesa_tpu.serve.cache import MISS as _RC_MISS
 from geomesa_tpu.serve.cache import ResultCache
@@ -113,15 +113,8 @@ _pc = time.perf_counter
 _pcn = time.perf_counter_ns   # the clock of trace.py's spans
 _MISS = object()
 _STOP = object()
-_NO_ANNOTATION = contextlib.nullcontext()
 _SLOW_CYCLE_S = 1.0           # first dequeue → resolved; kept in slow_cycles
 _SLOW_CYCLES_KEPT = 8
-
-
-def _annotate(name: str, **kw):
-    """A ``sched.*`` span in a profiler trace, while tracing is on."""
-    return _trace.annotation(name, **kw) if _trace.enabled() \
-        else _NO_ANNOTATION
 
 
 def _query_cell(f: "ir.Filter") -> Optional[str]:
@@ -343,8 +336,22 @@ class Request:
         self.funcs = _ir.funcs_of(f_ir) if f_ir is not None else ()
         self.result_cache_hit: Optional[bool] = None
 
+    def resolved_ns(self) -> Optional[int]:
+        """The instant (``perf_counter_ns``) the request was resolved on the
+        completer, where it went through a launch: launch + ``scan``."""
+        if self.t_launch is None or self.scan_s is None:
+            return None
+        return self.t_launch + int(self.scan_s * 1e9)
+
     def result(self, timeout: Optional[float] = None) -> int:
-        return self.future.result(timeout=timeout)
+        """Blocks for the count: a wait by design (``trace.waiting``) that
+        ends where the request was resolved; the caller's wake and turn at
+        the interpreter lock after that stay off-CPU time."""
+        with _trace.waiting() as w:
+            try:
+                return self.future.result(timeout=timeout)
+            finally:
+                w.until(self.resolved_ns())
 
 
 def _groupable(plan) -> bool:
@@ -563,11 +570,13 @@ class QueryScheduler:
         for p in getattr(binding, "_planners", {}).values():
             for idx in getattr(p, "indexes", ()):
                 _fused.warm_programs(idx)
-        self._collector = threading.Thread(
-            target=self._worker_main, args=("collector", self._collect_loop),
+        self._collector = _hostcpu.thread(
+            "sched.collector", target=self._worker_main,
+            args=("collector", self._collect_loop),
             name="geomesa-sched-collect", daemon=True)
-        self._completer = threading.Thread(
-            target=self._worker_main, args=("completer", self._complete_loop),
+        self._completer = _hostcpu.thread(
+            "sched.completer", target=self._worker_main,
+            args=("completer", self._complete_loop),
             name="geomesa-sched-complete", daemon=True)
         self._collector.start()
         self._completer.start()
@@ -630,7 +639,6 @@ class QueryScheduler:
             cached = self.results.get(rkey)
             if cached is not _RC_MISS:
                 req.result_cache_hit = True
-                _metrics.inc("scheduler.result_cache_serves")
                 self._resolve(req, cached)
                 return req
             req.result_cache_hit = False
@@ -693,7 +701,7 @@ class QueryScheduler:
 
     def _finish(self, req: Request, timeout: Optional[float]) -> int:
         try:
-            return req.future.result(timeout=timeout)
+            return req.result(timeout)
         finally:
             if _trace.enabled():
                 self._record_stages(req)
@@ -732,7 +740,7 @@ class QueryScheduler:
                 rec("plan", "plan", (t1 - t0) / 1e9, t1,
                     {"bound": True} if req.plan_bound else None, host)
             if req.scan_s is not None:
-                resolved = launch + int(req.scan_s * 1e9)
+                resolved = req.resolved_ns()
                 scan = rec("scan", "scan", req.scan_s, resolved,
                            None if req.batch_id is None
                            else {"batch_id": req.batch_id})
@@ -883,7 +891,7 @@ class QueryScheduler:
     def _collect_loop(self) -> None:
         while True:
             t_idle = _pcn()
-            with _annotate("sched.idle"):
+            with _trace.annotation("sched.idle"):
                 _, _, req = self._queue.get()
             cyc = _Cycle(t_idle, _pcn())
             _faults.serve_gate("sched.collect")
@@ -894,7 +902,7 @@ class QueryScheduler:
             t0 = _pc()
             reason = "window"
             stop = False
-            with _annotate("sched.window"):
+            with _trace.annotation("sched.window"):
                 while len(batch) < self._flush_size:
                     remaining = self._window_us / 1e6 - (_pc() - t0)
                     if remaining <= 0:
@@ -938,7 +946,6 @@ class QueryScheduler:
         self._n_batches += 1
         self._flush_reasons[reason] += 1
         self._batch_hist[n] = self._batch_hist.get(n, 0) + 1
-        _metrics.observe_value("scheduler.batch_size", n)
         # adaptive window: see class docstring
         self._ema_batch = 0.8 * self._ema_batch + 0.2 * n
         if self._ema_batch <= 1.5:
@@ -1044,7 +1051,7 @@ class QueryScheduler:
         groups: Dict[tuple, List[Request]] = {}
         cpu0 = time.thread_time_ns()
         cyc.t_loop = _pcn()
-        with _annotate("sched.plan_loop", n=len(batch)):
+        with _trace.annotation("sched.plan_loop", n=len(batch)):
             self._plan_loop(batch, cyc, groups)
             covers = []
             for grp in groups.values():
@@ -1120,7 +1127,6 @@ class QueryScheduler:
                 groups.setdefault(_group_key(plan), []).append(r)
             else:
                 self._n_single += 1
-                _metrics.inc("scheduler.singles")
                 self._to_completer_single(r)
 
     def _dispatch_group(self, grp: List[Request], cover: tuple,
@@ -1134,7 +1140,6 @@ class QueryScheduler:
         from geomesa_tpu.index.scan import blocks_tier
 
         self._n_fused += len(grp)
-        _metrics.inc("scheduler.fused", len(grp))
         lead = grp[0].plan
         kern = lead.index.kernels
         batch_id = next(self._batch_ids)
@@ -1146,7 +1151,7 @@ class QueryScheduler:
         # attribution tier = the padded batch size the dispatch shipped
         d.tier = max(1, 1 << max(0, (len(grp) - 1)).bit_length())
         d.t_union = _pcn()
-        with _annotate("sched.union", batch_id=batch_id):
+        with _trace.annotation("sched.union", batch_id=batch_id):
             boxes = np.concatenate([r.plan.boxes_loose for r in grp], axis=0)
             xfer = boxes.nbytes
             if pruned:
@@ -1154,7 +1159,7 @@ class QueryScheduler:
                 d.union_tier = blocks_tier(len(union))
                 xfer += union.nbytes
         d.t_prepare = _pcn()
-        with _annotate("sched.prepare", batch_id=batch_id):
+        with _trace.annotation("sched.prepare", batch_id=batch_id):
             if pruned:
                 disp = kern.prepare_counts_multi_blocks(
                     lead.primary_kind, boxes, lead.windows,
@@ -1184,7 +1189,7 @@ class QueryScheduler:
         # retries: transient dispatch failures retry (and count), a sick
         # device path opens the breaker and subsequent traffic fails fast
         # or degrades instead of piling on
-        with _annotate("sched.launch", batch_id=batch_id):
+        with _trace.annotation("sched.launch", batch_id=batch_id):
             out = retry_call(_launch, breaker=self.breaker)
         # the probe around a freshly built program timed its first call
         d.first_call_s = _attrib.take_first_call()
@@ -1221,13 +1226,13 @@ class QueryScheduler:
         batch_id = d.batch_id
         # host-side LSM-delta counts first: they overlap the in-flight
         # device round trip instead of adding to it
-        with _annotate("sched.delta", batch_id=batch_id):
+        with _trace.annotation("sched.delta", batch_id=batch_id):
             extras = [len(self.binding.delta_rows(r.delta, r.f_ir, r.auths))
                       if r.delta is not None else 0 for r in grp]
             _faults.serve_gate("sched.device_wait")
         d.t_wait = _pcn()
         try:
-            with _annotate("sched.ready_wait", batch_id=batch_id):
+            with _trace.annotation("sched.ready_wait", batch_id=batch_id):
                 # blocks until the device batch is ready
                 counts = np.asarray(out)
         except Exception:
@@ -1241,9 +1246,8 @@ class QueryScheduler:
         # time (a device trace gives that)
         wait_s = (t_ready - d.t_wait) / 1e9
         t_launch = d.t_launch
-        _attrib.record_dispatch(d.kernel, d.tier, wait_s)
         last = len(grp) - 1
-        with _annotate("sched.resolve", batch_id=batch_id):
+        with _trace.annotation("sched.resolve", batch_id=batch_id):
             for i, r in enumerate(grp):
                 r.batched = True
                 r.batch_size = len(grp)

@@ -47,6 +47,25 @@ for free — spans REPLACE the ad-hoc ``REGISTRY.time(...)`` calls on the hot
 paths. ``trace()`` nests: opened under an active trace it degrades to a
 plain span, so datastore-level and planner-level roots compose.
 
+CPU: a root trace also reads its thread's CPU clock
+(``time.thread_time_ns``) at entry and exit: ``cpu_ms`` beside
+``duration_ms``, and ``cpu_s`` beside ``total_s`` in its registry timer.
+Wall minus CPU is time the thread waited: for a device, a result, a body —
+or for the interpreter lock. ``waiting()`` marks the first kind (a wait by
+design); the REST handler subtracts them from a request's off-CPU time
+(web/server.py). Spans and ``record()`` leaves carry no CPU time: on a
+TPU v5e host the clock is a system call of ~4.7 µs (``perf/span_cost.py``),
+65 times ``perf_counter_ns``, too dear for every span of a request that
+holds the interpreter lock.
+
+Profiler: while a profiler session records host events
+(``host_tracer_level`` >= 1), every span and root is also a
+``jax.profiler.TraceAnnotation`` of its name, on the device trace's clock.
+The gate is one flag read a span — ``TraceAnnotation.is_enabled()``, the
+profiler's own "a host tracer is collecting" — so no annotation object is
+built outside such a session. ``record()`` leaves, timed after the fact,
+are not annotated.
+
 Clock: every span holds its start on ``time.perf_counter_ns``; ``ANCHOR``
 pairs one reading of that clock with ``time.time_ns`` at import, so
 ``epoch_ms(ns)`` puts any span — and the scheduler's dispatch-cycle stages,
@@ -69,8 +88,10 @@ reassemble ONE cross-process tree from the per-node halves.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import os
+import sys
 import threading
 import time
 import zlib
@@ -98,6 +119,7 @@ SPAN_KINDS = ("plan", "range_decompose", "submit", "queue_wait", "batch_host",
               "collective")
 
 _pcn = time.perf_counter_ns  # cached: spans sit on µs-scale hot paths
+_tcn = time.thread_time_ns   # the calling thread's CPU clock
 
 # (perf_counter_ns, time_ns) read together once: the one bridge between the
 # span clock and the wall clock
@@ -115,6 +137,7 @@ class _Local(threading.local):
     trace = None
     stack = None
     remote = None  # pending RemoteParent consumed by the next root trace
+    waited_ns = 0  # this thread's waits by design so far (``waiting``)
 
 
 _local = _Local()
@@ -291,7 +314,7 @@ class Span:
     list keeps leaf allocation to one object on the hot path)."""
 
     __slots__ = ("name", "kind", "attrs", "start_ns", "duration_ms",
-                 "children", "span_id")
+                 "cpu_ms", "children", "span_id")
 
     def __init__(self, name: str, kind: Optional[str], attrs: Optional[dict]):
         self.name = name
@@ -301,6 +324,8 @@ class Span:
         # perf_counter_ns at entry; None on a leaf recorded without its end
         self.start_ns: Optional[int] = None
         self.duration_ms = 0.0
+        # the thread's CPU time across the span: read by a root only
+        self.cpu_ms: Optional[float] = None
         self.children: Optional[List[Span]] = None
         # assigned on demand (inject_headers) when this span parents a
         # remote child — the stitcher's attachment point
@@ -333,6 +358,8 @@ class Span:
         d = {"name": self.name, "kind": self.kind,
              "duration_ms": round(self.duration_ms, 3),
              "self_ms": round(self.self_ms, 3)}
+        if self.cpu_ms is not None:
+            d["cpu_ms"] = round(self.cpu_ms, 3)
         if self.start_ns is not None and origin_ns is not None:
             d["start_ms"] = round((self.start_ns - origin_ns) / 1e6, 3)
         if self.span_id is not None:
@@ -456,9 +483,9 @@ RING = TraceRing()
 #
 # Close hooks fire once per ROOT trace at close (tail sampling + flight-
 # recorder derivation, obs/flight.py / obs/sampling.py); the device hook
-# fires per device_fetch with (dispatch_s, wait_s) so per-kernel attribution
-# (obs/attrib.py) can charge device time to the kernel an ambient label
-# names. Both are None/empty by default — the hot path pays one read.
+# fires per device_fetch with its dispatch seconds so per-kernel attribution
+# (obs/attrib.py) can charge the host's enqueue to the kernel an ambient
+# label names. Both are None/empty by default — the hot path pays one read.
 
 _close_hooks: List = []
 _device_hook = None
@@ -478,8 +505,8 @@ def remove_close_hook(fn) -> None:
 
 
 def set_device_hook(fn) -> None:
-    """Install ``fn(dispatch_s, wait_s)`` called from ``device_fetch`` —
-    the per-kernel device-cost attribution slot. None uninstalls."""
+    """Install ``fn(dispatch_s)`` called from ``device_fetch`` — the
+    per-kernel attribution slot. None uninstalls."""
     global _device_hook
     _device_hook = fn
 
@@ -505,7 +532,7 @@ class span:
     the drop-in replacement for ``REGISTRY.time(name)``. ~µs overhead when
     enabled; a no-op under ``disabled()``."""
 
-    __slots__ = ("name", "kind", "attrs", "_node", "_t0")
+    __slots__ = ("name", "kind", "attrs", "_node", "_t0", "_ann")
 
     def __init__(self, name: str, kind: Optional[str] = None, **attrs):
         self.name = name
@@ -516,6 +543,11 @@ class span:
         if not _state.enabled:
             self._t0 = None
             return self
+        if _profiling():
+            ann = self._ann = _annotation_cls(self.name)
+            ann.__enter__()
+        else:
+            self._ann = None
         tr = _local.trace
         if tr is not None:
             node = Span(self.name, self.kind, self.attrs)
@@ -549,6 +581,8 @@ class span:
             _local.stack.pop()
         else:
             _REGISTRY.observe(self.name, dt / 1e9)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         return False
 
 
@@ -591,6 +625,7 @@ def _leaf(name: str, kind: str, duration_ms: float,
     s.attrs = attrs
     s.start_ns = start_ns
     s.duration_ms = duration_ms
+    s.cpu_ms = None
     s.children = None
     s.span_id = None
     return s
@@ -623,7 +658,9 @@ def device_fetch(block, dispatch, *args):
     """Fused device_scan + device_wait recorder for the kernel hot path:
     ``block(dispatch(*args))`` with both stages timed through ONE function
     call instead of two context managers (the per-query span overhead budget
-    is single-digit µs — see tests/test_perf_budget.py)."""
+    is single-digit µs — see tests/test_perf_budget.py). The block counts
+    among the thread's waits by design (``waiting``); the leaves carry no
+    CPU time."""
     if not _state.enabled:
         return block(dispatch(*args))
     t0 = _pcn()
@@ -631,9 +668,10 @@ def device_fetch(block, dispatch, *args):
     t1 = _pcn()
     out = block(out)
     t2 = _pcn()
+    _local.waited_ns += t2 - t1   # the block is a wait by design
     hook = _device_hook
     if hook is not None:
-        hook((t1 - t0) / 1e9, (t2 - t1) / 1e9)
+        hook((t1 - t0) / 1e9)
     tr = _local.trace
     if tr is not None:
         parent = _local.stack[-1]
@@ -649,18 +687,93 @@ def device_fetch(block, dispatch, *args):
 
 
 _annotation_cls = None
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def _bind_profiler() -> bool:
+    """The annotation gate until ``jax.profiler`` is imported (this module
+    stays free of jax at import): binds ``_profiling`` to
+    ``TraceAnnotation.is_enabled`` once it is, and answers it."""
+    global _annotation_cls, _profiling
+    mod = sys.modules.get("jax.profiler")
+    if mod is None:
+        return False
+    _annotation_cls = mod.TraceAnnotation
+    _profiling = _annotation_cls.is_enabled
+    return _profiling()
+
+
+# True while a profiler session records host events (a host tracer level of 1
+# or more): the one flag a span reads before it builds an annotation
+_profiling = _bind_profiler
 
 
 def annotation(name: str, **kw):
-    """``jax.profiler.TraceAnnotation(name, **kw)``: a host span in the
-    profiler's own trace, on the device trace's clock. Imported on first use
-    (this module stays free of jax at import); outside a profiler session
-    entering one is a flag check."""
-    global _annotation_cls
-    if _annotation_cls is None:
-        from jax.profiler import TraceAnnotation
-        _annotation_cls = TraceAnnotation
+    """``jax.profiler.TraceAnnotation(name, **kw)`` while tracing is on and a
+    profiler session records host events: a host span in the profiler's own
+    trace, on the device trace's clock; a shared null context otherwise (the
+    same gate as every span's)."""
+    if not (_state.enabled and _profiling()):
+        return _NO_ANNOTATION
     return _annotation_cls(name, **kw)
+
+
+class _Waiting:
+    """One wait by design (``waiting``)."""
+
+    __slots__ = ("_t0", "_end")
+
+    def __enter__(self):
+        self._t0 = _pcn()
+        self._end = None
+        return self
+
+    def until(self, end_ns: Optional[int]) -> None:
+        self._end = end_ns
+
+    def __exit__(self, *exc):
+        end = _pcn()
+        if self._end is not None and self._end < end:
+            end = self._end
+        if end > self._t0:
+            _local.waited_ns += end - self._t0
+        return False
+
+
+class _NotWaiting:
+    """``waiting`` with tracing off: one shared object, no clock read."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def until(self, end_ns: Optional[int]) -> None:
+        pass
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NOT_WAITING = _NotWaiting()
+
+
+def waiting():
+    """Context manager around a blocking call whose wait is by design — a
+    scheduled result, the device, a request body — and not for the
+    interpreter lock: its wall time is added to this thread's waits by
+    design (``waited_ns``), which the REST handler subtracts from a
+    request's off-CPU time. ``until(ns)`` ends the wait at the instant
+    (``perf_counter_ns``) the program knows the result was ready: what
+    follows it, the wake and the turn at the lock, stays off-CPU time. With
+    tracing off, a shared no-op."""
+    return _Waiting() if _state.enabled else _NOT_WAITING
+
+
+def waited_ns() -> int:
+    """This thread's waits by design so far, in ns (``waiting``; the block
+    of every ``device_fetch``)."""
+    return _local.waited_ns
 
 
 class trace:
@@ -671,7 +784,7 @@ class trace:
     (root) or Span (nested) — both expose ``to_dict()`` — or None when
     tracing is disabled."""
 
-    __slots__ = ("name", "attrs", "_t0", "_trace", "_span")
+    __slots__ = ("name", "attrs", "_t0", "_c0", "_ann", "_trace", "_span")
 
     def __init__(self, name: str, **attrs):
         self.name = name
@@ -686,6 +799,11 @@ class trace:
             self._span = span(self.name, kind="trace",
                               **(self.attrs or {}))
             return self._span.__enter__()._node
+        if _profiling():
+            ann = self._ann = _annotation_cls(self.name)
+            ann.__enter__()
+        else:
+            self._ann = None
         t = QueryTrace(self.name, self.attrs)
         remote = _local.remote
         if remote is not None:
@@ -701,6 +819,7 @@ class trace:
         _local.stack = [t.root]
         self._trace = t
         self._t0 = t.root.start_ns = _pcn()
+        self._c0 = _tcn()
         return t
 
     def __exit__(self, *exc):
@@ -709,7 +828,10 @@ class trace:
         if self._t0 is None:
             return False
         t = self._trace
+        t.root.cpu_ms = (_tcn() - self._c0) / 1e6
         t.root.duration_ms = (_pcn() - self._t0) / 1e6
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         if exc and exc[0] is not None:
             t.error = exc[0].__name__
         _local.trace = None

@@ -112,11 +112,16 @@ from __future__ import annotations
 import json
 import re
 import threading
+import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional, Tuple
 from urllib.parse import parse_qs, urlparse
 
 import numpy as np
+
+from geomesa_tpu import trace as _trace
+from geomesa_tpu.metrics import REGISTRY as _metrics
+from geomesa_tpu.obs import hostcpu as _hostcpu
 
 
 class GeoJsonApi:
@@ -194,7 +199,6 @@ class GeoJsonApi:
     def handle(self, method: str, path: str, query: dict,
                body: Optional[bytes] = None,
                headers=None) -> Tuple[int, object]:
-        from geomesa_tpu import trace as _trace
         from geomesa_tpu.cluster.cells import NotOwnedError
         from geomesa_tpu.index.guards import QueryGuardError, QueryTimeout
         from geomesa_tpu.replication.fence import FencedError
@@ -242,6 +246,7 @@ class GeoJsonApi:
             return 200, {"types": self.store.get_type_names()}
         if parts == ["metrics"]:
             from geomesa_tpu.metrics import REGISTRY
+            _hostcpu.sample()    # host.cpu_us.<role>: read only here
             fmt = query.get("format", [None])[0]
             if fmt == "prometheus":
                 # str payload → text/plain exposition body
@@ -708,6 +713,17 @@ def _route_family(path: str) -> str:
     return re.sub(r"\W", "_", parts[0]) if parts else "root"
 
 
+class _Conn(threading.local):
+    # perf_counter_ns as the listener's accept() returned this thread's
+    # connection; taken by the connection's first request
+    accepted_ns = None
+
+
+_conn = _Conn()
+_pcn = time.perf_counter_ns
+_tcn = time.thread_time_ns
+
+
 class _Handler(BaseHTTPRequestHandler):
     api: GeoJsonApi = None  # set by serve()
 
@@ -745,8 +761,23 @@ class _Handler(BaseHTTPRequestHandler):
         the request's trace, the scheduler's ``query.count`` nests under
         it, and REST's own time is the root's self time; other routes keep
         their own roots (``query.features``, ``explain``) and get the two
-        as flat timers."""
-        from geomesa_tpu import trace as _trace
+        as flat timers.
+
+        With tracing on, before it, the flat timer ``http.accept`` runs
+        from the instant the listener's ``accept()`` returned
+        (``BacklogHTTPServer``) to here: the handler thread's creation and
+        start, its first turn at the interpreter lock and the header read.
+        Once the response is flushed the request adds to the counter
+        ``http.offcpu_us``: accept to flush, less the thread's CPU time from
+        its start (one request a thread: the server speaks HTTP/1.0) and
+        less its waits by design (``trace.waiting``); what is left is time
+        it wanted to run and did not — the lock, the OS."""
+        accepted, _conn.accepted_ns = _conn.accepted_ns, None
+        from_accept = accepted is not None and _trace.enabled()
+        if from_accept:
+            now = _pcn()
+            _trace.record("http.accept", "span", (now - accepted) / 1e9, now)
+            waited = _trace.waited_ns()
         u = urlparse(self.path)
         family = _route_family(u.path)
         timed = _trace.trace if family == "count" else _trace.span
@@ -758,7 +789,8 @@ class _Handler(BaseHTTPRequestHandler):
                 body = None
                 if method == "POST":
                     length = int(self.headers.get("Content-Length", 0))
-                    body = self.rfile.read(length) if length else b""
+                    with _trace.waiting():
+                        body = self.rfile.read(length) if length else b""
                 status, payload = self.api.handle(method, u.path,
                                                   parse_qs(u.query), body,
                                                   headers=self.headers)
@@ -770,6 +802,9 @@ class _Handler(BaseHTTPRequestHandler):
                     self._respond(status, payload)
                 except (BrokenPipeError, ConnectionResetError):
                     pass  # client went away; the server thread must survive
+        if from_accept:
+            off = _pcn() - accepted - (_trace.waited_ns() - waited) - _tcn()
+            _metrics.inc("http.offcpu_us", max(0, off) // 1000)
 
     def do_GET(self):
         self._serve("GET")
@@ -787,6 +822,33 @@ class BacklogHTTPServer(ThreadingHTTPServer):
     # the excess connects sit in SYN retransmits for 1, 2, 4 … seconds
     request_queue_size = 128
 
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
+        # request socket → perf_counter_ns as accept() returned it, while
+        # tracing is on; taken by the socket's handler thread
+        self._accepted = {}
+
+    def get_request(self):
+        request, client_address = super().get_request()
+        if _trace.enabled():
+            self._accepted[request] = _pcn()
+        return request, client_address
+
+    def process_request_thread(self, request, client_address):
+        """The handler thread: the role ``http`` (obs/hostcpu.py), its
+        connection's accept instant for the handler, and its whole CPU
+        time to ``host.cpu_us.http`` as it ends."""
+        _hostcpu.mark(threading.current_thread(), "http")
+        _conn.accepted_ns = self._accepted.pop(request, None)
+        try:
+            super().process_request_thread(request, client_address)
+        finally:
+            _hostcpu.charge("http", _tcn())
+
+    def shutdown_request(self, request):
+        self._accepted.pop(request, None)   # a request no thread took
+        super().shutdown_request(request)
+
 
 def serve(store, host: str = "127.0.0.1", port: int = 8765,
           background: bool = False):
@@ -795,6 +857,8 @@ def serve(store, host: str = "127.0.0.1", port: int = 8765,
     handler = type("BoundHandler", (_Handler,), {"api": GeoJsonApi(store)})
     httpd = BacklogHTTPServer((host, port), handler)
     if background:
-        threading.Thread(target=httpd.serve_forever, daemon=True).start()
+        _hostcpu.thread("http.listener", target=httpd.serve_forever,
+                        daemon=True).start()
         return httpd
+    _hostcpu.mark(threading.current_thread(), "http.listener")
     httpd.serve_forever()

@@ -238,8 +238,11 @@ def test_dead_series_are_gone(wave):
         | set(snap["timers"])
     assert "scheduler.fused_size" not in names
     assert not [n for n in names if n.startswith("scheduler.flush.")]
-    assert "scheduler.batch_size" in snap["histograms"]
+    assert "scheduler.batch_size" not in snap["histograms"]
+    assert not {"scheduler.fused", "scheduler.singles",
+                "scheduler.result_cache_serves"} & names
     assert wave["stats1"]["flush_reasons"]
+    assert wave["stats1"]["batch_size_hist"]
 
 
 def test_request_trace_partitions_its_latency(wave):

@@ -128,7 +128,7 @@ def test_datastore_count_deadline_ms(store):
 def test_expired_deadline_cancelled_before_dispatch(store, sched):
     from geomesa_tpu.trace import RING
     c0 = REGISTRY.snapshot()["counters"]
-    fused0 = c0.get("scheduler.fused", 0)
+    fused0 = sched.stats()["fused"]
     RING.clear()
     with pytest.raises(DeadlineExceeded):
         sched.count("t", BOX, deadline_ms=1e-6)
@@ -144,7 +144,7 @@ def test_expired_deadline_cancelled_before_dispatch(store, sched):
     tr = next(t for t in RING.recent(10) if t["name"] == "query.count")
     assert "cancel" in tr["stages_ms"]
     assert "scan" not in tr["stages_ms"]
-    assert c1.get("scheduler.fused", 0) == fused0
+    assert sched.stats()["fused"] == fused0
 
 
 def test_deadline_expiring_in_queue_cancels_at_dispatch(store, sched):

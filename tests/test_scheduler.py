@@ -334,10 +334,13 @@ def test_adaptive_window_stays_bounded_and_stats_populate(store):
     assert sum(st["batch_size_hist"].values()) == st["batches"]
     from geomesa_tpu.metrics import REGISTRY
     snap = REGISTRY.snapshot()
-    assert snap["histograms"]["scheduler.batch_size"]["count"] >= 1
+    # the batch sizes are the scheduler page's, not a registry histogram's
+    assert "scheduler.batch_size" not in snap["histograms"]
+    assert sum(int(k) * v for k, v in st["batch_size_hist"].items()) \
+        == st["queries"]
     assert "scheduler.queue_depth" in snap["gauges"]
     prom = REGISTRY.to_prometheus()
-    assert "geomesa_tpu_scheduler_batch_size" in prom
+    assert "geomesa_tpu_scheduler_queue_depth" in prom
 
 
 def test_parse_and_guard_errors_surface(store):
