@@ -12,12 +12,14 @@ from __future__ import annotations
 import csv
 import io
 import json
+from json.encoder import encode_basestring_ascii as _json_str
 from typing import Optional
 
 import numpy as np
 
-from geomesa_tpu.features.geometry import GeometryArray
+from geomesa_tpu.features.geometry import TYPE_NAMES, GeometryArray
 from geomesa_tpu.features.table import FeatureTable, StringColumn
+from geomesa_tpu.metrics import REGISTRY
 
 FORMATS = ("csv", "tsv", "geojson", "json", "wkt", "arrow", "parquet",
            "avro", "orc", "gml", "shp", "leaflet")
@@ -127,40 +129,98 @@ def _delimited(table: FeatureTable, delim: str, path):
     return _finish(f, path)
 
 
-def _geojson_geometry(garr: GeometryArray, i: int) -> dict:
-    from geomesa_tpu.features import geometry as geo
-    code, data = garr.shape(i)
-    return {"type": geo.TYPE_NAMES[code], "coordinates": data}
+def _geojson_tokens(col, attr):
+    """(the JSON text of each value of a property column, whether it was
+    encoded as a whole column). Numbers and booleans go through the C
+    encoder as one list; a string column encodes each distinct code it uses
+    once; a date column formats as one array, to ``_iso``'s text; anything
+    else (a geometry kept as a property, UUID, Bytes) value by value."""
+    if isinstance(col, StringColumn):
+        used, inv = np.unique(col.codes, return_inverse=True)
+        toks = np.array([_json_str(col.vocab[c]) for c in used.tolist()],
+                        dtype=object)
+        return toks[inv].tolist(), True
+    if isinstance(col, np.ndarray) and col.dtype.kind in "biuf":
+        if attr.type_name != "Date":
+            return _json_numbers(col), True
+        iso = np.datetime_as_string(col.astype("datetime64[ms]"), unit="ms")
+        return [f'"{s}Z"' for s in iso.tolist()], True
+    if isinstance(col, GeometryArray):
+        vals = [col.wkt(i) for i in range(len(col))]
+    else:
+        vals = [v.item() if isinstance(v, np.generic) else v for v in col]
+    return [json.dumps(v) for v in vals], False
+
+
+def _json_numbers(arr: np.ndarray) -> list:
+    """JSON text of each number of a 1-D array: one call of the C encoder
+    over ``tolist()``'s Python ints, floats and bools (the values ``.item()``
+    gives), split at its separators. NaN and ±inf come out as ``NaN`` and
+    ``Infinity``, as Python's encoder writes them."""
+    return json.dumps(arr.tolist())[1:-1].split(", ") if len(arr) else []
+
+
+def _geojson_geometries(garr: GeometryArray):
+    """(each feature's GeoJSON geometry text, whether the column was encoded
+    as a whole): points from the two coordinate planes, any other geometry
+    through the C encoder feature by feature."""
+    if garr.is_points:
+        xs = _json_numbers(garr.coords[:, 0])
+        ys = _json_numbers(garr.coords[:, 1])
+        return [f'{{"type": "Point", "coordinates": [{x}, {y}]}}'
+                for x, y in zip(xs, ys)], True
+    shapes = (garr.shape(i) for i in range(len(garr)))
+    return [json.dumps({"type": TYPE_NAMES[code], "coordinates": data})
+            for code, data in shapes], False
+
+
+def geojson(table: FeatureTable) -> str:
+    """The table as one GeoJSON FeatureCollection, written column by column:
+    each column's values become JSON text once, then a template a feature
+    (id, geometry, properties in the schema's order) joins them. It parses
+    to what ``json.dumps`` of a dict a feature gives: the same keys, ids
+    (``str(fid)``), key order, floats to the bit, ``NaN`` and ``Infinity``,
+    ASCII-escaped strings, ISO dates to the millisecond with a ``Z``.
+    Counters ``export.geojson.columns_whole`` and ``.columns_by_value``
+    gain the columns (the geometry one of them) encoded each way."""
+    gattr = table.sft.geometry_attribute
+    cols = [[_json_str(str(f)) for f in table.fids.tolist()]]
+    whole = []
+    if gattr is None:
+        head = '{"type": "Feature", "id": %s, "geometry": null'
+    else:
+        head = '{"type": "Feature", "id": %s, "geometry": %s'
+        toks, w = _geojson_geometries(table.columns[gattr.name])
+        cols.append(toks)
+        whole.append(w)
+    keys = []
+    for a in table.sft.attributes:
+        if gattr is not None and a.name == gattr.name:
+            continue
+        toks, w = _geojson_tokens(table.columns[a.name], a)
+        cols.append(toks)
+        whole.append(w)
+        keys.append(_json_str(a.name).replace("%", "%%") + ": %s")
+    template = head + ', "properties": {' + ", ".join(keys) + "}}"
+    REGISTRY.inc("export.geojson.columns_whole", sum(whole))
+    REGISTRY.inc("export.geojson.columns_by_value", len(whole) - sum(whole))
+    return ('{"type": "FeatureCollection", "features": ['
+            + ", ".join([template % row for row in zip(*cols)]) + "]}")
 
 
 def _geojson(table: FeatureTable, path):
-    garr = table.geometry() if table.sft.geometry_attribute else None
-    gname = table.sft.geometry_attribute.name if garr is not None else None
-    feats = []
-    for i in range(len(table)):
-        props = {}
-        for a in table.sft.attributes:
-            if a.name == gname:
-                continue
-            props[a.name] = _cell(table.columns[a.name], a, i)
-        feats.append({
-            "type": "Feature",
-            "id": str(table.fids[i]),
-            "geometry": None if garr is None else _geojson_geometry(garr, i),
-            "properties": props,
-        })
-    doc = {"type": "FeatureCollection", "features": feats}
     f = _out(path)
-    json.dump(doc, f)
+    f.write(geojson(table))
     return _finish(f, path)
 
 
 def _jsonlines(table: FeatureTable, path):
     f = _out(path)
     for row in table.to_dicts():
-        json.dump({k: (v.item() if isinstance(v, np.generic) else v)
-                   for k, v in row.items()}, f)
-        f.write("\n")
+        # json.dumps, not json.dump: only a one-shot encode takes the C
+        # encoder, a dump to a file runs the pure-Python one
+        f.write(json.dumps({k: (v.item() if isinstance(v, np.generic) else v)
+                            for k, v in row.items()}) + "\n")
     return _finish(f, path)
 
 
@@ -456,8 +516,7 @@ def _leaflet(table: FeatureTable, path):
     template does). The embedded JSON escapes '</' so a string value
     containing '</script>' can neither break the document nor inject
     script; popup values HTML-escape browser-side."""
-    geojson = _geojson(table, None).replace("</", "<\\/")
-    doc = _LEAFLET_HTML.format(geojson=geojson)
+    doc = _LEAFLET_HTML.format(geojson=geojson(table).replace("</", "<\\/"))
     f = _out(path)
     f.write(doc)
     return _finish(f, path)

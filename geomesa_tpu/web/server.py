@@ -193,7 +193,8 @@ class GeoJsonApi:
         raw = str(raw).strip()
         return raw or None
 
-    # returns (status, payload) — dict for JSON, str for raw text bodies.
+    # returns (status, payload) — dict for JSON, bytes for JSON already
+    # encoded (GET /features), str for raw text bodies.
     # A 429/503 payload carries retry_after_s; the transport turns it into
     # a Retry-After header.
     def handle(self, method: str, path: str, query: dict,
@@ -571,7 +572,7 @@ class GeoJsonApi:
                 res = self.store.query(t, cql, hints=hints or None,
                                        auths=auths)
                 # what the response will carry: its size is the route's
-                # cost (one GeoJSON feature a row, built in Python)
+                # cost (one GeoJSON feature a row)
                 from geomesa_tpu.metrics import REGISTRY
                 REGISTRY.inc("http.features.rows", len(res.table))
                 if "select" in query:
@@ -585,7 +586,9 @@ class GeoJsonApi:
                     return 200, {"type": t, "count": len(res.table),
                                  "columns": cols}
                 from geomesa_tpu.io.export import export
-                return 200, json.loads(export(res.table, "geojson"))
+                # the body as the writer made it: _respond sends bytes as
+                # they are, with no parse and no second encode
+                return 200, export(res.table, "geojson").encode()
             if rest == ["join"] and method == "GET":
                 if "with" not in query:
                     return 400, {"error": "missing ?with=<polygon type>"}
@@ -728,9 +731,13 @@ class _Handler(BaseHTTPRequestHandler):
     api: GeoJsonApi = None  # set by serve()
 
     def _respond(self, status: int, payload) -> None:
-        # str payloads are raw text bodies (the Prometheus exposition);
+        # bytes payloads are JSON already encoded (a GeoJSON answer); str
+        # payloads are raw text bodies (the Prometheus exposition);
         # everything else serializes as JSON
-        if isinstance(payload, str):
+        if isinstance(payload, bytes):
+            data = payload
+            ctype = "application/json"
+        elif isinstance(payload, str):
             data = payload.encode()
             ctype = "text/plain; version=0.0.4"
         else:

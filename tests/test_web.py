@@ -65,6 +65,80 @@ def test_features_geojson(server):
     assert g["type"] == "Point" and -5 <= g["coordinates"][0] <= 5
 
 
+def _columns_whole():
+    from geomesa_tpu.metrics import REGISTRY
+    return REGISTRY.snapshot()["counters"].get(
+        "export.geojson.columns_whole", 0)
+
+
+@pytest.mark.parametrize("hints", [{"limit": "25"},
+                                   {"limit": "40", "sort": "-v"},
+                                   {"crs": "EPSG:3857"}])
+def test_features_served_as_the_reference_writes_them(server, hints):
+    """The route's bytes parse to the document the per-feature reference
+    writes for the same query, under the JSON type and their own length;
+    every column of the schema is encoded as a whole column."""
+    from test_geojson_writer import reference
+    base, ds, x, y = server
+    q = "BBOX(geom, -5, -5, 5, 5)"
+    before = _columns_whole()
+    with urllib.request.urlopen(
+            f"{base}/types/w/features?cql={urllib.parse.quote(q)}&"
+            + urllib.parse.urlencode(hints)) as r:
+        body = r.read()
+        assert r.headers["Content-Type"] == "application/json"
+        assert int(r.headers["Content-Length"]) == len(body)
+    assert _columns_whole() - before == 4
+    hints = {k: int(v) if k == "limit" else v for k, v in hints.items()}
+    want = reference(ds.query("w", q, hints=hints).table)
+    assert json.dumps(json.loads(body)) == json.dumps(json.loads(want))
+    assert len(json.loads(body)["features"]) > 0
+
+
+def test_dict_routes_write_the_bytes_they_wrote_before(server):
+    """A count, a join and /metrics still go out as ``json.dumps`` of the
+    route's dict, byte for byte."""
+    from geomesa_tpu.web.server import GeoJsonApi
+    base, ds, x, y = server
+    q = {"cql": ["BBOX(geom, -5, -5, 5, 5)"]}
+    with urllib.request.urlopen(
+            f"{base}/types/w/count?{urllib.parse.urlencode(q, True)}") as r:
+        body = r.read()
+    assert body == json.dumps(
+        GeoJsonApi(ds).handle("GET", "/types/w/count", q)[1]).encode()
+    with urllib.request.urlopen(f"{base}/metrics") as r:
+        body = r.read()
+        assert r.headers["Content-Type"] == "application/json"
+    assert body == json.dumps(json.loads(body)).encode()
+
+    js = TpuDataStore()
+    js.create_schema("p", "v:Integer,dtg:Date,*geom:Point;"
+                          "geomesa.z3.interval=week")
+    js.load("p", FeatureTable.build(js.get_schema("p"), {
+        "v": np.arange(len(x), dtype=np.int32),
+        "dtg": np.full(len(x), 1_700_000_000_000, np.int64),
+        "geom": (x, y)}))
+    js.create_schema("c", "name:String,*geom:Polygon;geomesa.indices=xz2")
+    js.load("c", FeatureTable.build(js.get_schema("c"), {
+        "name": ["west", "east"],
+        "geom": ["POLYGON ((-10 -10, 0 -10, 0 10, -10 10, -10 -10))",
+                 "POLYGON ((0 -10, 10 -10, 10 10, 0 10, 0 -10))"]}))
+    httpd = serve(js, port=0, background=True)
+    try:
+        jq = {"with": ["c"], "stats": ["count,sum(v)"]}
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{httpd.server_address[1]}/types/p/join?"
+                + urllib.parse.urlencode(jq, True)) as r:
+            body = r.read()
+    finally:
+        httpd.shutdown()
+    doc = GeoJsonApi(js).handle("GET", "/types/p/join", jq)[1]
+    assert [row["count"] for row in doc["rows"]] == [
+        int(np.sum((x >= lo) & (x <= hi) & (y >= -10) & (y <= 10)))
+        for lo, hi in ((-10, 0), (0, 10))]
+    assert body == json.dumps(doc).encode()
+
+
 def test_post_ingest_roundtrip(server):
     base, ds, x, y = server
     fc = {"type": "FeatureCollection", "features": [
